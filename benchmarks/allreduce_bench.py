@@ -33,7 +33,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from bench import resolve_platform  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -44,10 +43,7 @@ def _compressed_ab(mesh, n, elems, repeats=7):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-0.6 jax spells it jax.experimental.shard_map
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from deeplearning4j_tpu.parallel import compression as comp
@@ -124,25 +120,15 @@ def main():
                          "and archive it under benchmarks/ab/")
     args = ap.parse_args()
 
-    platform, err = resolve_platform()
-    if platform is None or platform == "cpu":
-        if err:
-            print(f"[allreduce] accelerator unavailable: {err}",
-                  file=sys.stderr)
-        os.environ["JAX_PLATFORMS"] = "cpu"
-
     import jax
 
-    if platform is None or platform == "cpu":
-        from deeplearning4j_tpu.utils import force_cpu_devices
-        force_cpu_devices(args.devices or 8)
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        # the environment pinned the CPU: a virtual mesh stands in
+        jax.config.update("jax_num_cpu_devices", args.devices or 8)
 
     import jax.numpy as jnp
     import numpy as np
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-0.6 jax spells it jax.experimental.shard_map
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     devs = jax.devices()

@@ -31,7 +31,6 @@ import sys
 
 _TRAIN_WORKER = r"""
 import json, os, sys, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 model, steps, batch, etl_ms = (sys.argv[1], int(sys.argv[2]),
@@ -119,7 +118,6 @@ print(json.dumps({
 
 _SERVE_WORKER = r"""
 import json, os, sys
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 batch_limit, req_size, n_req = (int(sys.argv[1]), int(sys.argv[2]),
@@ -165,7 +163,6 @@ print(json.dumps({
 
 def _run(worker: str, args, async_mode: str) -> dict:
     env = dict(os.environ, DL4J_TPU_ASYNC=async_mode)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-c", worker] + [str(a) for a in args],
         capture_output=True, text=True, env=env, check=True)

@@ -28,7 +28,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import resolve_platform  # noqa: E402 — shared TPU probe
 
 
 def build_frozen_bert(batch, seq, layers, hidden, heads, intermediate,
@@ -165,19 +164,15 @@ def main():
                     help="tiny CPU config (CI/dev)")
     args = ap.parse_args()
 
-    platform, err = resolve_platform(force_cpu=args.smoke)
-    if platform is None or platform == "cpu":
-        if err:
-            print(f"[bert-bench] accelerator unavailable: {err}",
-                  file=sys.stderr)
-        os.environ["JAX_PLATFORMS"] = "cpu"
-
     import jax
 
-    if platform is None or platform == "cpu":
+    if args.smoke:
         jax.config.update("jax_platforms", "cpu")
     platform = jax.devices()[0].platform
     on_tpu = platform != "cpu"
+    if not (on_tpu or args.smoke):
+        sys.exit("[bert-bench] no accelerator (platform=cpu); "
+                 "--smoke runs the tiny CPU config")
     print(f"[bert-bench] platform={platform}", file=sys.stderr, flush=True)
 
     if args.smoke or not on_tpu:

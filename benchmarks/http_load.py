@@ -250,7 +250,6 @@ def check_streaming(addr: str, prompt, n_new: int) -> dict:
 def run_inproc(args, rng) -> dict:
     """One in-process worker; interleaved HTTP-vs-direct classify
     windows give the drift-immune ``vs_direct`` ratio."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import numpy as np
 
     sys.path.insert(0, os.path.join(_REPO, "tools"))
@@ -349,7 +348,6 @@ def run_qos_drill(args, rng) -> dict:
     out. Acceptance: every victim's goodput holds >= 90% of its
     baseline and its p99 stays within 2x, while the flooder is shed
     (429 + Retry-After) at the door."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, os.path.join(_REPO, "tools"))
     import serve as _serve
 
@@ -732,7 +730,7 @@ def run_fleet_chaos(args, rng) -> dict:
     journals), leader terms strictly monotonic, rollout stage never
     regresses."""
     state_dir = args.state_dir or f"/tmp/dl4j-fleet-chaos-{os.getpid()}"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ)
     # the whole run breathes injected store faults (seeded per process)
     env["DL4J_TPU_FAULTS"] = args.fleet_faults
     proc = subprocess.Popen(
@@ -1063,7 +1061,7 @@ def run_fleet_obs(args, rng) -> dict:
     trace coverage >= 0.95, federation completeness == 1.0, the
     single-trace check, and the partial scrape staying 200."""
     state_dir = args.state_dir or f"/tmp/dl4j-fleet-obs-{os.getpid()}"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ)
     env.pop("DL4J_TPU_FLEET_OBS", None)      # the drill grades the ON path
     proc = subprocess.Popen(
         [sys.executable, os.path.join(_REPO, "tools", "serve.py"),
@@ -1275,7 +1273,7 @@ def run_trace_intel(args, rng) -> dict:
     must stay bounded.  Assembly latency p99 is reported, never gated
     (host weather)."""
     state_dir = args.state_dir or f"/tmp/dl4j-trace-intel-{os.getpid()}"
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    env = dict(os.environ,
                DL4J_TPU_TRACE_SAMPLE="0.1", DL4J_TPU_TRACE_TAIL_Q="0.9")
     env.pop("DL4J_TPU_FLEET_OBS", None)     # the drill grades the ON path
     env.pop("DL4J_TPU_TRACE_STORE", None)
@@ -1535,7 +1533,7 @@ def run_watchtower(args, rng) -> dict:
     cleanly after recovery)."""
     state_dir = args.state_dir or f"/tmp/dl4j-watchtower-{os.getpid()}"
     pm_dir = os.path.join(state_dir, "postmortem")
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    env = dict(os.environ,
                DL4J_TPU_POSTMORTEM_DIR=pm_dir,
                DL4J_TPU_WATCHTOWER_INTERVAL_S="0.2",
                DL4J_TPU_TIMESERIES_INTERVAL_S="0.2",
@@ -1897,7 +1895,7 @@ def run_session_failover(args, rng) -> dict:
     baselines = _session_baselines(prompts, n_new, args.slots)
 
     state_dir = args.state_dir or f"/tmp/dl4j-sess-drill-{os.getpid()}"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ)
     env.pop("DL4J_TPU_SESSIONS", None)       # the drill grades the ON path
     env["DL4J_TPU_SESSION_JOURNAL_STEPS"] = "1"
     env["DL4J_TPU_FAULTS"] = args.session_faults

@@ -79,7 +79,6 @@ import sys
 
 _WORKER = r"""
 import json, os, sys, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 from deeplearning4j_tpu.models import zoo
@@ -114,7 +113,6 @@ print(json.dumps({"seconds_per_step": wall / steps,
 #: critical path). Bar: elastic_async vs no_elastic < 2%.
 _ELASTIC_WORKER = r"""
 import json, os, sys, tempfile, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 from deeplearning4j_tpu.models import zoo
@@ -161,7 +159,6 @@ def _run_worker(script: str, args, overrides) -> float:
     instrument creation, so flipping them in-process would measure the
     wrong thing. Shared by both A/Bs."""
     env = dict(os.environ, **overrides)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-c", script] + [str(a) for a in args],
         capture_output=True, text=True, env=env, check=True)
@@ -234,7 +231,6 @@ def elastic_ab(steps: int, batch: int, repeats: int,
 #: pays the whole-program XLA compile on live traffic.
 _WARMUP_WORKER = r"""
 import json, os, sys, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 from deeplearning4j_tpu.models import zoo
@@ -265,7 +261,6 @@ print(json.dumps({"first_ms": first * 1e3,
 
 def _run_warmup(batch: int, mode: str) -> dict:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-c", _WARMUP_WORKER, mode, str(batch)],
         capture_output=True, text=True, env=env, check=True)
@@ -319,7 +314,6 @@ def warmup_ab(batch: int, repeats: int, as_json: bool) -> float:
 #: this A/B exists to bound.
 _FLEET_OBS_WORKER = r"""
 import json, os, sys, time, urllib.request
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
@@ -453,7 +447,6 @@ def trace_store_ab(steps: int, repeats: int, as_json: bool) -> float:
 #: the default cadence off the hot path — the cost this A/B bounds.
 _SESSION_WORKER = r"""
 import json, os, sys, tempfile, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import numpy as np
 
@@ -531,7 +524,6 @@ def session_ab(steps: int, repeats: int, as_json: bool) -> float:
 #: request path — the cost this A/B exists to bound.
 _WATCHTOWER_WORKER = r"""
 import json, os, sys, threading, time, urllib.request
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration

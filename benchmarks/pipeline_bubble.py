@@ -8,8 +8,8 @@ This harness measures a pipelined train step at fixed GLOBAL batch while
 sweeping M, reports per-step wall time, implied utilisation vs the best
 rung, and the theoretical bubble — one JSON line per M.
 
-Run (virtual mesh):  python benchmarks/pipeline_bubble.py
-     (on TPU pass --tpu and set stages to the real chip count)
+Run (virtual mesh):  JAX_PLATFORMS=cpu python benchmarks/pipeline_bubble.py
+     (on TPU set stages to the real chip count)
 """
 from __future__ import annotations
 
@@ -25,7 +25,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--tpu", action="store_true")
     ap.add_argument("--stages", type=int, default=4)
     ap.add_argument("--micro", type=int, nargs="*", default=[4, 8, 16, 32])
     ap.add_argument("--layers", type=int, default=8)
@@ -36,10 +35,10 @@ def main():
     ap.add_argument("--iters", type=int, default=4)
     args = ap.parse_args()
 
-    if not args.tpu:
-        from deeplearning4j_tpu.utils import force_cpu_devices
-        force_cpu_devices(max(8, args.stages))
     import jax
+
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        jax.config.update("jax_num_cpu_devices", max(8, args.stages))
     import jax.numpy as jnp
     import numpy as np
     import optax

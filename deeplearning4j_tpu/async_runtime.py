@@ -25,8 +25,10 @@ Knobs (env var → default):
 ``DL4J_TPU_PREFETCH_DEPTH``   ``2``    device batches buffered ahead of the step
 ``DL4J_TPU_SCORE_EVERY``      ``16``   steps between loss materializations
 ``DL4J_TPU_INFLIGHT``         ``2``    serving batches dispatched but uncompleted
-``DL4J_TPU_COMPILE_CACHE``    unset    persistent XLA compile-cache directory
 ============================  =======  ==========================================
+
+The persistent XLA compile cache has no knob of its own: jax's
+``JAX_COMPILATION_CACHE_DIR`` places it, see :func:`configure_compile_cache`.
 
 Because the async pipelines are exactly what a hung run was doing when it
 hung, :func:`snapshot` returns every live knob value — the flight recorder
@@ -82,64 +84,59 @@ def inflight_limit() -> int:
     return _int_env("DL4J_TPU_INFLIGHT", 2)
 
 
-def compile_cache_dir():
-    """``DL4J_TPU_COMPILE_CACHE``: persistent XLA compilation-cache
-    directory (unset/empty = no persistent cache). Serving deploys call
-    :func:`configure_compile_cache` so re-deploys and restarts retrieve
-    executables from disk instead of recompiling them."""
-    return os.environ.get("DL4J_TPU_COMPILE_CACHE") or None
+#: where compiles persist when the environment does not say. A FIXED path
+#: inside the checkout: the directory is part of jax's cache key, so one
+#: named after a state dir, a pid or a time never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+_cache_configured = False
 
 
-_cache_dir_applied = None
+def configure_compile_cache() -> str:
+    """The ONE place the persistent compilation cache is placed; every
+    entry point that compiles calls it once at start (idempotent). Returns
+    the directory in force.
 
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads it, and the
+    program sets no directory in code. Unset: ``DEFAULT_COMPILE_CACHE_DIR``.
+    The min-compile-time / min-entry-size gates are zeroed so every
+    serving-bucket executable is eligible (the point is skipping the
+    small-but-many bucket compiles). Failures propagate: an entry point
+    that believes its compiles persist when they do not pays every cold
+    start in full and never says so."""
+    global _cache_configured
+    import jax
 
-def configure_compile_cache():
-    """Idempotently point jax's persistent compilation cache at
-    ``DL4J_TPU_COMPILE_CACHE``. Returns the directory in force (None =
-    persistent caching off). The min-compile-time / min-entry-size gates
-    are zeroed so every serving-bucket executable is eligible — the whole
-    point is skipping the small-but-many bucket compiles, and the CPU
-    test meshes compile fast enough that the 1 s default would exclude
-    everything."""
-    global _cache_dir_applied
-    path = compile_cache_dir()
-    if path is None or path == _cache_dir_applied:
-        return _cache_dir_applied
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        for knob, value in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, value)
-            except Exception:      # older jax without the gate: fine
-                pass
-        try:
-            # jax memoizes its cache decision at the FIRST backend
-            # compile; a deploy that follows model-init compiles (the
-            # normal order) would otherwise never engage the dir. The
-            # reset drops only that memo — jit dispatch caches survive.
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
-        _cache_dir_applied = path
-    except Exception:              # cache is an optimization, never fatal
-        return None
-    return _cache_dir_applied
+    if not _cache_configured:
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir",
+                              DEFAULT_COMPILE_CACHE_DIR)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # jax memoizes its cache decision at the FIRST backend compile; a
+        # caller that compiled before configuring (model init, the normal
+        # order) would otherwise never engage the dir. The reset drops only
+        # that memo — jit dispatch caches survive.
+        from jax._src import compilation_cache as _cc
+        _cc.reset_cache()
+        _cache_configured = True
+    return jax.config.jax_compilation_cache_dir
 
 
 def snapshot() -> dict:
     """Every live knob value — the async-runtime half of a postmortem
     bundle (a hang report without the pipeline depths that shaped the hang
     is not actionable)."""
+    import jax
+
     out = {
         "async_enabled": async_enabled(),
         "prefetch_depth": prefetch_depth(),
         "score_sync_every": score_sync_every(),
         "inflight_limit": inflight_limit(),
-        "compile_cache_dir": compile_cache_dir(),
+        # the directory in force (None until an entry point placed it)
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
     }
     try:
         # the observatory switches shape what a wedged step was computing

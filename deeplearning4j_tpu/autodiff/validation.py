@@ -17,11 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    _enable_x64 = jax.enable_x64
-except AttributeError:      # pre-0.5 jax: experimental home, same semantics
-    from jax.experimental import enable_x64 as _enable_x64
-
 
 def grad_check(fn: Callable, params, epsilon: float = 1e-5, max_rel_error: float = 1e-3,
                min_abs_error: float = 1e-8, subset: int = None, seed: int = 0) -> bool:
@@ -31,7 +26,7 @@ def grad_check(fn: Callable, params, epsilon: float = 1e-5, max_rel_error: float
     float64 on CPU (enable_x64 scope) — matching the reference's
     double-precision gradcheck requirement.
     """
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         params64 = jax.tree.map(lambda p: jnp.asarray(np.asarray(p), jnp.float64), params)
         analytic = jax.grad(fn)(params64)
 
@@ -68,7 +63,7 @@ def grad_check(fn: Callable, params, epsilon: float = 1e-5, max_rel_error: float
 def check_vjp(fn: Callable, *primals, atol: float = 1e-4, rtol: float = 1e-4, eps: float = 1e-4) -> bool:
     """Cheap directional check: FD directional derivative vs JVP, plus
     VJP/JVP inner-product consistency <J v, u> == <v, J^T u>."""
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         primals64 = jax.tree.map(lambda p: jnp.asarray(np.asarray(p), jnp.float64), primals)
         rng = np.random.default_rng(0)
         tangents = jax.tree.map(lambda p: jnp.asarray(rng.normal(size=p.shape)), primals64)

@@ -8,7 +8,7 @@ which is what makes the long-context path (SURVEY 5.7) viable per chip.
 
 Design:
 - forward: Pallas kernel, one grid cell per (batch·head, q-block); runs in
-  interpret mode off-TPU so tests exercise the same code path everywhere.
+  interpret mode off-TPU (only there) so tests exercise the same code path.
 - backward: custom_vjp recomputing per k-block inside a lax.scan (standard
   flash backward), fully fused by XLA — no (T, T) residuals are saved.
 """
@@ -22,12 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# this container's jax 0.4.x spells it TPUCompilerParams; newer jax renamed
-# it to CompilerParams — accept either (same repair family as the
-# shard_map/jax_num_cpu_devices fallbacks from the observability PR)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 # 512x1024 tiles: hardware-measured best on v5e (2026-07-31 crossover
 # sweep, benchmarks/flash_crossover.py — beat 256/512 at every T probed,
@@ -143,7 +137,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -204,16 +198,24 @@ def _bwd_blockwise(q, k, v, o, lse, do, scale, causal, block_k):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+def _interpret() -> bool:
+    """Interpret mode only where there is no Mosaic compiler (the CPU
+    tests). On a TPU backend the kernel always compiles — a kernel that
+    does not is an error there, never an interpreted or XLA fallback
+    (``chip_smoke.py`` asserts the Mosaic call from the lowering)."""
+    return jax.default_backend() != "tpu"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, scale, causal, block_q, block_k):
-    interpret = jax.default_backend() != "tpu"
-    o, _ = _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret)
+    o, _ = _fwd_pallas(q, k, v, scale, causal, block_q, block_k,
+                       _interpret())
     return o
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    interpret = jax.default_backend() != "tpu"
-    o, lse = _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret)
+    o, lse = _fwd_pallas(q, k, v, scale, causal, block_q, block_k,
+                         _interpret())
     return o, (q, k, v, o, lse)
 
 

@@ -25,9 +25,8 @@ key so a run is reproducible from its seed.
 Attention backends: prefill routes through the model's normal policy
 (flash kernel eligible — ``DL4J_TPU_ATTN_BACKEND`` forces ``xla`` or
 ``flash``); the decode step is XLA-native single-query attention and
-NEVER consults the Pallas capability probe — a per-token probe would
-dominate decode latency (pinned by a test counting ``_flash_lowers``
-calls across steps).
+never reaches the attention-backend policy (pinned by a test counting
+``_use_flash_attention`` calls across a decode trace).
 
 ``naive_generate`` is the honest O(T²) baseline the decode benchmark
 A/Bs against: re-run the full forward over the (fixed-padded) sequence
@@ -584,7 +583,7 @@ class DecodeEngine:
     def _quant_active(self) -> bool:
         """int8 storage is live only after the deploy/warmup-time
         numerics gate passes; a failed gate falls back to f32 pages
-        with a loud warning (the flash-kernel probe pattern)."""
+        with a loud warning."""
         if not self.kv_quant:
             return False
         if self.quant_gate is None:

@@ -1,13 +1,16 @@
 """ctypes bindings for the native host-ops library.
 
 The JavaCPP-preset analog (SURVEY N10): a thin binding layer over a flat C
-ABI (``src/host_ops.cpp``). The library is built on demand with ``make``
-(g++); every function has a pure-numpy fallback so the package works
-without a toolchain — ``is_native()`` reports which path is live.
+ABI (``src/host_ops.cpp``). The library is built on first use with ``make``
+(g++) from the committed sources — no binary is committed or shipped, and
+a binary the build could not (re)produce is never loaded. Every function
+has a pure-numpy fallback so the package works without a toolchain —
+``is_native()`` reports which path is live.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -16,10 +19,21 @@ from typing import Optional, Tuple
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_DIR, "libdl4jtpu_host.so")
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+
+
+def _build(target: str) -> str:
+    """(Re)build one Makefile target from the sources on disk and return
+    its path. Always invokes make: a no-op when fresh, a rebuild after
+    source edits. Raises when the build fails, even if an old binary is
+    lying around — a binary that does not come from the sources on disk
+    must not decide what runs."""
+    subprocess.run(["make", "-C", _DIR, target], check=True,
+                   capture_output=True, timeout=120)
+    return os.path.join(_DIR, target)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -27,18 +41,11 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        # always invoke make: it's a no-op when fresh and rebuilds after
-        # source edits (stale-.so bugs are silent otherwise)
         try:
-            subprocess.run(["make", "-C", _DIR], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
-            if not os.path.exists(_LIB_PATH):
-                _build_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+            lib = ctypes.CDLL(_build("libdl4jtpu_host.so"))
+        except (OSError, subprocess.SubprocessError) as e:
+            _log.warning("native host-ops library not built (%r); using "
+                         "the numpy fallback", e)
             _build_failed = True
             return None
         lib.threshold_encode.restype = ctypes.c_int64

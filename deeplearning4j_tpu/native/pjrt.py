@@ -12,15 +12,14 @@ from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
 import sysconfig
 from typing import Optional, Sequence
 
 import numpy as np
 
-from deeplearning4j_tpu.native import _load as _load_host  # triggers make
+from deeplearning4j_tpu.native import _build
 
-_DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_DIR, "libdl4jtpu_pjrt.so")
 _ERRLEN = 4096
 
 
@@ -31,11 +30,11 @@ def default_tpu_plugin_path() -> Optional[str]:
 
 
 def _lib() -> ctypes.CDLL:
-    _load_host()          # runs make (builds both .so targets)
-    if not os.path.exists(_LIB_PATH):
+    try:
+        lib = ctypes.CDLL(_build("libdl4jtpu_pjrt.so"))
+    except (OSError, subprocess.SubprocessError) as e:
         raise RuntimeError(
-            "libdl4jtpu_pjrt.so not built (pjrt_c_api.h unavailable?)")
-    lib = ctypes.CDLL(_LIB_PATH)
+            "libdl4jtpu_pjrt.so not built (pjrt_c_api.h unavailable?)") from e
     lib.nd4j_pjrt_load_plugin.restype = ctypes.c_void_p
     lib.nd4j_pjrt_load_plugin.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
                                           ctypes.c_int]

@@ -377,11 +377,8 @@ def _ensure_listener() -> None:
         if _listener_registered:
             return
         _listener_registered = True
-    try:
-        import jax.monitoring as _mon
-        _mon.register_event_duration_secs_listener(_on_compile_duration)
-    except Exception:       # older jax without the API: counts still work
-        pass
+    import jax.monitoring as _mon
+    _mon.register_event_duration_secs_listener(_on_compile_duration)
 
 
 # cost-model AOT re-lowerings re-enter the jitted bodies on a jaxpr-cache

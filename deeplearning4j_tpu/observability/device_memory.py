@@ -43,12 +43,24 @@ _last_sample_mono = 0.0
 _unsupported = False
 
 
-def _stats_per_device() -> List[tuple]:
-    """[(device, stats-dict)] for devices that report stats."""
+def initialized_devices() -> list:
+    """This process's devices, WITHOUT initializing a backend: empty in a
+    process that has run nothing on jax. A chip belongs to one process, so
+    an observer (a postmortem bundle, a stats page, the ``tools/serve.py``
+    proxy parent) must never be the call that takes it from a worker."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return []
     import jax
 
+    return jax.devices()
+
+
+def _stats_per_device(devices) -> List[tuple]:
+    """[(device, stats-dict)] for the devices that report stats."""
     out = []
-    for d in jax.devices():
+    for d in devices:
         try:
             stats = d.memory_stats()
         except Exception:
@@ -70,10 +82,12 @@ def sample(min_interval_s: Optional[float] = None) -> bool:
         if now - _last_sample_mono < interval:
             return False
         _last_sample_mono = now
-    per_dev = _stats_per_device()
+    devices = initialized_devices()
+    per_dev = _stats_per_device(devices)
     if not per_dev:
-        # nothing on this backend reports (CPU test mesh) — stop asking
-        _unsupported = True
+        # nothing on this backend reports (CPU test mesh) — stop asking;
+        # a process with no backend yet may still get one that does
+        _unsupported = bool(devices)
         return False
     gauge = global_registry().gauge(
         "dl4j_device_memory_bytes",
@@ -91,14 +105,8 @@ def sample(min_interval_s: Optional[float] = None) -> bool:
 
 def snapshot() -> dict:
     """Unthrottled point-in-time view for postmortem bundles."""
-    import jax
-
     devices = []
-    try:
-        devs = jax.devices()
-    except Exception as e:
-        return {"error": repr(e)}
-    for d in devs:
+    for d in initialized_devices():
         try:
             stats = d.memory_stats()
         except Exception:

@@ -39,9 +39,8 @@ _MULTIPROC_PROBE: Optional[Tuple[bool, str]] = None
 _PROBE_SCRIPT = r"""
 import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
+os.environ["JAX_NUM_CPU_DEVICES"] = "1"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address="127.0.0.1:" + sys.argv[2],
                            num_processes=2, process_id=int(sys.argv[1]))
 import numpy as np
@@ -86,8 +85,6 @@ def multiprocess_cpu_collectives_supported(
     port = s.getsockname()[1]
     s.close()
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)       # the probe pins its own platform
-    env.pop("XLA_FLAGS", None)
     procs = [subprocess.Popen(
         [sys.executable, "-c", _PROBE_SCRIPT, str(i), str(port)],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,

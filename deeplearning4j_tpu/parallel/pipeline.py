@@ -39,19 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:         # pre-0.6 jax: experimental home, same signature
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-import inspect as _inspect
-
-# "skip the replication/varying-type check" kwarg was renamed across jax
-# versions (check_rep -> check_vma); resolve the spelling once
-_NO_CHECK = ({"check_vma": False}
-             if "check_vma" in _inspect.signature(shard_map).parameters
-             else {"check_rep": False})
 
 from deeplearning4j_tpu.parallel.mesh import STAGE_AXIS, axis_size
 
@@ -153,7 +142,7 @@ def gpipe(stage_fn: Callable, mesh: Mesh, num_stages: Optional[int] = None,
                        + [None] * (x_micro.ndim - 2))) \
             if batch_axis else P(STAGE_AXIS)
         f = shard_map(local, mesh=mesh, in_specs=(pspecs, act_spec),
-                      out_specs=act_spec, **_NO_CHECK)
+                      out_specs=act_spec, check_vma=False)
         out = f(stacked_params, x_micro)
         return out[:M] if pad else out
 
@@ -260,7 +249,7 @@ def pipeline_trunk_1f1b(stage_fn: Callable, mesh: Mesh,
             if batch_axis else P()
         f = shard_map(bwd_local, mesh=mesh,
                       in_specs=(pspecs, aspec, aspec),
-                      out_specs=(pspecs, aspec), **_NO_CHECK)
+                      out_specs=(pspecs, aspec), check_vma=False)
         return f(stacked_params, x_micro, dy)
 
     trunk.defvjp(trunk_fwd, trunk_bwd)

@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:         # pre-0.6 jax: experimental home, same signature
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import (
@@ -59,11 +56,10 @@ def _block_attn_update(q, k, v, m, l, o, q_start, k_start, causal, scale):
 
 
 def _ring_attention_local(q, k, v, axis_name: str, causal: bool,
-                          p_size: int, vary_axes=()):
-    """Per-shard body under shard_map. q/k/v: (B, T/P, H, D) local blocks.
-    ``p_size`` is passed statically by the caller (from the mesh): older jax
-    has no ``lax.axis_size`` and the ring-unroll needs a concrete int."""
+                          vary_axes=()):
+    """Per-shard body under shard_map. q/k/v: (B, T/P, H, D) local blocks."""
     my_idx = lax.axis_index(axis_name)
+    p_size = lax.axis_size(axis_name)
     b, tq, h, d = q.shape
     tk = k.shape[1]
     scale = 1.0 / np.sqrt(d)
@@ -73,11 +69,9 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool,
     o0 = jnp.zeros((b, tq, h, d), jnp.float32)
     # mark accumulators device-varying over every axis the block inputs vary
     # on, so the fori_loop carry type matches the body output (shard_map vma
-    # typing; pre-vma jax has no pcast and needs no marking)
-    if hasattr(lax, "pcast"):
-        vary = tuple(vary_axes) or (axis_name,)
-        m0, l0, o0 = (lax.pcast(a, vary, to="varying")
-                      for a in (m0, l0, o0))
+    # typing)
+    vary = tuple(vary_axes) or (axis_name,)
+    m0, l0, o0 = (lax.pcast(a, vary, to="varying") for a in (m0, l0, o0))
     perm = [(j, (j + 1) % p_size) for j in range(p_size)]
 
     def body(i, carry):
@@ -111,17 +105,10 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = SEQ_AXIS,
                and q.shape[2] % axis_size(mesh, MODEL_AXIS) == 0 else None)
     spec = P(batch_ax, seq_axis, head_ax, None)
     vary = tuple(a for a in (batch_ax, seq_axis, head_ax) if a is not None)
-    kw = {}
-    if not hasattr(lax, "pcast"):
-        # pre-vma jax can't express "carry becomes device-varying in the
-        # loop body" — its replication checker rejects the ring accumulators,
-        # so disable it (the modern path proves the same property via pcast)
-        kw["check_rep"] = False
     fn = shard_map(
         functools.partial(_ring_attention_local, axis_name=seq_axis,
-                          causal=causal, p_size=axis_size(mesh, seq_axis),
-                          vary_axes=vary),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **kw)
+                          causal=causal, vary_axes=vary),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)
 
 

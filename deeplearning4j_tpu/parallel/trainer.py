@@ -44,10 +44,7 @@ from deeplearning4j_tpu.parallel.sharding import replicate_tree, tp_shardings
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax spells it jax.experimental.shard_map
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -435,7 +432,7 @@ class ShardedTrainer:
                 in_specs=(P(), P(), P(DATA_AXIS, None), P(), P(DATA_AXIS),
                           P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), P()),
                 out_specs=(P(), P(), P(), P(DATA_AXIS, None), P(), P()),
-                check_rep=False)
+                check_vma=False)
             loss, new_states, decoded, new_res, new_thr, stats = sm(
                 params, states, residual, thresholds, x, y, fmask, lmask,
                 rng)

@@ -177,10 +177,15 @@ class ElasticCapacity:
             self._good_steps = 0
 
     def snapshot(self) -> dict:
+        # an observer's view (bundles, /debug/elastic): never the call
+        # that initializes a backend — 0 devices in a process with none
+        from deeplearning4j_tpu.observability.device_memory import (
+            initialized_devices)
+        total = len(initialized_devices())
         with self._lock:
             lost, good = self._lost, self._good_steps
-        return {"total_devices": self.total(), "lost": lost,
-                "available": max(1, self.total() - lost),
+        return {"total_devices": total, "lost": lost,
+                "available": max(1, total - lost),
                 "good_steps_since_loss": good,
                 "recover_steps": recover_steps()}
 

@@ -15,8 +15,9 @@ Three modules:
 - :mod:`~deeplearning4j_tpu.serving.registry` — :class:`ModelRegistry`:
   ``deploy(version, net)`` builds a ``ParallelInference`` per version and
   AOT-warms every shape-bucket executable before the version is eligible
-  for traffic (persistent compile cache under ``DL4J_TPU_COMPILE_CACHE``
-  makes re-deploys and restarts skip compilation entirely);
+  for traffic (the persistent compile cache placed by
+  ``async_runtime.configure_compile_cache`` makes re-deploys and restarts
+  skip compilation entirely);
   ``deploy_generative(version, engine)`` does the same for a generative
   decode version — a ``GenerationPipeline`` whose prefill, slot-insert,
   and decode-step executables all warm before traffic;

@@ -17,12 +17,12 @@ the same best-effort attribution the bucket-miss path uses); the
 ``suppress_probes()`` spelling is reserved for lowerings that compile
 nothing (cost_model), which warmup is not.
 
-Persistent compile cache: when ``DL4J_TPU_COMPILE_CACHE`` names a
-directory, deploy wires jax's persistent compilation cache at it first
-(:func:`async_runtime.configure_compile_cache`), so a re-deploy of a
-known version — or a process restart — retrieves every bucket executable
-from disk instead of compiling (asserted by the tier-1 cache test via
-jax's ``compilation_cache/cache_hits`` event).
+Persistent compile cache: deploy places jax's persistent compilation
+cache first (:func:`async_runtime.configure_compile_cache` — at
+``JAX_COMPILATION_CACHE_DIR`` if set, else a fixed path in the checkout),
+so a re-deploy of a version after a process restart retrieves the warmed
+executables from disk instead of compiling (asserted by the tier-1 cache
+test via jax's ``compilation_cache/cache_hits`` event).
 
 Retire goes through **graceful drain**: the version stops admitting, the
 router's in-flight requests complete (bounded wait on the version's
@@ -39,6 +39,7 @@ import time
 import weakref
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from deeplearning4j_tpu import async_runtime as _async
@@ -348,4 +349,4 @@ class ModelRegistry:
             versions = [dv.snapshot() for _, dv in sorted(
                 self._versions.items())]
         return {"versions": versions,
-                "compile_cache_dir": _async.compile_cache_dir()}
+                "compile_cache_dir": jax.config.jax_compilation_cache_dir}

@@ -2,35 +2,6 @@
 from deeplearning4j_tpu.utils.serialization import ModelSerializer
 
 
-def force_cpu_devices(n: int = 8):
-    """Virtual n-device CPU backend, portable across jax versions: newer
-    jax has the ``jax_num_cpu_devices`` config; older jax only honors
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — which is read
-    at (lazy) backend init, so this works even after ``import jax`` as long
-    as no device has been touched yet. Benchmarks/examples/tests share this
-    instead of hand-rolling the dance."""
-    import os
-    import re
-
-    import jax
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    want = f"--xla_force_host_platform_device_count={n}"
-    if "xla_force_host_platform_device_count" in flags:
-        # rewrite, don't keep: a stale different count would win on jax
-        # versions that only read the env var
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
-                       want, flags)
-    else:
-        flags = (flags + " " + want).strip()
-    os.environ["XLA_FLAGS"] = flags
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        pass
-
-
 def strengthen_dtypes(tree):
     """Strip jax weak_type from every leaf (lax.convert_element_type to the
     same dtype). Weak-typed leaves (e.g. ``jnp.full(shape, 0.0)`` biases)

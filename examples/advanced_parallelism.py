@@ -2,20 +2,10 @@
 expert (Switch-MoE) parallelism, plus ring attention for long sequences.
 
 Run on a virtual mesh:
-  python examples/advanced_parallelism.py
+  JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8 python examples/advanced_parallelism.py
 (on a real TPU slice the same code shards over the physical chips)
 """
-import os
-
 import jax
-
-# default to a virtual 8-device CPU mesh; export DL4J_TPU_EXAMPLES_TPU=1 on
-# a real slice. (Don't probe jax.default_backend() here — that would
-# initialize the backend before the config can be changed.)
-if not os.environ.get("DL4J_TPU_EXAMPLES_TPU"):
-    from deeplearning4j_tpu.utils import force_cpu_devices
-    force_cpu_devices(8)
-
 import jax.numpy as jnp
 import numpy as np
 

@@ -2,11 +2,6 @@
 conf.layers.PrimaryCapsules/CapsuleLayer/CapsuleStrengthLayer).
 
 Dynamic routing runs unrolled inside the one jitted train step."""
-import jax
-
-if jax.default_backend() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 from deeplearning4j_tpu.data.mnist import MnistDataSetIterator
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.inputs import InputType

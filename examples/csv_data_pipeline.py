@@ -12,10 +12,6 @@ import argparse
 import tempfile
 from pathlib import Path
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 

@@ -3,7 +3,7 @@ analog (BASELINE config[4] shape, one slice).
 
 On a multi-chip TPU slice this shards batches over all chips with GSPMD
 allreduce; on CPU it runs on a virtual 8-device mesh:
-  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8 \
       python examples/distributed_data_parallel.py
 """
 import numpy as np

@@ -16,10 +16,6 @@ import zipfile
 
 import numpy as np
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 from deeplearning4j_tpu.modelimport import dl4j_zip
 from deeplearning4j_tpu.utils.serialization import ModelSerializer
 from deeplearning4j_tpu.data.dataset import DataSet

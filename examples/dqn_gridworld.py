@@ -3,11 +3,6 @@
 The Q-network, target sync, and replay sampling all run inside one jitted
 train step; the environment loop stays host-side (the reference's
 Learning/ExpReplay split maps to host env + device step)."""
-import jax
-
-if jax.default_backend() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 from deeplearning4j_tpu.rl.mdp import GridWorld
 from deeplearning4j_tpu.rl.qlearning import (QLearningConfiguration,
                                              QLearningDiscreteDense)

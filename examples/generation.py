@@ -18,8 +18,6 @@ Run: python examples/generation.py
 import os
 import sys
 
-if os.environ.get("DL4J_TPU_EXAMPLES_TPU") != "1":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import json

@@ -23,12 +23,8 @@ Every request here is a real socket round-trip — the same surface
 
 Run: python examples/http_serving.py
 """
-import os
-
-if os.environ.get("DL4J_TPU_EXAMPLES_TPU") != "1":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import json
+import os
 import sys
 import time
 import urllib.request

@@ -10,10 +10,6 @@ Run: python examples/hyperparameter_search.py [--candidates N]
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 

@@ -1,10 +1,9 @@
 """Round-2 TPU extensions in one place: bf16 mixed precision, gradient
 checkpointing (rematerialisation), and orbax sharded checkpoints.
 
-Run: python -c "from deeplearning4j_tpu.utils import force_cpu_devices;
-force_cpu_devices(8); import runpy;
-runpy.run_path('examples/mixed_precision_checkpointing.py',
-run_name='__main__')"
+Run on a virtual mesh:
+  JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8 \
+      python examples/mixed_precision_checkpointing.py
 """
 import os
 import tempfile

@@ -2,11 +2,6 @@
 for any exported .onnx file) and fine-tune it through `sd.fit`.
 
 ref analog: samediff-import-onnx usage in dl4j-examples."""
-import jax
-
-if jax.default_backend() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 from deeplearning4j_tpu.autodiff.samediff import TrainingConfig

@@ -15,13 +15,11 @@ and ``pipeline_schedule`` picks how the backward runs:
   micro-batch count; see benchmarks/RESULTS.md).
 
 Both produce the same gradients (tests/test_parallel.py::Test1F1B).
+
+Needs 8 devices; on a virtual mesh:
+  JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8 python examples/pipeline_1f1b.py
 """
 import jax
-
-from deeplearning4j_tpu.utils import force_cpu_devices
-
-force_cpu_devices(8)
-
 import jax.numpy as jnp
 import numpy as np
 import optax

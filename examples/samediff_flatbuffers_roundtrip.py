@@ -10,10 +10,6 @@ as a Jackson-style JSON string.
 import os
 import tempfile
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 from deeplearning4j_tpu.autodiff.samediff import SameDiff, TrainingConfig

@@ -5,15 +5,11 @@ TrainingMaster facade builds a device mesh and GSPMD emits the gradient
 allreduce over ICI (multi-host: bootstrap each process with
 DistributedConfig first — see tests/test_multihost.py).
 
-Run on a virtual mesh:  python examples/shared_training_tinyimagenet.py
+Run on a virtual mesh:
+  JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8 \
+      python examples/shared_training_tinyimagenet.py
 """
-import os
-
 import jax
-
-if not os.environ.get("DL4J_TPU_EXAMPLES_TPU"):
-    from deeplearning4j_tpu.utils import force_cpu_devices
-    force_cpu_devices(8)
 
 from deeplearning4j_tpu.data import TinyImageNetDataSetIterator
 from deeplearning4j_tpu.models import zoo

@@ -11,10 +11,6 @@ Run: python examples/ui_monitoring.py [--steps N] [--port P]
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 

@@ -14,11 +14,6 @@ Watch it live: the UIServer's ``/debug/deploy`` names the stage, share,
 and SLO verdicts at every step; ``/metrics`` carries the per-version
 series. Run: python examples/versioned_serving.py
 """
-import os
-
-if os.environ.get("DL4J_TPU_EXAMPLES_TPU") != "1":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import json
 import urllib.request
 

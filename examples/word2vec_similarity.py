@@ -2,11 +2,6 @@
 
 The SGNS hot loop — the reference's native sg/cbow op (SURVEY D15/N3) —
 runs as one fused batched jax program per epoch chunk."""
-import jax
-
-if jax.default_backend() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 from deeplearning4j_tpu.nlp.sentence import CollectionSentenceIterator
 from deeplearning4j_tpu.nlp.word2vec import Word2Vec
 

@@ -39,7 +39,6 @@ def run_in_subprocess(test_fn):
         env = dict(os.environ)
         env[_CHILD_ENV] = "1"
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        # APPEND to PYTHONPATH (the container's sitecustomize dir must stay)
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         r = subprocess.run(
             [sys.executable, "-m", "pytest", nodeid, "-x", "-q", "-rs",

@@ -46,16 +46,9 @@ def drill_main(argv):
     args = ap.parse_args(argv)
 
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices}")
+    os.environ["JAX_NUM_CPU_DEVICES"] = str(args.devices)
 
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", args.devices)
-    except AttributeError:
-        pass  # pre-0.5 jax: the XLA_FLAGS env var above handles it
 
     import jax.numpy as jnp
 
@@ -121,15 +114,9 @@ def legacy_multihost_main():
     die_at = int(sys.argv[7]) if len(sys.argv) > 7 else -1
 
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    os.environ["JAX_NUM_CPU_DEVICES"] = "2"
 
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass  # pre-0.5 jax: the XLA_FLAGS env var above handles it
 
     from deeplearning4j_tpu.parallel.master import DistributedConfig
 

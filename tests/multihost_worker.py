@@ -49,15 +49,9 @@ def main():
     out_path = sys.argv[4]
 
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    os.environ["JAX_NUM_CPU_DEVICES"] = "2"
 
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass  # pre-0.5 jax: the XLA_FLAGS env var above handles it
 
     from deeplearning4j_tpu.parallel.master import DistributedConfig
 

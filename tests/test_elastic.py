@@ -466,7 +466,7 @@ class TestElasticTrainer:
 def _run_drill(args, timeout=300):
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)
-    env.pop("XLA_FLAGS", None)
+    env.pop("JAX_NUM_CPU_DEVICES", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     p = subprocess.run([sys.executable, WORKER, "drill"] + args,
                        capture_output=True, text=True, env=env,

@@ -623,3 +623,56 @@ def test_fleet_chaos_drill_end_to_end(tmp_path):
     assert rec["demotions"] >= 1             # the woken leader demoted
     assert rec["corruptions"] >= 1           # the doc was quarantined
     assert rec["respawned"] is True
+
+
+# ---------------------------------------------------------------------------
+# one process for each chip: the fleet parent never initializes a backend
+
+_FLEET_PARENT = r"""
+import json, os, sys, tempfile, urllib.error, urllib.request
+sys.path.insert(0, os.path.join(sys.argv[1], "tools"))
+import serve                                   # what run_fleet's parent runs
+import deeplearning4j_tpu, deeplearning4j_tpu.serving
+import deeplearning4j_tpu.models.generation, deeplearning4j_tpu.models.transformer
+import deeplearning4j_tpu.parallel.generation
+from deeplearning4j_tpu.observability.federation import FleetAdminServer
+from deeplearning4j_tpu.observability.flight_recorder import FlightRecorder
+from deeplearning4j_tpu.serving import SharedStore
+
+state = tempfile.mkdtemp()
+serve._ProxyMetrics.get()
+admin = FleetAdminServer(SharedStore(state), host="127.0.0.1", port=0,
+                         local_worker="proxy").start()
+codes = {}
+for path in ("/metrics", "/metrics/fleet", "/health/fleet", "/alerts/fleet",
+             "/debug/proxy", "/debug/alerts", "/debug/timeseries",
+             "/debug/trace"):
+    try:
+        with urllib.request.urlopen(admin.get_address() + path,
+                                    timeout=20) as r:
+            codes[path] = r.status
+    except urllib.error.HTTPError as e:
+        codes[path] = e.code
+admin.stop()
+# an incident fan-out makes every process that sees it dump a bundle
+bundle = FlightRecorder(out_dir=state).dump("incident:drill")
+from jax._src import xla_bridge
+print(json.dumps({"backends_initialized":
+                  xla_bridge.backends_are_initialized(),
+                  "codes": codes, "bundle": sorted(os.listdir(bundle))}))
+"""
+
+
+def test_fleet_parent_never_initializes_a_jax_backend():
+    """A chip belongs to one process. The ``tools/serve.py`` parent imports
+    the package, serves the admin routes and may dump an incident bundle —
+    none of which may touch ``jax.devices()``, or the proxy would take a
+    chip from a worker."""
+    r = subprocess.run([sys.executable, "-c", _FLEET_PARENT, _REPO],
+                       capture_output=True, text=True, timeout=120,
+                       env=dict(os.environ, PYTHONPATH=_REPO))
+    assert r.returncode == 0, r.stdout + r.stderr
+    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    assert rec["backends_initialized"] is False
+    assert rec["codes"]["/metrics"] == 200
+    assert "config.json" in rec["bundle"]       # the dump did run

@@ -178,23 +178,25 @@ def test_zero_steady_state_decode_recompiles():
     assert before == after, f"steady-state retraced: {before} -> {after}"
 
 
-def test_decode_path_never_consults_flash_probe(monkeypatch):
-    """The Pallas capability probe must never run per decode step (a
-    per-token probe would dominate decode latency): steady-state decode
-    calls ``_flash_lowers`` exactly zero times, and the process-wide
-    cache means even prefill consults it at most once per trace."""
+def test_decode_path_never_reaches_flash_policy(monkeypatch):
+    """The decode step is XLA single-query attention: tracing and running
+    it never consults the attention-backend policy, so no Pallas kernel
+    can land in a decode executable. Prefill consults it at trace time."""
     calls = {"n": 0}
-    real = _tr._flash_lowers
+    real = _tr._use_flash_attention
 
-    def counting():
+    def counting(seq_len=None):
         calls["n"] += 1
-        return real()
+        return real(seq_len)
 
-    monkeypatch.setattr(_tr, "_flash_lowers", counting)
-    eng = _engine()
-    eng.generate(_prompt(5)[None], 8)       # warm (cached executables)
+    monkeypatch.setattr(_tr, "_use_flash_attention", counting)
+    m, p = _model()
+    eng = DecodeEngine(m, p, max_len=48)    # fresh jits: every call traces
+    first, _logits, kv, t = eng.prefill(_prompt(5)[None])
+    assert calls["n"] >= 1
     calls["n"] = 0
-    eng.generate(_prompt(5)[None], 8)       # pure steady state
+    state = eng.insert_slot(eng.new_state(1), kv, 0)
+    eng.decode(state, np.asarray(first), np.full((1,), t, np.int32), 1)
     assert calls["n"] == 0
 
 

@@ -80,7 +80,7 @@ def _clean_env():
     env = dict(os.environ)
     # the workers pick their own platform/devices; scrub the conftest pins
     env.pop("JAX_PLATFORMS", None)
-    env.pop("XLA_FLAGS", None)
+    env.pop("JAX_NUM_CPU_DEVICES", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     return env
 
@@ -117,13 +117,8 @@ def test_n_process_training_matches_single_process(
         [sys.executable, "-c", f"""
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
+os.environ["JAX_NUM_CPU_DEVICES"] = "{ndev}"
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", {ndev})
-except AttributeError:
-    pass  # pre-0.5 jax: the XLA_FLAGS env var above handles it
 import numpy as np
 import sys
 sys.path.insert(0, {REPO!r})

@@ -42,11 +42,10 @@ def test_bad_plugin_path_clean_error():
 
 def test_non_pjrt_library_clean_error():
     # a real .so without GetPjrtApi: the host-ops library itself
-    from deeplearning4j_tpu.native import _LIB_PATH
-    if not os.path.exists(_LIB_PATH):
-        pytest.skip("host ops .so not built")
+    from deeplearning4j_tpu.native import _build
+    host_lib = _build("libdl4jtpu_host.so")
     with pytest.raises(RuntimeError, match="GetPjrtApi symbol not found"):
-        PjrtPlugin(_LIB_PATH)
+        PjrtPlugin(host_lib)
 
 
 def test_compile_options_proto_bytes():

@@ -461,12 +461,17 @@ def test_rollout_kill_switch_is_byte_identical_passthrough(monkeypatch):
 # ------------------------------------------------------------ compile cache
 _CACHE_CHILD = r"""
 import json, os, sys
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
-events = []
+events, dir_updates = [], []
 import jax.monitoring as mon
 mon.register_event_listener(
     lambda ev, **kw: events.append(ev) if "compilation_cache" in ev else None)
+_update = jax.config.update
+def _spy(name, value):
+    if name == "jax_compilation_cache_dir":
+        dir_updates.append(value)
+    return _update(name, value)
+jax.config.update = _spy
 import numpy as np
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
@@ -486,18 +491,30 @@ reg.shutdown()
 print(json.dumps({
     "hits": sum(1 for e in events if e.endswith("cache_hits")),
     "misses": sum(1 for e in events if e.endswith("cache_misses")),
+    "dir": jax.config.jax_compilation_cache_dir,
+    "dir_updates": dir_updates,
 }))
 """
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-def test_compile_cache_second_process_skips_recompilation(tmp_path):
-    """Satellite: with DL4J_TPU_COMPILE_CACHE set, a second process
-    deploying the same model retrieves the warmed bucket executables
-    from the persistent cache instead of recompiling them."""
+
+def _cache_env(**extra):
     env = dict(os.environ)
-    env["DL4J_TPU_COMPILE_CACHE"] = str(tmp_path / "xla-cache")
-    env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))) + os.pathsep + env.get("PYTHONPATH", ""))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"    # conftest keeps it off
+    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env.update(extra)
+    return env
+
+
+def test_compile_cache_dir_from_environment_second_process_hits(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets NO directory in
+    code (jax reads the variable itself), and a second process deploying
+    the same model retrieves the warmed bucket executables from the
+    persistent cache instead of recompiling them."""
+    cache = str(tmp_path / "xla-cache")
+    env = _cache_env(JAX_COMPILATION_CACHE_DIR=cache)
 
     def run():
         r = subprocess.run([sys.executable, "-c", _CACHE_CHILD],
@@ -507,11 +524,39 @@ def test_compile_cache_second_process_skips_recompilation(tmp_path):
         return json.loads(r.stdout.strip().splitlines()[-1])
 
     first = run()
+    assert first["dir"] == cache and first["dir_updates"] == []
     assert first["misses"] >= 1          # cold: executables compiled + saved
-    assert os.path.isdir(env["DL4J_TPU_COMPILE_CACHE"])
+    assert os.path.isdir(cache)
     second = run()
+    assert second["dir_updates"] == []
     assert second["hits"] >= 1           # warm: retrieved from disk
     assert second["misses"] == 0         # nothing recompiled
+
+
+def test_compile_cache_default_dir_is_fixed_in_checkout(tmp_path):
+    """Unset, the cache lives at <repo>/.jax_cache whatever the pid or the
+    working directory (nothing else — no state dir, no temp name — can
+    place it): the path is part of jax's cache key, so a directory that
+    moves never hits."""
+    code = ("import json, os; "
+            "from deeplearning4j_tpu.async_runtime import "
+            "configure_compile_cache; "
+            "print(json.dumps([configure_compile_cache(), os.getpid()]))")
+    cwds = [tmp_path / "a", tmp_path / "b"]
+    for d in cwds:
+        d.mkdir()
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", code], cwd=str(d), text=True,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, env=_cache_env())
+        for d in cwds]
+    outs = []
+    for p in procs:
+        out, err = p.communicate(timeout=120)
+        assert p.returncode == 0, out + err
+        outs.append(json.loads(out.strip().splitlines()[-1]))
+    (dir0, pid0), (dir1, pid1) = outs
+    assert pid0 != pid1
+    assert dir0 == dir1 == os.path.join(_REPO, ".jax_cache")
 
 
 # ------------------------------------------------------------------- faults

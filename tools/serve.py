@@ -23,8 +23,15 @@ connections across the live workers. The pieces:
 - **Respawn**: the parent monitors children and respawns a dead worker
   under its old worker id; the respawned process reads the store at
   startup and rejoins the rollout at its CURRENT stage. The persistent
-  compile cache (``DL4J_TPU_COMPILE_CACHE``, defaulted into the state
-  dir) makes the respawned deploy a disk retrieval, not a recompile.
+  compile cache (``async_runtime.configure_compile_cache``: at
+  ``JAX_COMPILATION_CACHE_DIR``, else a fixed path in the checkout — never
+  the state dir, whose name would change the cache key) makes the
+  respawned deploy a disk retrieval, not a recompile.
+- **One chip per worker**: the platform comes from the environment (no CPU
+  default). The parent never initializes a jax backend, and worker *i* is
+  spawned seeing only chip *i* (``TPU_VISIBLE_CHIPS``), so N workers need
+  N chips; a worker that cannot open its chip dies with its own error and
+  spin-up fails at once.
 
 Workers serve the demo version set (scoring ``v1``/``v2`` + generative
 ``g1``) so the subsystem is drivable out of the box; real deployments
@@ -239,9 +246,14 @@ def _retrying(what, fn, attempts: int = 8, delay_s: float = 0.1):
 
 
 def run_worker(args) -> int:
+    import jax
+
+    from deeplearning4j_tpu.async_runtime import configure_compile_cache
     from deeplearning4j_tpu.serving import (FrontDoor, SharedServingState,
                                             SharedStore)
 
+    cache_dir = configure_compile_cache()
+    devices = jax.devices()     # no chip to open: die here, with jax's error
     reg, router, gen_router = _build_demo(args.slots,
                                           not args.no_generative)
     shared = SharedServingState(SharedStore(args.state_dir),
@@ -258,7 +270,11 @@ def run_worker(args) -> int:
     _retrying("register",
               lambda: shared.register(os.getpid(), fd.port))
     print(json.dumps({"worker": args.worker_id, "pid": os.getpid(),
-                      "port": fd.port, "address": fd.get_address()}),
+                      "port": fd.port, "address": fd.get_address(),
+                      "platform": devices[0].platform,
+                      "device_kind": devices[0].device_kind,
+                      "device_count": len(devices),
+                      "compile_cache_dir": cache_dir}),
           flush=True)
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *a: stop.set())
@@ -866,7 +882,11 @@ def _spawn(args, wid: str) -> subprocess.Popen:
     if args.reuseport:
         cmd += ["--reuseport", "--port", str(args.port)]
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # a chip belongs to one process: worker i sees chip i and nothing else
+    # (libtpu's per-process visibility; inert on a platform with no chips)
+    env["TPU_VISIBLE_CHIPS"] = wid[1:]          # "w<i>"
+    env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    env["TPU_PROCESS_BOUNDS"] = "1,1,1"
     env.setdefault("PYTHONPATH",
                    _REPO + os.pathsep + env.get("PYTHONPATH", ""))
     # workers write to stderr so the PARENT's stdout stays a clean
@@ -882,14 +902,11 @@ def run_fleet(args) -> int:
     from deeplearning4j_tpu.serving import SharedStore
 
     os.makedirs(args.state_dir, exist_ok=True)
-    # warm spin-up: every worker (and every respawn) shares one
-    # persistent XLA compile cache unless the operator pointed elsewhere
-    os.environ.setdefault(
-        "DL4J_TPU_COMPILE_CACHE", os.path.join(args.state_dir, "xla-cache"))
     store = SharedStore(args.state_dir)
     wids = [f"w{i}" for i in range(args.workers)]
     children = {wid: _spawn(args, wid) for wid in wids}
     deadline = time.monotonic() + args.spinup_timeout_s
+    failure = None
     while time.monotonic() < deadline:
         try:
             ports = {w: r.get("port") for w, r in
@@ -898,11 +915,21 @@ def run_fleet(args) -> int:
             ports = {}          # store blip (chaos env): keep waiting
         if all(ports.get(w) for w in wids):
             break
+        dead = {w: p.returncode for w, p in children.items()
+                if p.poll() is not None}
+        if dead:
+            # e.g. more workers than chips: the worker's own traceback is
+            # already on stderr; do not sit out the spin-up timeout
+            failure = f"workers exited during spin-up: {dead}"
+            break
         time.sleep(0.2)
     else:
+        failure = "workers failed to register in time"
+    if failure:
         for p in children.values():
-            p.terminate()
-        print("workers failed to register in time", file=sys.stderr)
+            if p.poll() is None:
+                p.terminate()
+        print(failure, file=sys.stderr)
         return 1
     proxy = None
     if not args.reuseport:
