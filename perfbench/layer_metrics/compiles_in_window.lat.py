@@ -1,0 +1,1 @@
+from perfbench.layer_metrics._shared import compiles_in_window as read  # noqa: F401
