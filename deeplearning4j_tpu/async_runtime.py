@@ -102,7 +102,12 @@ def configure_compile_cache() -> str:
     program sets no directory in code. Unset: ``DEFAULT_COMPILE_CACHE_DIR``.
     The min-compile-time / min-entry-size gates are zeroed so every
     serving-bucket executable is eligible (the point is skipping the
-    small-but-many bucket compiles). Failures propagate: an entry point
+    small-but-many bucket compiles). An operation's metadata (its
+    ``op_name`` with the model's named scopes, its source line) is part of
+    the key: by jax's default it is not, and a hit then hands back an
+    executable with whatever names its first compile had - a profile of
+    this program would be read under another program's scopes, or none.
+    Failures propagate: an entry point
     that believes its compiles persist when they do not pays every cold
     start in full and never says so."""
     global _cache_configured
@@ -114,6 +119,8 @@ def configure_compile_cache() -> str:
                               DEFAULT_COMPILE_CACHE_DIR)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         # jax memoizes its cache decision at the FIRST backend compile; a
         # caller that compiled before configuring (model init, the normal
         # order) would otherwise never engage the dir. The reset drops only

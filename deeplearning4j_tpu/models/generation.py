@@ -58,6 +58,7 @@ import bisect
 import dataclasses
 import logging
 import os
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -67,6 +68,7 @@ from jax import lax
 
 from deeplearning4j_tpu.observability import compile_watch as _cw
 from deeplearning4j_tpu.observability import cost_model as _cost
+from deeplearning4j_tpu.observability import span as _span
 from deeplearning4j_tpu.resilience.policy import CachePagesExhausted
 
 _log = logging.getLogger(__name__)
@@ -348,6 +350,9 @@ class DecodeEngine:
         #: cumulative accept-loop stats (the dl4j_spec_accept_ratio
         #: gauge and the snapshot ``spec`` section read these)
         self.spec_stats = {"rounds": 0, "proposed": 0, "accepted": 0}
+        #: cumulative seconds ``spec_step`` waited for the device (its
+        #: two fetches); a scheduler books the rest of a round as dispatch
+        self.spec_fetch_s = 0.0
 
         def _prefill(params, tokens, last_idx, step):
             _cw.note_trace(PREFILL_FN, tokens)
@@ -367,6 +372,7 @@ class DecodeEngine:
             # loop never round-trips them through the host
             return nxt, logits, cache, positions + 1
 
+        @jax.named_scope("kv_write")
         def _insert(cache, k, v, slot):
             zero = jnp.zeros((), jnp.int32)
             at = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
@@ -391,6 +397,7 @@ class DecodeEngine:
             nxt = sample_tokens(logits, rng, sampler_cfg)
             return nxt, logits, pool
 
+        @jax.named_scope("kv_write")
         def _insert_paged(pool, k, v, page_ids):
             # (L, 1, Tb, H, hd) prefill k/v → whole-page rows
             # (pack_kv_pages) scattered into the slot's physical pages
@@ -792,32 +799,40 @@ class DecodeEngine:
         deliberately forfeited: emitting it would leave the draft cache
         one position behind and force a non-uniform catch-up step."""
         k = self.spec_k
-        if state.mode == "paged":
-            for b in active:
-                last = min(int(positions[b]) + k, self.max_len - 1)
-                if not self.ensure_slot_pages(state, b, last):
-                    raise CachePagesExhausted(
-                        f"page pool exhausted backing slot {b}'s verify "
-                        f"window through position {last}")
-        props, dlog, state.draft_cache = self._propose_jit(
-            self.draft.params, state.draft_cache,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(step, jnp.int32))
-        props = np.asarray(props)                       # (B, k)
-        win = np.concatenate([np.asarray(tokens, np.int32)[:, None],
-                              props], axis=1)           # (B, k+1)
-        if state.mode == "paged":
-            logits, state.arrays = self._verify_paged_jit(
-                self.params, state.arrays, self._tables(state),
-                jnp.asarray(win), jnp.asarray(positions, jnp.int32),
-                jnp.asarray(step, jnp.int32))
-        else:
-            logits, state.arrays = self._verify_dense_jit(
-                self.params, state.arrays, jnp.asarray(win),
+        with _span("decode_dispatch"):
+            if state.mode == "paged":
+                for b in active:
+                    last = min(int(positions[b]) + k, self.max_len - 1)
+                    if not self.ensure_slot_pages(state, b, last):
+                        raise CachePagesExhausted(
+                            f"page pool exhausted backing slot {b}'s "
+                            f"verify window through position {last}")
+            props, dlog, state.draft_cache = self._propose_jit(
+                self.draft.params, state.draft_cache,
+                jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(step, jnp.int32))
-        logits = np.asarray(logits)                     # (B, k+1, V)
+        t0 = time.perf_counter()
+        with _span("token_fetch"):
+            props = np.asarray(props)                   # (B, k)
+        t1 = time.perf_counter()
+        with _span("decode_dispatch"):
+            win = np.concatenate([np.asarray(tokens, np.int32)[:, None],
+                                  props], axis=1)       # (B, k+1)
+            if state.mode == "paged":
+                logits, state.arrays = self._verify_paged_jit(
+                    self.params, state.arrays, self._tables(state),
+                    jnp.asarray(win), jnp.asarray(positions, jnp.int32),
+                    jnp.asarray(step, jnp.int32))
+            else:
+                logits, state.arrays = self._verify_dense_jit(
+                    self.params, state.arrays, jnp.asarray(win),
+                    jnp.asarray(positions, jnp.int32),
+                    jnp.asarray(step, jnp.int32))
+        t2 = time.perf_counter()
+        with _span("token_fetch"):
+            logits = np.asarray(logits)                 # (B, k+1, V)
+        self.spec_fetch_s += (t1 - t0) + (time.perf_counter() - t2)
         greedy = (self.sampler.kind == "greedy"
                   and self.draft.sampler.kind == "greedy")
         dlog_h = None if greedy else np.asarray(dlog)

@@ -14,6 +14,13 @@ Sharding map (Megatron-style):
 - attn qkvo   (C, C):      qkv P(None, 'model') / out P('model', None)
 - mlp up/down (C, 4C)/(4C, C): up P(None, 'model') / down P('model', None)
 - activations (B, T, C):   P('data', 'seq', None)
+
+Named scopes (``jax.named_scope``: metadata on the compiled operations, read
+from a device profile; no change to the program): one fixed vocabulary in
+every spelling of the block - ``embed``, ``ln``, ``attn_qkv``, ``attn_core``,
+``attn_out``, ``mlp``, ``head``, ``loss``, ``optimizer``, and on the decode
+paths ``cast_params``, ``kv_write`` (rows stored into cache or pool),
+``kv_gather`` (a slot's pages read back through its table).
 """
 from __future__ import annotations
 
@@ -274,11 +281,12 @@ class TransformerLM:
     # ----------------------------------------------------------------- forward
     def _ln(self, p, x):
         # layernorm statistics in f32 regardless of compute dtype
-        xf = x.astype(jnp.float32)
-        mu, var = one_pass_moments(xf, -1, keepdims=True)
-        y = (xf - mu) * lax.rsqrt(var + 1e-5)
-        y = y * p["g"].astype(jnp.float32) + p["b"].astype(jnp.float32)
-        return y.astype(x.dtype)
+        with jax.named_scope("ln"):
+            xf = x.astype(jnp.float32)
+            mu, var = one_pass_moments(xf, -1, keepdims=True)
+            y = (xf - mu) * lax.rsqrt(var + 1e-5)
+            y = y * p["g"].astype(jnp.float32) + p["b"].astype(jnp.float32)
+            return y.astype(x.dtype)
 
     def _qkv(self, p, x):
         """Project one (B, T, C) activation into (B, T, H, hd) q/k/v —
@@ -287,35 +295,39 @@ class TransformerLM:
         c = self.config
         b, t, _ = x.shape
         h, hd = c.n_heads, c.d_model // c.n_heads
-        if "wqkv" in p:
-            qkv = x @ p["wqkv"]                       # one MXU op, one x read
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(b, t, h, hd)
-            k = k.reshape(b, t, h, hd)
-            v = v.reshape(b, t, h, hd)
-        else:
-            q = (x @ p["wq"]).reshape(b, t, h, hd)
-            k = (x @ p["wk"]).reshape(b, t, h, hd)
-            v = (x @ p["wv"]).reshape(b, t, h, hd)
+        with jax.named_scope("attn_qkv"):
+            if "wqkv" in p:
+                qkv = x @ p["wqkv"]                   # one MXU op, one x read
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(b, t, h, hd)
+                k = k.reshape(b, t, h, hd)
+                v = v.reshape(b, t, h, hd)
+            else:
+                q = (x @ p["wq"]).reshape(b, t, h, hd)
+                k = (x @ p["wk"]).reshape(b, t, h, hd)
+                v = (x @ p["wv"]).reshape(b, t, h, hd)
         return q, k, v
 
     def _attn(self, p, x, mesh, return_kv: bool = False):
         c = self.config
         b, t, _ = x.shape
         q, k, v = self._qkv(p, x)
-        if mesh is not None and SEQ_AXIS in mesh.axis_names:
-            o = ring_attention(q, k, v, mesh, causal=c.causal)
-        elif _use_flash_attention(t):
-            # Pallas flash kernel: O(T·d) memory (ref of N4's platform
-            # override hook — kernel swapped in when the platform supports it)
-            from deeplearning4j_tpu.kernels import flash_attention
-            o4 = flash_attention(q.transpose(0, 2, 1, 3),
-                                 k.transpose(0, 2, 1, 3),
-                                 v.transpose(0, 2, 1, 3), causal=c.causal)
-            o = o4.transpose(0, 2, 1, 3)
-        else:
-            o = _plain_attention(q, k, v, causal=c.causal)
-        out = o.reshape(b, t, c.d_model) @ p["wo"]
+        with jax.named_scope("attn_core"):
+            if mesh is not None and SEQ_AXIS in mesh.axis_names:
+                o = ring_attention(q, k, v, mesh, causal=c.causal)
+            elif _use_flash_attention(t):
+                # Pallas flash kernel: O(T·d) memory (ref of N4's platform
+                # override hook — kernel swapped in when the platform
+                # supports it)
+                from deeplearning4j_tpu.kernels import flash_attention
+                o4 = flash_attention(q.transpose(0, 2, 1, 3),
+                                     k.transpose(0, 2, 1, 3),
+                                     v.transpose(0, 2, 1, 3), causal=c.causal)
+                o = o4.transpose(0, 2, 1, 3)
+            else:
+                o = _plain_attention(q, k, v, causal=c.causal)
+        with jax.named_scope("attn_out"):
+            out = o.reshape(b, t, c.d_model) @ p["wo"]
         if return_kv:
             return out, k, v
         return out
@@ -356,14 +368,16 @@ class TransformerLM:
             x = self._constrain(x)
         h = self._ln(blk["ln2"], x)
         aux = self._zero_aux()
-        if c.moe is not None:
-            y, stats = moe_ffn(blk["moe"], h, c.moe, mesh)
-            aux = (stats["aux_loss"].astype(jnp.float32),
-                   stats["dropped_fraction"].astype(jnp.float32),
-                   stats["expert_fraction"].astype(jnp.float32))
-        else:
-            hdn = jax.nn.gelu(h @ blk["mlp"]["w_up"] + blk["mlp"]["b_up"])
-            y = hdn @ blk["mlp"]["w_down"] + blk["mlp"]["b_down"]
+        with jax.named_scope("mlp"):
+            if c.moe is not None:
+                y, stats = moe_ffn(blk["moe"], h, c.moe, mesh)
+                aux = (stats["aux_loss"].astype(jnp.float32),
+                       stats["dropped_fraction"].astype(jnp.float32),
+                       stats["expert_fraction"].astype(jnp.float32))
+            else:
+                hdn = jax.nn.gelu(h @ blk["mlp"]["w_up"]
+                                  + blk["mlp"]["b_up"])
+                y = hdn @ blk["mlp"]["w_down"] + blk["mlp"]["b_down"]
         x = x + self._dropout(y, rng, 2 * li + 2)
         if mesh is not None:
             x = self._constrain(x)
@@ -423,8 +437,10 @@ class TransformerLM:
         # mixed precision: f32 master params (init_params), compute in
         # c.dtype — the grads/updates stay f32 on the outside
         params = self._cast_params(params)
-        x = jnp.take(params["tok_emb"], tokens, axis=0) + params["pos_emb"][:t]
-        x = self._dropout(x.astype(c.dtype), rng, 0)
+        with jax.named_scope("embed"):
+            x = (jnp.take(params["tok_emb"], tokens, axis=0)
+                 + params["pos_emb"][:t])
+            x = self._dropout(x.astype(c.dtype), rng, 0)
         x = self._constrain(x)
         # dense (non-MoE) models carry NO aux through the layer stack: the
         # telemetry would be all-zero anyway, and threading it through the
@@ -492,7 +508,8 @@ class TransformerLM:
         (training mode); None = inference. ``return_aux``: also return the
         dict of auxiliary losses/stats (MoE load-balancing)."""
         x, emb, aux = self._apply_trunk(params, tokens, rng)
-        logits = jnp.matmul(x, emb.T, preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.matmul(x, emb.T, preferred_element_type=jnp.float32)
         if return_aux:
             return logits, aux
         return logits
@@ -506,16 +523,18 @@ class TransformerLM:
             from deeplearning4j_tpu.kernels.chunked_ce import (
                 chunked_softmax_xent)
             x, emb, aux = self._apply_trunk(params, tokens, rng)
-            lm_loss = chunked_softmax_xent(x, emb, targets, c.ce_chunks)
+            with jax.named_scope("loss"):       # head and loss in one
+                lm_loss = chunked_softmax_xent(x, emb, targets, c.ce_chunks)
         else:
             logits, aux = self.apply(params, tokens, rng=rng, return_aux=True)
             # fused cross-entropy: logsumexp − correct-logit avoids
             # materializing the (B, T, V) log-softmax in forward AND
             # backward — ~35% step-time win at V=8192 (HBM-traffic bound)
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)
-            correct = jnp.take_along_axis(logits, targets[..., None],
-                                          axis=-1)[..., 0]
-            lm_loss = jnp.mean(lse - correct)
+            with jax.named_scope("loss"):
+                lse = jax.scipy.special.logsumexp(logits, axis=-1)
+                correct = jnp.take_along_axis(logits, targets[..., None],
+                                              axis=-1)[..., 0]
+                lm_loss = jnp.mean(lse - correct)
         loss = lm_loss
         if self.config.moe is not None:
             loss = loss + self.config.moe_aux_weight * aux["moe_aux_loss"]
@@ -534,8 +553,10 @@ class TransformerLM:
                 (loss, aux), grads = jax.value_and_grad(
                     self.loss_fn, has_aux=True)(
                     params, tokens, targets, rng, with_aux=True)
-                updates, opt_state = optimizer.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = optimizer.update(grads, opt_state,
+                                                          params)
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, {"loss": loss, **aux}
             return step_m
 
@@ -543,8 +564,10 @@ class TransformerLM:
         def step(params, opt_state, tokens, targets, rng=None):
             loss, grads = jax.value_and_grad(self.loss_fn)(
                 params, tokens, targets, rng)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
         return step
 
@@ -583,19 +606,21 @@ class TransformerLM:
         c = self.config
         if c.dtype == jnp.float32:
             return params
-        return jax.tree.map(
-            lambda a: a.astype(c.dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+        with jax.named_scope("cast_params"):
+            return jax.tree.map(
+                lambda a: a.astype(c.dtype)
+                if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
 
     def _ffn(self, blk, h, mesh):
         """One block's feed-forward on (B, T, C) — the same math
         ``_block_math`` inlines (MoE stats dropped: generation has no
         aux loss to feed)."""
-        if self.config.moe is not None:
-            y, _ = moe_ffn(blk["moe"], h, self.config.moe, mesh)
-            return y
-        hdn = jax.nn.gelu(h @ blk["mlp"]["w_up"] + blk["mlp"]["b_up"])
-        return hdn @ blk["mlp"]["w_down"] + blk["mlp"]["b_down"]
+        with jax.named_scope("mlp"):
+            if self.config.moe is not None:
+                y, _ = moe_ffn(blk["moe"], h, self.config.moe, mesh)
+                return y
+            hdn = jax.nn.gelu(h @ blk["mlp"]["w_up"] + blk["mlp"]["b_up"])
+            return hdn @ blk["mlp"]["w_down"] + blk["mlp"]["b_down"]
 
     def init_cache(self, batch: int, max_len: int,
                    dtype: Optional[Any] = None) -> Dict:
@@ -619,8 +644,10 @@ class TransformerLM:
         c = self.config
         params = self._cast_params(params)
         t = tokens.shape[1]
-        x = jnp.take(params["tok_emb"], tokens, axis=0) + params["pos_emb"][:t]
-        x = x.astype(c.dtype)
+        with jax.named_scope("embed"):
+            x = (jnp.take(params["tok_emb"], tokens, axis=0)
+                 + params["pos_emb"][:t])
+            x = x.astype(c.dtype)
         if self.mesh is not None:
             x = self._constrain(x)
         ks, vs = [], []
@@ -636,8 +663,9 @@ class TransformerLM:
             ks.append(k)
             vs.append(v)
         x = self._ln(params["ln_f"], x)
-        logits = jnp.matmul(x, params["tok_emb"].T,
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.matmul(x, params["tok_emb"].T,
+                                preferred_element_type=jnp.float32)
         if not ks:
             # zero-layer trunk (an embedding-only speculative draft):
             # no attention, an empty (0, B, T, H, hd) cache
@@ -645,7 +673,8 @@ class TransformerLM:
             b, t = tokens.shape
             empty = jnp.zeros((0, b, t, h, hd), c.dtype)
             return logits, {"k": empty, "v": empty}
-        return logits, {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+        with jax.named_scope("kv_write"):
+            return logits, {"k": jnp.stack(ks), "v": jnp.stack(vs)}
 
     def decode_step_math(self, params, cache, tokens, positions):
         """One autoregressive step for a whole slot batch.
@@ -662,9 +691,10 @@ class TransformerLM:
         B = tokens.shape[0]
         S = cache["k"].shape[2]
         h, hd = c.n_heads, c.d_model // c.n_heads
-        x = (jnp.take(params["tok_emb"], tokens, axis=0)
-             + jnp.take(params["pos_emb"], positions, axis=0))
-        x = x[:, None, :].astype(c.dtype)          # (B, 1, C)
+        with jax.named_scope("embed"):
+            x = (jnp.take(params["tok_emb"], tokens, axis=0)
+                 + jnp.take(params["pos_emb"], positions, axis=0))
+            x = x[:, None, :].astype(c.dtype)      # (B, 1, C)
         # keys at cache position p are attendable when p <= current pos
         # (the current token's k/v are written before attention below)
         mask = jnp.arange(S)[None, :] <= positions[:, None]   # (B, S)
@@ -675,27 +705,34 @@ class TransformerLM:
         new_k, new_v = [], []
         for li, blk in enumerate(self._decode_blocks(params)):
             q, k, v = self._qkv(blk["attn"], self._ln(blk["ln1"], x))
-            ck = jax.vmap(write)(cache["k"][li], k[:, 0], positions)
-            cv = jax.vmap(write)(cache["v"][li], v[:, 0], positions)
+            with jax.named_scope("kv_write"):
+                ck = jax.vmap(write)(cache["k"][li], k[:, 0], positions)
+                cv = jax.vmap(write)(cache["v"][li], v[:, 0], positions)
             new_k.append(ck)
             new_v.append(cv)
             # single-query attention against the cache — the same
             # max-subtract/f32-exp softmax _plain_attention runs, so the
             # incremental logits match the full forward's to tolerance
-            s = jnp.einsum("bhd,bshd->bhs", q[:, 0], ck) / float(np.sqrt(hd))
-            s = jnp.where(mask[:, None, :], s, jnp.asarray(-1e30, s.dtype))
-            m = lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp((s - m).astype(jnp.float32))
-            p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(x.dtype)
-            o = jnp.einsum("bhs,bshd->bhd", p, cv)
-            x = x + (o.reshape(B, 1, c.d_model) @ blk["attn"]["wo"])
+            with jax.named_scope("attn_core"):
+                s = (jnp.einsum("bhd,bshd->bhs", q[:, 0], ck)
+                     / float(np.sqrt(hd)))
+                s = jnp.where(mask[:, None, :], s,
+                              jnp.asarray(-1e30, s.dtype))
+                m = lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp((s - m).astype(jnp.float32))
+                p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(x.dtype)
+                o = jnp.einsum("bhs,bshd->bhd", p, cv)
+            with jax.named_scope("attn_out"):
+                x = x + (o.reshape(B, 1, c.d_model) @ blk["attn"]["wo"])
             x = x + self._ffn(blk, self._ln(blk["ln2"], x), None)
         x = self._ln(params["ln_f"], x)
-        logits = jnp.matmul(x[:, 0], params["tok_emb"].T,
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.matmul(x[:, 0], params["tok_emb"].T,
+                                preferred_element_type=jnp.float32)
         if not new_k:           # zero-layer trunk: cache untouched
             return logits, {"k": cache["k"], "v": cache["v"]}
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        with jax.named_scope("kv_write"):
+            return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
 
     # ------------------------------------------ paged / windowed decode
     # The paged twin of the dense cache above: k/v live in a POOL of
@@ -738,21 +775,24 @@ class TransformerLM:
         """(B, W) tokens at (B, W) positions → (B, W, C) activations +
         the (B, W, S-broadcastable) query positions."""
         c = self.config
-        x = (jnp.take(params["tok_emb"], tokens, axis=0)
-             + jnp.take(params["pos_emb"], positions, axis=0))
-        return x.astype(c.dtype)
+        with jax.named_scope("embed"):
+            x = (jnp.take(params["tok_emb"], tokens, axis=0)
+                 + jnp.take(params["pos_emb"], positions, axis=0))
+            return x.astype(c.dtype)
 
     def _window_attend(self, q, ck, cv, mask, hd):
         """Single-query attention generalized to a W-window: q (B, W,
         H, hd) against gathered caches (B, S, H, hd) under mask (B, W,
         S) — the same max-subtract/f32-exp softmax the dense step
         runs."""
-        s = jnp.einsum("bwhd,bshd->bwhs", q, ck) / float(np.sqrt(hd))
-        s = jnp.where(mask[:, :, None, :], s, jnp.asarray(-1e30, s.dtype))
-        m = lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp((s - m).astype(jnp.float32))
-        p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(q.dtype)
-        return jnp.einsum("bwhs,bshd->bwhd", p, cv)
+        with jax.named_scope("attn_core"):
+            s = jnp.einsum("bwhd,bshd->bwhs", q, ck) / float(np.sqrt(hd))
+            s = jnp.where(mask[:, :, None, :], s,
+                          jnp.asarray(-1e30, s.dtype))
+            m = lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp((s - m).astype(jnp.float32))
+            p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(q.dtype)
+            return jnp.einsum("bwhs,bshd->bwhd", p, cv)
 
     def decode_window_math(self, params, cache, tokens, positions):
         """Dense-cache W-window decode: ``tokens`` (B, W) int32 with
@@ -776,19 +816,23 @@ class TransformerLM:
         new_k, new_v = [], []
         for li, blk in enumerate(self._decode_blocks(params)):
             q, k, v = self._qkv(blk["attn"], self._ln(blk["ln1"], x))
-            ck = jax.vmap(write)(cache["k"][li], k, pos_w)
-            cv = jax.vmap(write)(cache["v"][li], v, pos_w)
+            with jax.named_scope("kv_write"):
+                ck = jax.vmap(write)(cache["k"][li], k, pos_w)
+                cv = jax.vmap(write)(cache["v"][li], v, pos_w)
             new_k.append(ck)
             new_v.append(cv)
             o = self._window_attend(q, ck, cv, mask, hd)
-            x = x + (o.reshape(B, W, c.d_model) @ blk["attn"]["wo"])
+            with jax.named_scope("attn_out"):
+                x = x + (o.reshape(B, W, c.d_model) @ blk["attn"]["wo"])
             x = x + self._ffn(blk, self._ln(blk["ln2"], x), None)
         x = self._ln(params["ln_f"], x)
-        logits = jnp.matmul(x, params["tok_emb"].T,
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.matmul(x, params["tok_emb"].T,
+                                preferred_element_type=jnp.float32)
         if not new_k:           # zero-layer trunk: cache untouched
             return logits, {"k": cache["k"], "v": cache["v"]}
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        with jax.named_scope("kv_write"):
+            return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
 
     def decode_window_paged(self, params, pool, tables, tokens, positions,
                             page_tokens: int):
@@ -818,53 +862,58 @@ class TransformerLM:
         bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
         trash = pool["k"].shape[1] - 1
         in_range = pos_w < S
-        phys = jnp.where(
-            in_range,
-            tables[bidx, jnp.minimum(pos_w // P, tables.shape[1] - 1)],
-            trash)                                               # (B, W)
-        off = pos_w % P                                          # (B, W)
+        with jax.named_scope("kv_write"):
+            phys = jnp.where(
+                in_range,
+                tables[bidx, jnp.minimum(pos_w // P, tables.shape[1] - 1)],
+                trash)                                           # (B, W)
+            off = pos_w % P                                      # (B, W)
 
-        def store(pool_l, scale_l, rows):
-            """Scatter W rows per slot into one layer's pool (+ scale
-            grid under quant), then gather every slot's pages back as a
-            dequantized (B, S, H, hd) view."""
-            if quant:
-                q8, sc = quantize_kv_rows(rows)
-                pool_l = pool_l.at[phys, off].set(q8)
-                scale_l = scale_l.at[phys, off].set(sc)
-                gath = pool_l[tables].reshape(B, S, *pool_l.shape[-2:])
-                gsc = scale_l[tables].reshape(B, S)
-                view = (gath.astype(jnp.float32)
-                        * gsc[:, :, None, None]).astype(c.dtype)
-                return pool_l, scale_l, view
-            pool_l = pool_l.at[phys, off].set(rows)
-            view = pool_l[tables].reshape(B, S, *pool_l.shape[-2:])
-            return pool_l, None, view
+        def store(name, li, rows):
+            """Scatter W rows per slot into layer ``li`` of pool
+            ``name`` (+ scale grid under quant), then gather every
+            slot's pages back as a dequantized (B, S, H, hd) view."""
+            with jax.named_scope("kv_write"):
+                pool_l, scale_l = pool[name][li], None
+                if quant:
+                    q8, sc = quantize_kv_rows(rows)
+                    pool_l = pool_l.at[phys, off].set(q8)
+                    scale_l = pool[name + "_scale"][li].at[phys, off].set(sc)
+                else:
+                    pool_l = pool_l.at[phys, off].set(rows)
+            with jax.named_scope("kv_gather"):
+                view = pool_l[tables].reshape(B, S, *pool_l.shape[-2:])
+                if quant:
+                    gsc = scale_l[tables].reshape(B, S)
+                    view = (view.astype(jnp.float32)
+                            * gsc[:, :, None, None]).astype(c.dtype)
+            return pool_l, scale_l, view
 
         nk, nv, nks, nvs = [], [], [], []
         for li, blk in enumerate(self._decode_blocks(params)):
             q, k, v = self._qkv(blk["attn"], self._ln(blk["ln1"], x))
-            pk, sk, ck = store(pool["k"][li],
-                               pool["k_scale"][li] if quant else None, k)
-            pv, sv, cv = store(pool["v"][li],
-                               pool["v_scale"][li] if quant else None, v)
+            pk, sk, ck = store("k", li, k)
+            pv, sv, cv = store("v", li, v)
             nk.append(pk)
             nv.append(pv)
             if quant:
                 nks.append(sk)
                 nvs.append(sv)
             o = self._window_attend(q, ck, cv, mask, hd)
-            x = x + (o.reshape(B, W, c.d_model) @ blk["attn"]["wo"])
+            with jax.named_scope("attn_out"):
+                x = x + (o.reshape(B, W, c.d_model) @ blk["attn"]["wo"])
             x = x + self._ffn(blk, self._ln(blk["ln2"], x), None)
         x = self._ln(params["ln_f"], x)
-        logits = jnp.matmul(x, params["tok_emb"].T,
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.matmul(x, params["tok_emb"].T,
+                                preferred_element_type=jnp.float32)
         if not nk:              # zero-layer trunk: pool untouched
             return logits, pool
-        out = {"k": jnp.stack(nk), "v": jnp.stack(nv)}
-        if quant:
-            out["k_scale"] = jnp.stack(nks)
-            out["v_scale"] = jnp.stack(nvs)
+        with jax.named_scope("kv_write"):
+            out = {"k": jnp.stack(nk), "v": jnp.stack(nv)}
+            if quant:
+                out["k_scale"] = jnp.stack(nks)
+                out["v_scale"] = jnp.stack(nvs)
         return logits, out
 
 

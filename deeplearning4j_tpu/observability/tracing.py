@@ -6,9 +6,13 @@ into a process-wide ring (bounded memory — a week-long trainer cannot OOM
 the host by tracing), and export as Chrome trace-event JSON: complete
 events (``ph: "X"`` with ``ts``/``dur`` in microseconds) plus flow events
 (``ph: "s"/"f"``) that load directly in Perfetto / ``chrome://tracing``.
-This is the portable twin of the device timeline ``profiler.xprof``
-captures — host phases (data wait, dispatch, callbacks) live here, XLA
-kernels live there.
+Host phases (data wait, dispatch, callbacks) live here, XLA kernels in the
+device timeline ``jax.profiler`` records — and while such a profile runs
+(``profiler.xprof.DeviceProfiler``, ``GET /debug/profile``) every ``span()``
+also writes its name into it as a ``jax.profiler.TraceAnnotation``: the
+program's spans then sit in the profile's host plane, on the thread that
+opened them and on the device events' own clock. ``record_span`` sections
+were timed by someone else and cannot be bridged.
 
 Causal context (the production-tracing model of TF-Serving-style systems,
 Abadi et al. arXiv:1605.08695 §9): every span carries
@@ -47,6 +51,7 @@ from __future__ import annotations
 
 import json
 import os
+import random
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
@@ -55,7 +60,8 @@ from deeplearning4j_tpu.observability.registry import (global_registry,
                                                        metrics_enabled,
                                                        on_registry_reset)
 # cycle-safe: trace_store imports only registry, never tracing
-from deeplearning4j_tpu.observability.trace_store import (store_span_close,
+from deeplearning4j_tpu.observability.trace_store import (_ENV_DATA,
+                                                          store_span_close,
                                                           store_span_open)
 
 #: default ring capacity — ~200k spans at <100 bytes each stays tens of MB
@@ -75,16 +81,44 @@ def _now_us() -> float:
 now_us = _now_us
 
 
+# both switches are read on every span: from ``trace_store._ENV_DATA``, the
+# live dict under ``os.environ``, with byte keys, at a dict's speed
+_K_METRICS = os.fsencode("DL4J_TPU_METRICS")
+_K_TRACE = os.fsencode("DL4J_TPU_TRACE")
+
+
 def tracing_enabled() -> bool:
     """Spans record only when metrics are on AND ``DL4J_TPU_TRACE`` != 0
     (the latter keeps metrics live while isolating tracing's cost)."""
+    if _ENV_DATA is not None:
+        return (_ENV_DATA.get(_K_METRICS) != b"0"
+                and _ENV_DATA.get(_K_TRACE) != b"0")
     return metrics_enabled() and os.environ.get("DL4J_TPU_TRACE", "1") != "0"
+
+
+#: ``jax.profiler.TraceAnnotation``, bound at the first span: importing this
+#: module must not import jax (nor may any leaf initialize a backend)
+_annotation = None
+
+
+def _bind_annotation():
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+# ids come from a generator seeded once from the OS (again in a forked
+# child), not from ``os.urandom`` per id: a system call or two a span, with
+# which a span took 28 us on the chip's host and without 8 (PERF.md, PR 24)
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
 
 
 def _new_id() -> str:
     """16-hex-char random id (64 bits — the W3C trace-context span-id
     size; cheap enough for one or two per span on a hot fit loop)."""
-    return os.urandom(8).hex()
+    return "%016x" % _ids.getrandbits(64)
 
 
 class TraceContext(NamedTuple):
@@ -358,7 +392,7 @@ class Span:
     thread-local stack so ``depth`` reflects the live call structure, and
     carries trace context (see module doc) so cross-thread work links."""
 
-    __slots__ = ("name", "attrs", "sink", "_ts", "depth",
+    __slots__ = ("name", "attrs", "sink", "_ts", "_ann", "depth",
                  "trace_id", "span_id", "parent_id")
 
     def __init__(self, name: str, sink: Optional[TraceSink] = None,
@@ -392,6 +426,14 @@ class Span:
             # open/close balance tells it when a trace's last span closed
             store_span_open(self.trace_id)
         st.append(self)
+        # with a profile running the span is in it too, under its name (the
+        # attributes stay in the ring); with none, one flag read
+        ann = _annotation or _bind_annotation()
+        if ann.is_enabled():
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._ts = _now_us()
         return self
 
@@ -400,6 +442,8 @@ class Span:
         # capture at enter left a preemption window that could make a
         # child's end time exceed its parent's (ts + dur must nest)
         dur = _now_us() - self._ts
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()

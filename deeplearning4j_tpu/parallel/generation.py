@@ -96,6 +96,14 @@ from deeplearning4j_tpu.resilience.policy import (TYPED_OUTCOMES,
 
 _TYPED_OUTCOMES = TYPED_OUTCOMES
 
+#: the disjoint phases of one decode-loop iteration, in order: admit (joins
+#: and their prefills), reclaim (page backing for this step's writes),
+#: dispatch (``engine.decode``: table upload, enqueue), fetch (the host
+#: waits for the step's tokens), sweep (emit to every stream, resolve,
+#: free), publish (step metrics, cost model, breaker, flight recorder,
+#: journal, cache gauges). Their seconds sum to the loop's busy time.
+_LOOP_PHASES = ("admit", "reclaim", "dispatch", "fetch", "sweep", "publish")
+
 
 def _session_mod():
     """Lazy ``serving.session`` import: ``parallel`` must not import the
@@ -182,6 +190,20 @@ class _GenMetrics:
             "dl4j_decode_pages_capacity",
             "KV-cache page pool capacity across live paged pipelines "
             "(gauge: _total is counter-reserved by the metric lint)")
+        loop = reg.counter(
+            "dl4j_decode_loop_seconds_total",
+            "decode-thread seconds by phase of the loop iteration (admit, "
+            "reclaim, dispatch, fetch, sweep, publish); the phases are "
+            "disjoint and sum to the loop's busy time - everything but "
+            "fetch is host work the device may be waiting for",
+            label_names=("phase",))
+        self.loop_seconds = {ph: loop.labels(phase=ph)
+                             for ph in _LOOP_PHASES}
+        self.prefill_stall = reg.counter(
+            "dl4j_decode_prefill_stall_seconds_total",
+            "seconds the decode thread spent starting a joining request "
+            "(prefill, insert, first-token fetch) while at least one "
+            "other slot was active: every live stream waits that long")
         self.spec_accept = reg.gauge(
             "dl4j_spec_accept_ratio",
             "cumulative speculative-decode acceptance: accepted draft "
@@ -809,8 +831,9 @@ class GenerationPipeline:
             # the join-latency phase continuous batching exists to shrink
             record_span("slot_wait", req.t_enqueue_us, req.t_slot_us,
                         ctx=req.ctx, slot=slot)
-        t0 = time.perf_counter()
         t_us = now_us()
+        # the live streams that get no token until this joiner is in
+        stalled = self._n_active()
         # a resumed request re-prefills prompt + already-emitted tokens:
         # the cache rebuilds to exactly the state the lost slot held, and
         # the in-graph seeded sampler continues the identical stream
@@ -819,7 +842,7 @@ class GenerationPipeline:
         x_in = (np.concatenate([req.x, np.asarray(req.out, np.int32)])
                 if k_resumed else req.x)
         try:
-            with _span("prefill", slot=slot,
+            with _span("prefill_dispatch", slot=slot,
                        prompt_tokens=int(x_in.size)):
                 first, _logits, kv, t = self.engine.prefill(
                     x_in[None], step=self._step)
@@ -831,7 +854,7 @@ class GenerationPipeline:
             self._fail_request(req, e)
             return False
         try:
-            with _span("prefill", slot=slot, phase="insert"):
+            with _span("prefill_insert", slot=slot):
                 self._cache = self.engine.insert_slot(self._cache, kv, slot)
                 if self.engine.spec:
                     # the draft tracks the same prompt in its own dense
@@ -841,11 +864,15 @@ class GenerationPipeline:
                                                   x_in[None],
                                                   step=self._step)
                 first_tok = int(np.asarray(first)[0])
-            dt = time.perf_counter() - t0
+            end_us = now_us()
+            dt = (end_us - t_us) * 1e-6
             if req.ctx is not None:
-                record_span("prefill", t_us, now_us(), ctx=req.ctx,
-                            slot=slot, prompt_tokens=int(req.x.size))
+                record_span("prefill", t_us, end_us, ctx=req.ctx,
+                            slot=slot, prompt_tokens=int(req.x.size),
+                            stalled_slots=stalled)
             obs.prefill_latency.observe(dt)
+            if stalled:
+                obs.prefill_stall.inc(dt)
             _cost.global_cost_model().observe_time(PREFILL_FN, dt)
             if req.tenant is not None:
                 req.cost_flops += _cost.global_cost_model().flops_for(
@@ -957,13 +984,16 @@ class GenerationPipeline:
         ``_waiting`` and retried at every boundary (pages free exactly
         there) before the queue is touched — admission resumes the
         moment reclamation or completions return enough pages.
-        (Blocking briefly only when the whole pipeline is idle.)"""
+        Never blocks: an idle pipeline waits for its next request in
+        ``_decode_loop``, outside any iteration. Returns how many
+        requests it started."""
+        joined = 0
         while not self._stop.is_set():
             free = [i for i, r in enumerate(self._slot_req) if r is None]
             if not free:
                 if self._qos and self._maybe_preempt():
                     continue       # a slot was freed — re-scan and join
-                return
+                return joined
             req, self._waiting = self._waiting, None
             if req is not None:
                 if req._claimed:
@@ -974,10 +1004,9 @@ class GenerationPipeline:
                         "request expired waiting for cache pages"))
                     continue
             else:
-                idle = len(free) == self.slots
-                req = self._take_request(timeout=0.05 if idle else 0.0)
+                req = self._take_request(timeout=0.0)
             if req is None:
-                return
+                return joined
             if (self.engine.paged
                     and self.engine.min_pages_for_prompt(
                         req.x.size + len(req.out))
@@ -994,9 +1023,11 @@ class GenerationPipeline:
                     self._waiting = req
                     continue       # pages came back — retry this joiner
                 self._waiting = req
-                return
+                return joined
             _GenMetrics.get().queue_depth.set(self._queue.qsize())
             self._start_request(req, free[0])
+            joined += 1
+        return joined
 
     def _reclaim_victim_key(self, slot: int):
         """Reclamation victim ordering (max wins): shed sessions with
@@ -1053,8 +1084,10 @@ class GenerationPipeline:
         ``spec_k`` for a speculative round), then resolve/free finished,
         cancelled, or expired requests. A request finishing mid-window
         simply ignores the window's tail — same semantics as plain
-        decode stopping at its boundary."""
+        decode stopping at its boundary. Returns how many requests left
+        their slots."""
         obs = _GenMetrics.get()
+        left = 0
         # each occupied slot owns 1/slots of the step boundary's
         # accounted FLOPs (the whole slot batch runs whether occupied or
         # not — charging per OCCUPIED slot would make a lonely tenant
@@ -1071,6 +1104,7 @@ class GenerationPipeline:
             req = self._slot_req[slot]
             if req is None:
                 continue
+            left += 1               # taken back below if the slot stays
             if req._claimed:
                 # another path already resolved it (the caller's
                 # deadline walk-away) — stop spending device steps on a
@@ -1120,6 +1154,9 @@ class GenerationPipeline:
             elif done:
                 self._resolve(req)
                 self._free_slot(slot)
+            else:
+                left -= 1
+        return left
 
     def _decode_loop(self):
         while not self._stop.is_set():
@@ -1127,91 +1164,19 @@ class GenerationPipeline:
             # and re-binds the singleton (on_registry_reset) — a cached
             # handle would keep writing to detached instruments
             obs = _GenMetrics.get()
-            self._admit()
-            active = [i for i, r in enumerate(self._slot_req)
-                      if r is not None]
-            obs.slots_in_use.set(len(active))
-            if not active:
-                continue
-            try:
-                if self._resilience:
-                    self._retry.call(
-                        lambda: _faults.check("generation.step"),
-                        op="generation.step")
-                active = self._reclaim_pages(active)
-                if not active:
-                    self._step += 1
-                    self._publish_cache_bytes()
+            if self._waiting is None and self._n_active() == 0:
+                # idle: the wait for a request is no phase of any
+                # iteration, and a poll that found none records nothing
+                self._waiting = self._take_request(timeout=0.05)
+                if self._waiting is None:
+                    obs.slots_in_use.set(0)
                     continue
-                t0 = time.perf_counter()
-                if self.engine.spec:
-                    with _span("decode_step", active=len(active),
-                               slots=self.slots, spec=True):
-                        emitted = self.engine.spec_step(
-                            self._cache, self._tokens, self._positions,
-                            self._step, active)
-                    for slot, toks_l in emitted.items():
-                        # the last emitted token is the next carry; the
-                        # cache advanced one row per emitted token
-                        self._tokens[slot] = toks_l[-1]
-                        self._positions[slot] += len(toks_l)
-                else:
-                    with _span("decode_step", active=len(active),
-                               slots=self.slots):
-                        tokens, _logits, self._cache = self.engine.decode(
-                            self._cache, self._tokens, self._positions,
-                            self._step)
-                        toks = np.asarray(tokens)  # device→host sync
-                    self._tokens[active] = toks[active]
-                    self._positions[active] += 1
-                    emitted = {s: [int(toks[s])] for s in active}
-                dt = time.perf_counter() - t0
-                obs.step_latency.observe(dt)
-                obs.steps.inc()
-                obs.occupancy.observe(len(active) / max(1, self.slots))
-                if self.engine.spec:
-                    # the round's wall time covers the fused propose +
-                    # the windowed verify — book it against the verify
-                    # entry (the dominant executable), NEVER the
-                    # one-token decode step that did not run
-                    _cost.global_cost_model().observe_time(VERIFY_FN, dt)
-                    if self._fresh_spec_compile():
-                        self.engine.account_spec(
-                            self._cache, self._tokens, self._positions,
-                            self._step)
-                else:
-                    _cost.global_cost_model().observe_time(DECODE_FN, dt)
-                    if self._fresh_decode_compile():
-                        self.engine.account_decode(
-                            self._cache, self._tokens, self._positions,
-                            self._step)
-                if self._breaker is not None:
-                    self._breaker.record_success()
-                _flight().progress("generation_step")
-            # graftlint: disable=typed-errors — the catch must be broad
-            # (any step fault poisons the donated cache); the taxonomy
-            # is resolved per-request via _fail_request/_shed_request
-            except Exception as e:
-                if (self._breaker is not None
-                        and not isinstance(e, _TYPED_OUTCOMES)):
-                    self._breaker.record_failure()
-                # the step died mid-donation: the cache buffers are no
-                # longer trustworthy — rebuild the pages, resume the
-                # journaled sessions in place (tentpole 2; the in-graph
-                # seed makes the continued stream deterministic), and
-                # fail the rest (queued requests are untouched; the
-                # fresh state resets the page allocator and, in spec
-                # mode, the draft cache with it)
-                survivors = self._rebuild_after_fault(e)
-                self._step += 1
-                self._replace_survivors(survivors, e)
-                self._notify_journal()
-                self._publish_cache_bytes()
-                continue
-            self._step += 1
-            self._sweep_finished(emitted)
-            self._notify_journal()
-            self._publish_cache_bytes()
+            sec = dict.fromkeys(_LOOP_PHASES, 0.0)
+            with _span("decode_iter", step=self._step):
+                self._iterate(obs, sec)
+            for phase, s in sec.items():
+                if s:
+                    obs.loop_seconds[phase].inc(s)
         # shutdown: resolve whatever still occupies a slot (and the
         # parked joiner the pool never backed)
         for slot, req in enumerate(self._slot_req):
@@ -1223,6 +1188,137 @@ class GenerationPipeline:
             self._fail_request(self._waiting, ShutdownError(
                 "GenerationPipeline shut down"))
             self._waiting = None
+
+    def _iterate(self, obs: "_GenMetrics", sec: Dict[str, float]):
+        """One pass of the decode loop with work in hand: join, back this
+        step's pages, step every occupied slot one token (or one
+        speculative round) forward, sweep, publish. Each phase is a span
+        under the caller's ``decode_iter`` and adds its seconds to
+        ``sec`` (``_LOOP_PHASES``; consecutive clock reads, so the
+        phases leave no gap between them)."""
+        t_prev = time.perf_counter()
+
+        def close(phase: str):
+            nonlocal t_prev
+            now = time.perf_counter()
+            sec[phase] += now - t_prev
+            t_prev = now
+
+        with _span("loop_admit") as sp:
+            sp.set_attr("joined", self._admit())
+        active = [i for i, r in enumerate(self._slot_req)
+                  if r is not None]
+        obs.slots_in_use.set(len(active))
+        close("admit")
+        if not active:
+            return
+        try:
+            with _span("loop_reclaim"):
+                if self._resilience:
+                    self._retry.call(
+                        lambda: _faults.check("generation.step"),
+                        op="generation.step")
+                active = self._reclaim_pages(active)
+            close("reclaim")
+            if not active:
+                self._step += 1
+                self._publish_cache_bytes()
+                close("publish")
+                return
+            # the keys and values this step reads: every active slot's
+            # rows up to and including the one it writes
+            live = int(self._positions[active].sum()) + len(active)
+            if self.engine.spec:
+                fetch0 = self.engine.spec_fetch_s
+                with _span("decode_step", active=len(active),
+                           slots=self.slots, spec=True, live_tokens=live):
+                    emitted = self.engine.spec_step(
+                        self._cache, self._tokens, self._positions,
+                        self._step, active)
+                for slot, toks_l in emitted.items():
+                    # the last emitted token is the next carry; the
+                    # cache advanced one row per emitted token
+                    self._tokens[slot] = toks_l[-1]
+                    self._positions[slot] += len(toks_l)
+                close("dispatch")
+                # the round's waits for the device, as the engine timed
+                # them, are fetch; the rest of it is dispatch
+                waited = self.engine.spec_fetch_s - fetch0
+                sec["dispatch"] -= waited
+                sec["fetch"] += waited
+            else:
+                with _span("decode_step", active=len(active),
+                           slots=self.slots, live_tokens=live):
+                    with _span("decode_dispatch"):
+                        tokens, _logits, self._cache = self.engine.decode(
+                            self._cache, self._tokens, self._positions,
+                            self._step)
+                    close("dispatch")
+                    with _span("token_fetch"):
+                        toks = np.asarray(tokens)  # device→host sync
+                    # the step's device outputs die here, inside the
+                    # step's span and the fetch phase, not at this
+                    # function's return: handing (B, V) float32 logits
+                    # back to the runtime takes 1-2 ms on the chip's host
+                    del tokens, _logits
+                self._tokens[active] = toks[active]
+                self._positions[active] += 1
+                emitted = {s: [int(toks[s])] for s in active}
+                close("fetch")
+            dt = sec["dispatch"] + sec["fetch"]
+        # graftlint: disable=typed-errors — the catch must be broad
+        # (any step fault poisons the donated cache); the taxonomy
+        # is resolved per-request via _fail_request/_shed_request
+        except Exception as e:
+            if (self._breaker is not None
+                    and not isinstance(e, _TYPED_OUTCOMES)):
+                self._breaker.record_failure()
+            # the step died mid-donation: the cache buffers are no
+            # longer trustworthy — rebuild the pages, resume the
+            # journaled sessions in place (tentpole 2; the in-graph
+            # seed makes the continued stream deterministic), and
+            # fail the rest (queued requests are untouched; the
+            # fresh state resets the page allocator and, in spec
+            # mode, the draft cache with it)
+            survivors = self._rebuild_after_fault(e)
+            self._step += 1
+            self._replace_survivors(survivors, e)
+            close("sweep")      # requests failed and resumed: a sweep
+            self._notify_journal()
+            self._publish_cache_bytes()
+            close("publish")
+            return
+        self._step += 1
+        with _span("loop_sweep") as sp:
+            sp.set_attr("finished", self._sweep_finished(emitted))
+            sp.set_attr("emitted", sum(map(len, emitted.values())))
+        close("sweep")
+        with _span("loop_publish"):
+            obs.step_latency.observe(dt)
+            obs.steps.inc()
+            obs.occupancy.observe(len(active) / max(1, self.slots))
+            if self.engine.spec:
+                # the round's wall time covers the fused propose +
+                # the windowed verify — book it against the verify
+                # entry (the dominant executable), NEVER the
+                # one-token decode step that did not run
+                _cost.global_cost_model().observe_time(VERIFY_FN, dt)
+                if self._fresh_spec_compile():
+                    self.engine.account_spec(
+                        self._cache, self._tokens, self._positions,
+                        self._step)
+            else:
+                _cost.global_cost_model().observe_time(DECODE_FN, dt)
+                if self._fresh_decode_compile():
+                    self.engine.account_decode(
+                        self._cache, self._tokens, self._positions,
+                        self._step)
+            if self._breaker is not None:
+                self._breaker.record_success()
+            _flight().progress("generation_step")
+            self._notify_journal()
+            self._publish_cache_bytes()
+        close("publish")
 
     def _notify_journal(self):
         """Step-boundary poke for the session journal writer — an

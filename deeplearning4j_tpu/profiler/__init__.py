@@ -2,7 +2,7 @@
 from deeplearning4j_tpu.profiler.op_profiler import (OpProfiler,
                                                      ProfilerConfig)
 from deeplearning4j_tpu.profiler.performance import PerformanceTracker
-from deeplearning4j_tpu.profiler.xprof import DeviceProfiler, profile_step
+from deeplearning4j_tpu.profiler.xprof import DeviceProfiler
 
 __all__ = ["OpProfiler", "ProfilerConfig", "PerformanceTracker",
-           "DeviceProfiler", "profile_step"]
+           "DeviceProfiler"]

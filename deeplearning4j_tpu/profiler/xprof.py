@@ -5,19 +5,18 @@ The eager-path ``OpProfiler`` times per-op host dispatch; compiled programs
 need the device timeline instead. This wraps ``jax.profiler`` behind the
 same start/stop surface the reference exposes through
 ``Nd4j.getExecutioner().setProfilingConfig`` — traces land in a directory
-TensorBoard/XProf can open.
+TensorBoard/XProf can open. Host-side labels on that timeline are the
+program's own ``observability.span()``s: while a profile runs, every span
+is also written into it under its name (``observability/tracing.py``).
 """
 from __future__ import annotations
 
-import contextlib
 import os
-import time
-from typing import Optional
 
 
 class DeviceProfiler:
-    """ref-analog surface: start/stop + annotate (OpProfiler's scoped
-    sections, but for the XLA device timeline)."""
+    """ref-analog surface: start/stop around the XLA device timeline
+    (``OpProfiler``'s scoped sections are ``span()``s here)."""
 
     def __init__(self, log_dir: str = "/tmp/dl4j_tpu_profile"):
         self.log_dir = log_dir
@@ -40,46 +39,3 @@ class DeviceProfiler:
             jax.profiler.stop_trace()
             self._active = False
         return self.log_dir
-
-    @contextlib.contextmanager
-    def trace(self, name: Optional[str] = None):
-        """Scoped trace: ``with prof.trace("step"): step(...)``."""
-        import jax
-
-        started = not self._active
-        if started:
-            self.start()
-        try:
-            with jax.profiler.TraceAnnotation(name or "section"):
-                yield self
-        finally:
-            if started:
-                self.stop()
-
-    @staticmethod
-    def annotate(name: str):
-        """Standalone annotation context (host-side label on the timeline)."""
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-
-
-def profile_step(fn, *args, log_dir: str = "/tmp/dl4j_tpu_profile",
-                 iters: int = 3):
-    """One-shot helper: trace ``iters`` calls of a jitted step; returns
-    (last_output, trace_dir, wall_seconds_per_iter)."""
-    import jax
-
-    prof = DeviceProfiler(log_dir)
-    out = fn(*args)                      # compile outside the trace
-    jax.block_until_ready(out)
-    prof.start()
-    try:
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        wall = (time.perf_counter() - t0) / iters
-    finally:
-        trace_dir = prof.stop()          # never leave the profiler running
-    return out, trace_dir, wall

@@ -1,0 +1,9 @@
+"""Device seconds of the train step's operations scoped ``head`` or ``loss``
+(forward and backward, a collective that carries the scope included) over the
+device seconds of all its operations, first chip, in percent."""
+from perfbench.layer_metrics._named import scope_share_pct
+from perfbench.layer_metrics._shared import TRAIN_MODULE
+
+
+def read(ctx):
+    return scope_share_pct(ctx, TRAIN_MODULE, ("head", "loss"))

@@ -350,29 +350,42 @@ def test_parallel_transform_executor_matches_local():
     assert dist == local and len(dist) == 37
 
 
-@pytest.mark.slow
-
-
 def test_device_profiler_produces_trace(tmp_path):
     """jax-profiler bridge (SURVEY 5.1 'jax profiler → XProf'): tracing a
-    jitted step writes an XPlane trace TensorBoard can open."""
+    jitted step under ``DeviceProfiler.start/stop`` writes an XPlane trace
+    TensorBoard can open, and a ``span()`` around the step is the host-side
+    label on it: the one way to put the program's names on the profile."""
     import glob
 
     import jax
     import jax.numpy as jnp
+    from jax.profiler import ProfileData
 
-    from deeplearning4j_tpu.profiler import DeviceProfiler, profile_step
+    from deeplearning4j_tpu.observability import span
+    from deeplearning4j_tpu.profiler import DeviceProfiler
 
     d = str(tmp_path)
     step = jax.jit(lambda x: jnp.tanh(x @ x.T).sum())
     x = jnp.ones((64, 64))
-    out, trace_dir, wall = profile_step(step, x, log_dir=d, iters=2)
-    assert float(out) != 0 and wall > 0
+    jax.block_until_ready(step(x))          # compile outside the trace
+    prof = DeviceProfiler(d).start()
+    try:
+        for _ in range(2):
+            with span("profiled_section"):
+                out = jax.block_until_ready(step(x))
+    finally:
+        assert prof.stop() == d             # never leave the profiler on
+    assert float(out) != 0
     traces = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
     assert traces, f"no xplane trace written under {d}"
+    labels = [ev.name for plane in ProfileData.from_file(traces[0]).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name == "profiled_section"]
+    assert len(labels) == 2
 
-    # scoped annotation API is usable standalone
-    with DeviceProfiler.annotate("section"):
+    # with no profile running a span is no annotation, and still a span
+    with span("profiled_section"):
         jax.block_until_ready(step(x))
 
 
