@@ -10,7 +10,8 @@ annotations, attention routes through ring attention when a ``seq`` axis is
 present, and the whole train step jits into one GSPMD program.
 
 Sharding map (Megatron-style):
-- embeddings  (V, C):      P(None, 'model')
+- embeddings  (V, C):      P(None, 'model'); the head gathers the cast copy
+  whole and splits its tokens over 'model' too (``_head_operands``)
 - attn qkvo   (C, C):      qkv P(None, 'model') / out P('model', None)
 - mlp up/down (C, 4C)/(4C, C): up P(None, 'model') / down P('model', None)
 - activations (B, T, C):   P('data', 'seq', None)
@@ -26,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import os
 from typing import Any, Dict, Optional, Tuple
 
@@ -37,10 +39,12 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn._remat import remat as _remat
+from deeplearning4j_tpu.observability import compile_watch as _cw
 from deeplearning4j_tpu.ops.moments import one_pass_moments
 from deeplearning4j_tpu.parallel.mesh import (DATA_AXIS, EXPERT_AXIS,
                                               MODEL_AXIS, SEQ_AXIS,
-                                              STAGE_AXIS)
+                                              STAGE_AXIS, axis_size,
+                                              replicated)
 from deeplearning4j_tpu.parallel.moe import (MoEConfig, init_moe_params,
                                              moe_ffn, moe_param_specs)
 from deeplearning4j_tpu.parallel.ring import ring_attention, _plain_attention
@@ -58,6 +62,9 @@ FLASH_ATTENTION: Optional[bool] = None
 # T=2048 (XLA 4.90 ms vs flash 5.01 ms), flash 1.71x faster at T=8192
 # (17.2 ms vs 29.4 ms) with bq=512/bk=1024 tiles.
 FLASH_MIN_SEQ = 4096
+
+#: compile_watch's name for ``make_train_step``'s jitted step
+TRAIN_STEP_FN = "TransformerLM.train_step"
 
 
 def _use_flash_attention(seq_len: Optional[int] = None) -> bool:
@@ -231,7 +238,11 @@ class TransformerLM:
         return params
 
     def param_shardings(self, mesh: Mesh):
-        """PartitionSpec pytree (Megatron column/row split over ``model``)."""
+        """PartitionSpec pytree (Megatron column/row split over ``model``).
+        ``tok_emb`` is stored split on the embedding axis, not the vocabulary:
+        50257 is odd and not padded, and jax refuses an uneven split. The head
+        therefore gathers its cast whole and runs token-parallel over the
+        model axis (``_head_operands``) in place of all-reducing logits."""
         has_tp = MODEL_AXIS in mesh.axis_names
         col = P(None, MODEL_AXIS) if has_tp else P()
         row = P(MODEL_AXIS, None) if has_tp else P()
@@ -503,12 +514,49 @@ class TransformerLM:
             "moe_dropped_fraction": dropped / n_moe,
             "moe_expert_fraction": frac / n_moe}
 
+    def _head_operands(self, x, emb):
+        """``x`` (B, T, C) after ``ln_f`` and the cast ``tok_emb`` (V, C), laid
+        out for the head's contraction. On a ``model`` axis the stored
+        ``tok_emb`` is split on C, so ``x @ emb.T`` would leave every chip
+        partial logits for the whole vocabulary and all-reduce (B, T, V)
+        float32. Here the model axis joins the data axis for the rows of
+        ``x`` (or the seq axis for its tokens, where the rows do not divide)
+        and the cast embedding is gathered whole: the contraction is local
+        and logits, loss and their gradient exist once, for a chip's own
+        tokens. Where neither divides, or no model axis is there, both come
+        back as they were."""
+        log = logging.getLogger(__name__)
+        tp = 1 if self.mesh is None else axis_size(self.mesh, MODEL_AXIS)
+        if tp == 1:
+            log.info("head layout: replicated: %s", "no mesh"
+                     if self.mesh is None else "no model axis")
+            return x, emb
+        mesh = self.mesh
+        rows = (DATA_AXIS,) if DATA_AXIS in mesh.axis_names else ()
+        toks = (SEQ_AXIS,) if SEQ_AXIS in mesh.axis_names else ()
+        b, t, _ = x.shape
+        if b % (tp * axis_size(mesh, DATA_AXIS)) == 0:
+            layout, rows = "rows", rows + (MODEL_AXIS,)
+        elif t % (tp * axis_size(mesh, SEQ_AXIS)) == 0:
+            layout, toks = "tokens", toks + (MODEL_AXIS,)
+        else:
+            log.info("head layout: replicated: neither %d rows nor %d "
+                     "tokens divide over the model axis (%d)", b, t, tp)
+            return x, emb
+        log.info("head layout: %s split over the model axis (%d)", layout, tp)
+        x = lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P(rows or None, toks or None, None)))
+        return x, lax.with_sharding_constraint(emb, replicated(mesh))
+
     def apply(self, params, tokens, rng=None, return_aux=False):
         """tokens (B, T) int32 → logits (B, T, V). ``rng`` enables dropout
         (training mode); None = inference. ``return_aux``: also return the
-        dict of auxiliary losses/stats (MoE load-balancing)."""
+        dict of auxiliary losses/stats (MoE load-balancing). On a ``model``
+        axis the head runs token-parallel over it (``_head_operands``): the
+        vocabulary itself is not split, 50257 is odd and not padded."""
         x, emb, aux = self._apply_trunk(params, tokens, rng)
         with jax.named_scope("head"):
+            x, emb = self._head_operands(x, emb)
             logits = jnp.matmul(x, emb.T, preferred_element_type=jnp.float32)
         if return_aux:
             return logits, aux
@@ -516,6 +564,10 @@ class TransformerLM:
 
     # ------------------------------------------------------------------- loss
     def loss_fn(self, params, tokens, targets, rng=None, with_aux=False):
+        """Mean token cross-entropy (plus the weighted MoE aux loss). On a
+        ``model`` axis both spellings take their head operands from
+        ``_head_operands``: each chip computes logits, log-sum-exp and their
+        gradient for its own tokens only, and the mean reduces scalars."""
         c = self.config
         if c.ce_chunks:          # validated divisible in __post_init__
             # streamed CE: the (B,T,V) logits tensor never materializes
@@ -524,6 +576,7 @@ class TransformerLM:
                 chunked_softmax_xent)
             x, emb, aux = self._apply_trunk(params, tokens, rng)
             with jax.named_scope("loss"):       # head and loss in one
+                x, emb = self._head_operands(x, emb)
                 lm_loss = chunked_softmax_xent(x, emb, targets, c.ce_chunks)
         else:
             logits, aux = self.apply(params, tokens, rng=rng, return_aux=True)
@@ -550,6 +603,7 @@ class TransformerLM:
         if return_metrics:
             @functools.partial(jax.jit, donate_argnums=(0, 1))
             def step_m(params, opt_state, tokens, targets, rng=None):
+                _cw.note_trace(TRAIN_STEP_FN, tokens)
                 (loss, aux), grads = jax.value_and_grad(
                     self.loss_fn, has_aux=True)(
                     params, tokens, targets, rng, with_aux=True)
@@ -562,6 +616,7 @@ class TransformerLM:
 
         @functools.partial(jax.jit, donate_argnums=(0, 1))
         def step(params, opt_state, tokens, targets, rng=None):
+            _cw.note_trace(TRAIN_STEP_FN, tokens)
             loss, grads = jax.value_and_grad(self.loss_fn)(
                 params, tokens, targets, rng)
             with jax.named_scope("optimizer"):
@@ -922,7 +977,15 @@ def make_sharded_lm(config: TransformerConfig, mesh: Mesh, optimizer=None,
     """Build model + sharded params + opt state on the mesh."""
     optimizer = optimizer or optax.adamw(3e-4)
     model = TransformerLM(config, mesh)
-    params = model.init_params(jax.random.key(seed))
-    params = jax.device_put(params, model.param_shardings(mesh))
-    opt_state = jax.jit(optimizer.init)(params)
+    shardings = model.param_shardings(mesh)
+    params = jax.device_put(model.init_params(jax.random.key(seed)), shardings)
+    # the optimizer's moments placed like the parameters they belong to, the
+    # rest replicated: ``jit(optimizer.init)`` alone leaves the whole state
+    # on the first device, and a step fed from there compiles a second time
+    # for its own outputs
+    where = optax.tree_utils.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(optimizer.init, params), shardings,
+        transform_non_params=lambda _: replicated(mesh))
+    opt_state = jax.jit(optimizer.init, out_shardings=where)(params)
     return model, params, opt_state, optimizer
