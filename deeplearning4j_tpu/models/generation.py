@@ -69,6 +69,7 @@ from jax import lax
 from deeplearning4j_tpu.observability import compile_watch as _cw
 from deeplearning4j_tpu.observability import cost_model as _cost
 from deeplearning4j_tpu.observability import span as _span
+from deeplearning4j_tpu.models.transformer import pack_kv_pages  # noqa: F401
 from deeplearning4j_tpu.resilience.policy import CachePagesExhausted
 
 _log = logging.getLogger(__name__)
@@ -117,19 +118,13 @@ def spec_decode_env() -> bool:
     return os.environ.get("DL4J_TPU_SPEC_DECODE", "1") not in ("0", "")
 
 
-def pack_kv_pages(arr, page_tokens: int):
-    """(L, 1, Tb, H, hd) prefill k/v → (L, npb, P, H, hd) page rows,
-    zero-padded up to whole pages (pad rows sit past the prompt's
-    positions — masked until the slot's own decode writes overwrite
-    them). ONE spelling shared by the traced paged insert and the
-    eager numerics-gate probe: the gate must compare exactly the
-    packing production inserts use, or a layout change could slip past
-    it."""
-    L, _b, tb, h, hd = arr.shape
-    npb = -(-tb // page_tokens)
-    pad = npb * page_tokens - tb
-    a = jnp.pad(arr[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
-    return a.reshape(L, npb, page_tokens, h, hd)
+class CacheFeatureUnsupported(ValueError, RuntimeError):
+    """An engine was asked for a cache feature its model's cache protocol
+    does not provide (the dense cache, the int8 page pool, a speculative
+    draft): refused at construction. A ``ValueError`` like the engine's
+    other refusals of its arguments, and a ``RuntimeError`` because a
+    caller that probes for the int8 pool (``perfbench/control.py``) reads
+    "the program refused it" as one."""
 
 
 class PageAllocator:
@@ -286,10 +281,12 @@ class DecodeEngine:
         self.params = params
         self.max_len = int(max_len if max_len is not None else c.max_len)
         if not 0 < self.max_len <= c.max_len:
+            why = ("positions beyond the learned pos_emb table cannot decode"
+                   if model.max_positions is not None else
+                   "the configuration sizes no cache slot beyond it")
             raise ValueError(
                 f"max_len {self.max_len} must be in (0, "
-                f"config.max_len={c.max_len}] — positions beyond the "
-                "learned pos_emb table cannot decode")
+                f"config.max_len={c.max_len}] — {why}")
         self.sampler = sampler if sampler is not None else SamplerConfig()
         if prefill_buckets:
             buckets = tuple(sorted({int(b) for b in prefill_buckets
@@ -318,6 +315,21 @@ class DecodeEngine:
                                if self.paged else 0)
         self.kv_quant = bool(kv_quant if kv_quant is not None
                              else kv_quant_env())
+        # what this model's cache does not do is refused here, typed, not
+        # ignored at the first request
+        features, who = model.cache_features, type(model).__name__
+        if not self.paged and "dense" not in features:
+            raise CacheFeatureUnsupported(
+                f"{who} has no dense (unpaged) cache: "
+                "DL4J_TPU_KV_PAGE_TOKENS=0 / page_tokens=0 cannot serve it")
+        if self.kv_quant and "int8_pages" not in features:
+            raise CacheFeatureUnsupported(
+                f"{who} has no int8 page pool "
+                "(kv_quant / DL4J_TPU_KV_QUANT=1)")
+        if draft is not None and "spec" not in features:
+            raise CacheFeatureUnsupported(
+                f"{who} cannot verify a speculative draft: its decode "
+                "step takes one token a slot")
         if self.kv_quant and not self.paged:
             _log.warning(
                 "DL4J_TPU_KV_QUANT requested with the dense cache "
@@ -356,9 +368,12 @@ class DecodeEngine:
 
         def _prefill(params, tokens, last_idx, step):
             _cw.note_trace(PREFILL_FN, tokens)
-            logits, kv = model.prefill(params, tokens)
+            logits, kv = model.prefill_cache(params, tokens, last_idx)
             rng = jax.random.fold_in(self._base_key, step)
-            last = jnp.take(logits, last_idx, axis=1)        # (B, V)
+            # a model returns the logits of every position, or of the
+            # prompt's last alone (B, 1, V)
+            last = (jnp.take(logits, last_idx, axis=1)       # (B, V)
+                    if model.prefill_all_logits else logits[:, 0])
             first = sample_tokens(last, rng, sampler_cfg)
             return first, logits, kv
 
@@ -389,30 +404,19 @@ class DecodeEngine:
 
         def _decode_paged(params, pool, tables, tokens, positions, step):
             _cw.note_trace(DECODE_FN, tokens, positions)
-            logits, pool = model.decode_window_paged(
-                params, pool, tables, tokens[:, None], positions,
-                page_toks)
-            logits = logits[:, 0]
+            logits, pool, stats = model.decode_paged(
+                params, pool, tables, tokens, positions, page_toks)
             rng = jax.random.fold_in(self._base_key, step)
             nxt = sample_tokens(logits, rng, sampler_cfg)
+            if stats is not None:
+                # the step's counts ride behind its tokens: one transfer
+                nxt = jnp.concatenate([nxt, stats.astype(jnp.int32)])
             return nxt, logits, pool
 
         @jax.named_scope("kv_write")
-        def _insert_paged(pool, k, v, page_ids):
-            # (L, 1, Tb, H, hd) prefill k/v → whole-page rows
-            # (pack_kv_pages) scattered into the slot's physical pages
-            kr = pack_kv_pages(k, page_toks)
-            vr = pack_kv_pages(v, page_toks)
-            if "k_scale" in pool:
-                from deeplearning4j_tpu.models import transformer as _tr
-                k8, ks = _tr.quantize_kv_rows(kr)
-                v8, vs = _tr.quantize_kv_rows(vr)
-                return {"k": pool["k"].at[:, page_ids].set(k8),
-                        "v": pool["v"].at[:, page_ids].set(v8),
-                        "k_scale": pool["k_scale"].at[:, page_ids].set(ks),
-                        "v_scale": pool["v_scale"].at[:, page_ids].set(vs)}
-            return {"k": pool["k"].at[:, page_ids].set(kr),
-                    "v": pool["v"].at[:, page_ids].set(vr)}
+        def _insert_paged(pool, entries, page_ids, slot):
+            return model.insert_paged(pool, entries, page_ids, slot,
+                                      page_toks)
 
         def _verify_paged(params, pool, tables, win, positions, step):
             _cw.note_trace(VERIFY_FN, win, positions)
@@ -475,8 +479,8 @@ class DecodeEngine:
                 else slots * self.pages_per_slot
             if n < 1:
                 raise ValueError(f"page pool needs >= 1 page, got {n}")
-            pool = self.model.init_paged_cache(
-                n + 1, self.page_tokens, quant=self._quant_active())
+            pool = self.model.new_paged_cache(
+                slots, n + 1, self.page_tokens, quant=self._quant_active())
             tables = np.full((slots, self.pages_per_slot), n, np.int32)
             state = DecodeState("paged", slots, pool, tables=tables,
                                 alloc=PageAllocator(n))
@@ -497,7 +501,8 @@ class DecodeEngine:
     @staticmethod
     def cache_bytes(cache) -> int:
         """Total device bytes of a cache/state (dense prealloc, or the
-        whole page pool + draft cache) — the worst-case footprint."""
+        whole page pool + every slot's fixed state + draft cache) — the
+        worst-case footprint."""
         if isinstance(cache, DecodeState):
             total = sum(int(a.nbytes) for a in jax.tree.leaves(cache.arrays))
             if cache.draft_cache is not None:
@@ -507,33 +512,36 @@ class DecodeEngine:
         return int(sum(int(a.nbytes) for a in jax.tree.leaves(cache)))
 
     def page_bytes(self) -> int:
-        """Device bytes one page costs ACROSS ALL LAYERS (the pool
-        carries every layer's k + v + scale rows for a page, so one
-        allocated page id pins ``n_layers`` stripes) —
-        ``pages_in_use x page_bytes`` is the actual resident cache, the
-        admission unit."""
+        """Device bytes one page costs ACROSS ALL LAYERS that keep pages
+        (one allocated page id pins a stripe of every such layer), as the
+        model's cache lays them out — ``pages_in_use x page_bytes`` is
+        the resident paged cache, the admission unit."""
         if not self.paged:
             return 0
-        c = self.model.config
-        h, hd = c.n_heads, c.d_model // c.n_heads
-        per_row = h * hd
-        if self._quant_active():
-            # int8 k + int8 v + one f32 scale each, per layer
-            return c.n_layers * self.page_tokens * (2 * per_row + 8)
-        itemsize = jnp.dtype(c.dtype).itemsize
-        return c.n_layers * self.page_tokens * 2 * per_row * itemsize
+        return int(self.model.page_bytes(self.page_tokens,
+                                         quant=self._quant_active()))
+
+    def slot_state_bytes(self) -> int:
+        """Device bytes of the fixed state ONE slot holds beside its
+        pages (a recurrent layer's state; 0 for a model whose cache is
+        pages alone)."""
+        return int(self.model.slot_state_bytes())
 
     def resident_cache_bytes(self, state: DecodeState) -> int:
         """ACTUAL resident TARGET-cache bytes: dense = the full
         preallocation (all resident); paged = pages in use x page bytes
-        post-quantization — the admission unit the
-        dl4j_decode_cache_bytes gauge reports post-PR-13. The draft's
-        fixed dense cache is deliberately excluded (a constant, visible
-        in the snapshot's ``pool_bytes`` worst-case figure)."""
+        post-quantization, plus the fixed state of every OCCUPIED slot (a
+        freed slot's state is dead until the next insert overwrites it)
+        — the admission unit the dl4j_decode_cache_bytes gauge reports.
+        The draft's fixed dense cache is deliberately excluded (a
+        constant, visible in the snapshot's ``pool_bytes`` worst-case
+        figure)."""
         if state.mode != "paged":
             return int(sum(int(a.nbytes)
                            for a in jax.tree.leaves(state.arrays)))
-        return int(state.alloc.in_use * self.page_bytes())
+        occupied = sum(1 for p in state.slot_pages if p)
+        return int(state.alloc.in_use * self.page_bytes()
+                   + occupied * self.slot_state_bytes())
 
     # ------------------------------------------------------ page plumbing
     def pages_for(self, n_tokens: int) -> int:
@@ -739,8 +747,16 @@ class DecodeEngine:
             return nxt, logits, cache
         return nxt, logits, arrays
 
+    def step_counts(self, fetched: np.ndarray, slots: int) -> Dict[str, int]:
+        """What rode behind a decode step's tokens in their one transfer:
+        ``{name: count}`` for the model's ``step_stats`` (empty for a
+        model that returns none)."""
+        names = self.model.step_stats
+        return {n: int(v) for n, v in zip(names, fetched[slots:])}
+
     def insert_slot(self, cache, kv, slot: int):
-        """Write a prefill's (L, Bp, T_bucket, H, hd) k/v into the cache
+        """Write a prefill's cache entries (for ``TransformerLM`` the
+        (L, Bp, T_bucket, H, hd) k/v) into the cache
         starting at ``slot`` (donates the cache arrays). Dense: a traced
         slot index — joining slot 3 reuses slot 0's executable. Paged: a
         :class:`DecodeState` is required; the slot's pages are
@@ -748,7 +764,7 @@ class DecodeEngine:
         pool cannot cover the prompt's bucket — nothing allocated,
         nothing written)."""
         if isinstance(cache, DecodeState) and cache.mode == "paged":
-            npb = self.pages_for(kv["k"].shape[2])
+            npb = self.pages_for(self.model.entries_tokens(kv))
             if cache.slot_pages[slot]:
                 self.free_slot(cache, slot)
             pages = cache.alloc.alloc(npb)
@@ -761,8 +777,8 @@ class DecodeEngine:
             cache.tables[slot, :npb] = pages
             cache.tables_dev = None
             cache.arrays = self._insert_paged_jit(
-                cache.arrays, kv["k"], kv["v"],
-                jnp.asarray(pages, jnp.int32))
+                cache.arrays, kv, jnp.asarray(pages, jnp.int32),
+                jnp.asarray(slot, jnp.int32))
             return cache
         arrays = cache.arrays if isinstance(cache, DecodeState) else cache
         arrays = self._insert_jit(arrays, kv["k"], kv["v"],
@@ -1027,8 +1043,7 @@ class DecodeEngine:
         if self.paged:
             for b in range(B):
                 state = self.insert_slot(
-                    state, {"k": kv["k"][:, b:b + 1],
-                            "v": kv["v"][:, b:b + 1]}, b)
+                    state, self.model.entries_row(kv, b), b)
             return self._generate_paged(state, first, logits, t, B,
                                         max_new_tokens, eos_id,
                                         return_logits, on_token)
@@ -1081,7 +1096,8 @@ class DecodeEngine:
         growth is arithmetic (position = t + step), so the host
         allocates ahead of each step without syncing the tokens."""
         out = [first]
-        logit_steps = [np.asarray(logits)[:, t - 1]] if return_logits else []
+        at = t - 1 if self.model.prefill_all_logits else 0
+        logit_steps = [np.asarray(logits)[:, at]] if return_logits else []
         if on_token is not None:
             on_token(int(np.asarray(first)[0]), 0)
         tokens = first
@@ -1097,6 +1113,8 @@ class DecodeEngine:
                         f"{t + step} (pool {state.alloc.total} pages)")
             tokens, logits, state = self.decode(state, tokens, positions,
                                                 step)
+            if self.model.step_stats:   # the step's counts ride behind
+                tokens = tokens[:B]
             positions = positions + 1
             if step == 1:
                 self.account_decode(state, tokens, positions, step)

@@ -99,6 +99,21 @@ def quantize_kv_rows(rows):
     return q8, scale.astype(jnp.float32)
 
 
+def pack_kv_pages(arr, page_tokens: int):
+    """(L, 1, Tb, H, hd) prefill k/v → (L, npb, P, H, hd) page rows,
+    zero-padded up to whole pages (pad rows sit past the prompt's
+    positions — masked until the slot's own decode writes overwrite
+    them). ONE spelling shared by the traced paged insert and the
+    eager numerics-gate probe: the gate must compare exactly the
+    packing production inserts use, or a layout change could slip past
+    it."""
+    L, _b, tb, h, hd = arr.shape
+    npb = -(-tb // page_tokens)
+    pad = npb * page_tokens - tb
+    a = jnp.pad(arr[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
+    return a.reshape(L, npb, page_tokens, h, hd)
+
+
 @dataclasses.dataclass
 class TransformerConfig:
     vocab_size: int = 256
@@ -676,6 +691,71 @@ class TransformerLM:
                 return y
             hdn = jax.nn.gelu(h @ blk["mlp"]["w_up"] + blk["mlp"]["b_up"])
             return hdn @ blk["mlp"]["w_down"] + blk["mlp"]["b_down"]
+
+    # ------------------------------------------------- cache protocol
+    # What ``models/generation.py::DecodeEngine`` asks of a model: which
+    # cache features it has, how its paged cache is laid out and what a page
+    # and a slot cost, a prefill that returns what an insert takes, the
+    # insert, and the one-token paged decode. ``models/hybrid.py`` implements
+    # the same block over two kinds of state.
+    cache_features = frozenset(("dense", "int8_pages", "spec"))
+    step_stats = ()                  # counts a decode step returns
+    prefill_all_logits = True        # (B, T, V), not the last token's alone
+
+    @property
+    def max_positions(self) -> int:
+        return self.config.max_len   # the learned position table
+
+    def prefill_cache(self, params, tokens, last_idx):
+        """Every position's logits and k/v; keys and values of the padding
+        beyond ``last_idx`` are masked by position until overwritten."""
+        return self.prefill(params, tokens)
+
+    @staticmethod
+    def entries_tokens(kv) -> int:
+        return kv["k"].shape[2]
+
+    @staticmethod
+    def entries_row(kv, b: int):
+        return {"k": kv["k"][:, b:b + 1], "v": kv["v"][:, b:b + 1]}
+
+    def new_paged_cache(self, slots: int, n_pages: int, page_tokens: int,
+                        quant: bool = False) -> Dict:
+        return self.init_paged_cache(n_pages, page_tokens, quant=quant)
+
+    def page_bytes(self, page_tokens: int, quant: bool = False) -> int:
+        """Bytes of one page across all layers: k + v rows, int8 with one
+        float32 scale each under ``quant``."""
+        c = self.config
+        per_row = c.d_model
+        if quant:
+            return c.n_layers * page_tokens * (2 * per_row + 8)
+        return (c.n_layers * page_tokens * 2 * per_row
+                * jnp.dtype(c.dtype).itemsize)
+
+    def slot_state_bytes(self) -> int:
+        return 0                     # the cache is pages alone
+
+    def insert_paged(self, pool, kv, page_ids, slot, page_tokens: int):
+        """(L, 1, Tb, H, hd) prefill k/v -> whole-page rows
+        (:func:`pack_kv_pages`) scattered into the slot's physical pages."""
+        kr = pack_kv_pages(kv["k"], page_tokens)
+        vr = pack_kv_pages(kv["v"], page_tokens)
+        if "k_scale" in pool:
+            k8, ks = quantize_kv_rows(kr)
+            v8, vs = quantize_kv_rows(vr)
+            return {"k": pool["k"].at[:, page_ids].set(k8),
+                    "v": pool["v"].at[:, page_ids].set(v8),
+                    "k_scale": pool["k_scale"].at[:, page_ids].set(ks),
+                    "v_scale": pool["v_scale"].at[:, page_ids].set(vs)}
+        return {"k": pool["k"].at[:, page_ids].set(kr),
+                "v": pool["v"].at[:, page_ids].set(vr)}
+
+    def decode_paged(self, params, pool, tables, tokens, positions,
+                     page_tokens: int):
+        logits, pool = self.decode_window_paged(
+            params, pool, tables, tokens[:, None], positions, page_tokens)
+        return logits[:, 0], pool, None
 
     def init_cache(self, batch: int, max_len: int,
                    dtype: Optional[Any] = None) -> Dict:

@@ -175,6 +175,26 @@ class _GenMetrics:
             "ACTUAL resident KV-cache bytes of live pipelines: paged = "
             "pages in use x page bytes (post-quantization), dense = the "
             "full preallocation")
+        self.slot_state_bytes = reg.gauge(
+            "dl4j_decode_slot_state_bytes",
+            "device bytes of the fixed per-slot state (a recurrent "
+            "layer's state and convolution tail) live pipelines hold "
+            "for all their slots, beside the page pool")
+        self.page_pool_bytes = reg.gauge(
+            "dl4j_decode_page_pool_bytes",
+            "device bytes of the whole page pool of live paged "
+            "pipelines (capacity + the trash page, x page bytes)")
+        pairs = reg.counter(
+            "dl4j_moe_pairs_total",
+            "token-expert pairs decode steps routed, by whether the "
+            "chosen expert is held on this chip (held=\"0\": another "
+            "chip's part of the result)", label_names=("held",))
+        self.moe_pairs = {h: pairs.labels(held=h) for h in ("1", "0")}
+        self.moe_touched = reg.counter(
+            "dl4j_moe_experts_touched_total",
+            "held experts that received at least one token, summed over "
+            "expert layers and decode steps (the expert weights a step "
+            "had to read)")
         self.slots_in_use = reg.gauge(
             "dl4j_decode_slots_in_use",
             "slots occupied by in-flight generations (sampled per step "
@@ -327,6 +347,9 @@ class GenerationPipeline:
         self._tokens = np.zeros((self.slots,), np.int32)
         self._positions = np.zeros((self.slots,), np.int32)
         self._cache = engine.new_state(self.slots, pages=cache_pages)
+        # constants of this deployment, for the gauges every step publishes
+        self._page_bytes = engine.page_bytes()
+        self._slot_state_bytes = self.slots * engine.slot_state_bytes()
         # a popped request the pool couldn't back yet — retried at every
         # step boundary (pages free there) before the queue is touched
         self._waiting: Optional[_GenRequest] = None
@@ -345,7 +368,7 @@ class GenerationPipeline:
         gauge). Paged pipelines contribute pages-in-use x page-bytes
         (post-quantization), dense ones their full preallocation."""
         obs = _GenMetrics.get()
-        total = in_use = pages = 0
+        total = in_use = pages = slot_state = pool = 0
         accepted = proposed = 0
         for gp in list(cls._live):
             if gp._stop.is_set():
@@ -355,12 +378,16 @@ class GenerationPipeline:
             if st is not None and st.alloc is not None:
                 in_use += st.alloc.in_use
                 pages += st.alloc.total
+                slot_state += gp._slot_state_bytes
+                pool += (st.alloc.total + 1) * gp._page_bytes
             if gp.engine.spec:
                 accepted += gp.engine.spec_stats["accepted"]
                 proposed += gp.engine.spec_stats["proposed"]
         obs.cache_bytes.set(total)
         obs.pages_in_use.set(in_use)
         obs.pages_total.set(pages)
+        obs.slot_state_bytes.set(slot_state)
+        obs.page_pool_bytes.set(pool)
         # 0 when no live spec engine has proposed anything — a retired
         # spec deploy's final ratio must not outlive it on dashboards
         obs.spec_accept.set(accepted / proposed if proposed else 0.0)
@@ -869,7 +896,7 @@ class GenerationPipeline:
             if req.ctx is not None:
                 record_span("prefill", t_us, end_us, ctx=req.ctx,
                             slot=slot, prompt_tokens=int(req.x.size),
-                            stalled_slots=stalled)
+                            tokens=int(x_in.size), stalled_slots=stalled)
             obs.prefill_latency.observe(dt)
             if stalled:
                 obs.prefill_stall.inc(dt)
@@ -1248,7 +1275,7 @@ class GenerationPipeline:
                 sec["fetch"] += waited
             else:
                 with _span("decode_step", active=len(active),
-                           slots=self.slots, live_tokens=live):
+                           slots=self.slots, live_tokens=live) as step_sp:
                     with _span("decode_dispatch"):
                         tokens, _logits, self._cache = self.engine.decode(
                             self._cache, self._tokens, self._positions,
@@ -1256,6 +1283,11 @@ class GenerationPipeline:
                     close("dispatch")
                     with _span("token_fetch"):
                         toks = np.asarray(tokens)  # device→host sync
+                    # a model's own counts of the step (experts touched,
+                    # pairs on held experts) came in the same transfer
+                    counts = self.engine.step_counts(toks, self.slots)
+                    for name, n in counts.items():
+                        step_sp.set_attr(name, n)
                     # the step's device outputs die here, inside the
                     # step's span and the fetch phase, not at this
                     # function's return: handing (B, V) float32 logits
@@ -1309,6 +1341,11 @@ class GenerationPipeline:
                         self._step)
             else:
                 _cost.global_cost_model().observe_time(DECODE_FN, dt)
+                if counts:
+                    held = counts["pairs_held"]
+                    obs.moe_touched.inc(counts["experts_touched"])
+                    obs.moe_pairs["1"].inc(held)
+                    obs.moe_pairs["0"].inc(counts["pairs_routed"] - held)
                 if self._fresh_decode_compile():
                     self.engine.account_decode(
                         self._cache, self._tokens, self._positions,
@@ -1404,6 +1441,7 @@ class GenerationPipeline:
                 "in_use": st.alloc.in_use,
                 "total": st.alloc.total,
                 "page_bytes": eng.page_bytes(),
+                "slot_state_bytes": eng.slot_state_bytes(),
                 "quant": bool(eng.kv_quant),
                 "quant_gate": eng.quant_gate,
                 "waiting_for_pages": self._waiting is not None,

@@ -179,3 +179,113 @@ def moe_reference_dense(params, x, cfg: MoEConfig):
         y = expert_out(idx) * (gate / denom)[:, None] \
             + expert_out(idx2) * (gate2 / denom)[:, None]
     return y.reshape(B, T, d)
+
+
+# --------------------------------------------------------------------------
+# Routed experts, the chip's share: sigmoid router at published width, k a
+# token, nothing dropped, a grouped product over the experts held here
+# --------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class RoutedExpertsConfig:
+    """A layer of many small SwiGLU experts of which this chip holds a
+    contiguous range. ``router_width`` is the published number of experts
+    and is never cut: every token is scored against all of them and chooses
+    ``top_k``; ``held = (first, count)`` says which of them live here (the
+    deployment's configuration, as under expert parallelism)."""
+
+    router_width: int
+    top_k: int
+    held: tuple                     # (first, count)
+    scale: float = 1.0              # routed_scaling_factor
+    renormalize: bool = True
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.router_width):
+            raise ValueError(f"held {self.held} outside the router's "
+                             f"{self.router_width} experts")
+        if not 1 <= self.top_k <= self.router_width:
+            raise ValueError(f"top_k {self.top_k} outside [1, "
+                             f"{self.router_width}]")
+
+
+def swiglu(x, w_gu, w_down):
+    """SwiGLU with one fused gate|up matrix: x (.., d), w_gu (d, 2f), w_down
+    (f, d) -> float32 (.., d)."""
+    h = jnp.matmul(x, w_gu, preferred_element_type=jnp.float32)
+    f = w_down.shape[0]
+    act = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(x.dtype)
+    return jnp.matmul(act, w_down, preferred_element_type=jnp.float32)
+
+
+def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
+                       x_route=None):
+    """x (T, d) -> (y (T, d) in x's dtype, stats int32[3]).
+
+    ``s = sigmoid(x W_r)`` over all ``router_width`` experts; the ``top_k``
+    largest of ``s + b_select`` are chosen; their weights are ``s`` at the
+    chosen, divided by their sum and scaled. The token-expert pairs that fall
+    on held experts are sorted by expert and go through one grouped product
+    (``lax.ragged_dot``) a matrix; pairs on absent experts add nothing - what
+    those experts would give is the other chips' part of the result. No pair
+    on a held expert is ever dropped: there is no capacity. The shared expert
+    is added once. ``token_mask`` (T,) marks the rows that carry a real
+    token (a free decode slot routes nowhere and touches no expert).
+    ``x_route`` (T, d), if given, is what the router scores instead of ``x``:
+    the same rows before they were rounded to the experts' dtype. Choosing 8
+    of 256 is a discrete decision, and a score rounded to bfloat16 flips it
+    where the 8th and 9th are close; in float32 at highest precision the
+    router costs microseconds.
+
+    ``params``: ``w_router`` (d, E), ``b_select`` (E,), ``w_gu``
+    (held, d, 2f: gate columns then up columns), ``w_down`` (held, f, d),
+    ``shared`` {``w_gu`` (d, 2f), ``w_down`` (f, d)}.
+    ``stats``: [held experts with at least one pair, pairs on held experts,
+    pairs routed anywhere (real tokens x ``top_k``)].
+    """
+    T, d = x.shape
+    k = cfg.top_k
+    first, count = cfg.held
+    with jax.named_scope("moe_route"):
+        xr = x if x_route is None else x_route
+        s = jax.nn.sigmoid(jnp.matmul(
+            xr, params["w_router"].astype(xr.dtype),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32))
+        _, idx = lax.top_k(s + params["b_select"].astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)                 # (T, k)
+        if cfg.renormalize:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        w = w * cfg.scale
+        here = (idx >= first) & (idx < first + count)
+        if token_mask is not None:
+            here = here & token_mask[:, None]
+        # absent pairs get the sentinel ``count`` and sort behind every group
+        local = jnp.where(here, idx - first, count).reshape(-1)  # (T k,)
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)[:count]
+        n_held = jnp.sum(sizes)
+        rows = x[order // k]                                     # (T k, d)
+        valid = (jnp.arange(T * k) < n_held)[:, None]
+        n_routed = k * (T if token_mask is None else jnp.sum(token_mask))
+        stats = jnp.stack([jnp.sum(sizes > 0), n_held,
+                           n_routed]).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        f = params["w_down"].shape[1]
+        h = lax.ragged_dot(rows, params["w_gu"], sizes,
+                           preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        y = lax.ragged_dot(act, params["w_down"], sizes,
+                           preferred_element_type=jnp.float32)
+        # rows behind the last group belong to no expert held here
+        y = jnp.where(valid, y, 0.0)
+    with jax.named_scope("moe_shared"):
+        shared = swiglu(x, params["shared"]["w_gu"],
+                         params["shared"]["w_down"])
+    with jax.named_scope("moe_combine"):
+        inverse = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        pairs = y[inverse].reshape(T, k, d)
+        routed = jnp.einsum("tk,tkd->td", jnp.where(here, w, 0.0), pairs)
+        return (shared + routed).astype(x.dtype), stats
