@@ -2,13 +2,17 @@
 
 Role of the reference's hand-written CUDA kernels (SURVEY N3/N4/N9): most of
 libnd4j's kernel library collapses into XLA lowerings, but two genuinely
-custom kernels remain worth owning: flash attention (the hot op XLA can't
-fuse into one memory-efficient pass by itself) and the Strom-2015 threshold
-gradient codec (the distributed-training compressor, kept for the DCN
-cross-slice path).
+custom kernels remain worth owning: flash attention (forward and backward
+Pallas kernels that keep the (T, T) scores in VMEM; the training path from
+``models.transformer.FLASH_MIN_SEQ`` up, read by slabs of lanes straight from
+the model's (B, T, H, hd) projections) and the Strom-2015 threshold gradient
+codec (the distributed-training compressor, kept for the DCN cross-slice
+path).
 """
-from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
+                                                        flash_attention_bthd)
 from deeplearning4j_tpu.kernels.threshold import (threshold_decode,
                                                   threshold_encode)
 
-__all__ = ["flash_attention", "threshold_encode", "threshold_decode"]
+__all__ = ["flash_attention", "flash_attention_bthd", "threshold_encode",
+           "threshold_decode"]

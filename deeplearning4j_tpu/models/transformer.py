@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn._remat import remat as _remat
@@ -49,39 +49,46 @@ from deeplearning4j_tpu.parallel.moe import (MoEConfig, init_moe_params,
                                              moe_ffn, moe_param_specs)
 from deeplearning4j_tpu.parallel.ring import ring_attention, _plain_attention
 
-# attention backend override: None = auto (flash kernel on TPU for long
-# sequences, XLA attention elsewhere — interpret-mode pallas is slow on CPU);
-# True/False forces it
+# attention backend override: None = auto (flash kernels on TPU from
+# FLASH_MIN_SEQ up, XLA attention elsewhere — interpret-mode pallas is slow
+# on CPU); True/False forces it
 FLASH_ATTENTION: Optional[bool] = None
 
-# auto-policy crossover: below this sequence length the XLA attention's
-# (T, T) materialization is cheap enough that it beats the Pallas kernel on
-# device-measured step time; at/above it the Pallas kernel wins outright.
-# Hardware-measured crossover (v5e, 2026-07-31, fwd+grad, D=64, causal,
-# benchmarks/flash_crossover.py): XLA 2.7x faster at T=512, dead heat at
-# T=2048 (XLA 4.90 ms vs flash 5.01 ms), flash 1.71x faster at T=8192
-# (17.2 ms vs 29.4 ms) with bq=512/bk=1024 tiles.
-FLASH_MIN_SEQ = 4096
+# auto-policy crossover: from this sequence length up the Pallas kernels
+# (kernels/flash_attention.py: scores in VMEM only, forward and backward)
+# beat XLA attention's (T, T) tensors in HBM on device-measured time.
+# Hardware-measured (v5e, PR 28, benchmarks/flash_crossover.py; causal,
+# D = 64, B x H = 8 x 16 and 8 x 10, kernels | XLA in ms): fwd+bwd T=512
+# 0.59 | 0.46 and 0.37 | 0.20; T=768 1.19 | 1.84 and 0.75 | 0.63 (mixed);
+# T=1024 1.59 | 3.52 and 0.99 | 2.11; T=4096 16.1 | 69.1. Forward alone
+# crosses at the same length (T=768 0.60 | 0.50 and 0.38 | 0.18; T=1024
+# 0.64 | 0.85 and 0.40 | 0.53), so prefill and training share one gate.
+# The whole table is in PERF.md section 6, PR 28.
+FLASH_MIN_SEQ = 1024
 
 #: compile_watch's name for ``make_train_step``'s jitted step
 TRAIN_STEP_FN = "TransformerLM.train_step"
 
 
-def _use_flash_attention(seq_len: Optional[int] = None) -> bool:
-    # env override first: "xla"/"flash" force a backend, "auto" (default)
-    # keeps the measured-crossover policy below. Consulted at TRACE time
-    # only — a compiled executable never re-reads it. On a TPU a kernel
-    # that does not compile is an error, never a downgrade to XLA attention.
+def _attention_policy(seq_len: Optional[int] = None) -> Tuple[bool, str]:
+    """(use the flash kernels, why). Env override first: "xla"/"flash" force
+    a backend, "auto" (default) keeps the measured-crossover policy.
+    Consulted at TRACE time only — a compiled executable never re-reads it.
+    On a TPU a kernel that does not compile is an error, never a downgrade
+    to XLA attention."""
     backend = os.environ.get("DL4J_TPU_ATTN_BACKEND", "auto").lower()
-    if backend == "xla":
-        return False
-    if backend == "flash":
-        return True
+    if backend in ("xla", "flash"):
+        return backend == "flash", f"DL4J_TPU_ATTN_BACKEND={backend}"
     if FLASH_ATTENTION is not None:
-        return FLASH_ATTENTION
+        return FLASH_ATTENTION, f"FLASH_ATTENTION = {FLASH_ATTENTION}"
     if seq_len is not None and seq_len < FLASH_MIN_SEQ:
-        return False
-    return jax.default_backend() == "tpu"
+        return False, f"T = {seq_len} is under FLASH_MIN_SEQ = {FLASH_MIN_SEQ}"
+    platform = jax.default_backend()
+    return platform == "tpu", f"T = {seq_len} on {platform}"
+
+
+def _use_flash_attention(seq_len: Optional[int] = None) -> bool:
+    return _attention_policy(seq_len)[0]
 
 
 def quantize_kv_rows(rows):
@@ -195,6 +202,7 @@ class TransformerLM:
     def __init__(self, config: TransformerConfig, mesh: Optional[Mesh] = None):
         self.config = config
         self.mesh = mesh
+        self._attn_said = None      # the last trace's backend line
 
     # ------------------------------------------------------------------ params
     def init_params(self, key) -> Dict:
@@ -338,18 +346,17 @@ class TransformerLM:
         c = self.config
         b, t, _ = x.shape
         q, k, v = self._qkv(p, x)
+        if mesh is not None and SEQ_AXIS in mesh.axis_names:
+            backend, why = "ring", "the mesh has a seq axis"
+        else:
+            backend = "flash" if _use_flash_attention(t) else "xla"
+            why = _attention_policy(t)[1]
+        self._say_backend(backend, why)
         with jax.named_scope("attn_core"):
-            if mesh is not None and SEQ_AXIS in mesh.axis_names:
+            if backend == "ring":
                 o = ring_attention(q, k, v, mesh, causal=c.causal)
-            elif _use_flash_attention(t):
-                # Pallas flash kernel: O(T·d) memory (ref of N4's platform
-                # override hook — kernel swapped in when the platform
-                # supports it)
-                from deeplearning4j_tpu.kernels import flash_attention
-                o4 = flash_attention(q.transpose(0, 2, 1, 3),
-                                     k.transpose(0, 2, 1, 3),
-                                     v.transpose(0, 2, 1, 3), causal=c.causal)
-                o = o4.transpose(0, 2, 1, 3)
+            elif backend == "flash":
+                o = self._flash_attention(q, k, v, mesh)
             else:
                 o = _plain_attention(q, k, v, causal=c.causal)
         with jax.named_scope("attn_out"):
@@ -357,6 +364,33 @@ class TransformerLM:
         if return_kv:
             return out, k, v
         return out
+
+    def _say_backend(self, backend, why):
+        """``attention backend: flash | xla | ring: <reason>``, once a trace
+        (every layer asks; the trace's identity tells a new one)."""
+        said = (jax.core.get_opaque_trace_state(), backend, why)
+        if said != self._attn_said:
+            self._attn_said = said
+            logging.getLogger(__name__).info(
+                "attention backend: %s: %s", backend, why)
+
+    def _flash_attention(self, q, k, v, mesh):
+        """The Pallas kernels over (B, T, H, hd) as ``_qkv`` makes them (no
+        transpose: the kernels read the (B, T, H·hd) array by slabs of
+        lanes). A kernel has no partitioning rule, so on a mesh the call is
+        wrapped in ``shard_map`` with the batch over ``data`` and the heads
+        over ``model`` (the layout ``ring_attention`` uses); inside the
+        pipeline body (``mesh=None``) it is already per-shard."""
+        from deeplearning4j_tpu.kernels.flash_attention import (
+            flash_attention_bthd)
+        fn = functools.partial(flash_attention_bthd, causal=self.config.causal)
+        if mesh is None:
+            return fn(q, k, v)
+        dp, tp = axis_size(mesh, DATA_AXIS), axis_size(mesh, MODEL_AXIS)
+        spec = P(DATA_AXIS if dp > 1 and q.shape[0] % dp == 0 else None, None,
+                 MODEL_AXIS if tp > 1 and q.shape[2] % tp == 0 else None, None)
+        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
     def _constrain(self, x):
         """Activation sharding hint: (B, T, C) → ('data', 'seq', None)."""
