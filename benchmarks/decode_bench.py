@@ -37,8 +37,7 @@ arm — raw single samples on this ±40%-drift box are weather):
    asserted. Bar: >= 1.3x tokens/s.
 
 JSON archives to ``benchmarks/ab/decode_ab.json`` (never the repo
-root — the driver's ``DECODE_r*.json`` copies are what
-``tools/bench_diff.py`` grades across rounds, sustained-only).
+root).
 """
 from __future__ import annotations
 

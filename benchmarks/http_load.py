@@ -9,8 +9,7 @@ multi-token generations, a slice of SSE streams — because production
 traffic is never uniform and the tail is what kills SLOs. Grades the
 run with the SLO machinery (p50/p99 per route, goodput, shed/error
 ratios via the PR-3 ``_grade``) and emits ONE JSON line
-(``metric: http_serve``) the driver archives as ``SERVE_r*.json`` for
-``tools/bench_diff.py``'s sustained-only trajectory.
+(``metric: http_serve``) the driver archives as ``SERVE_r*.json``.
 
 Two modes:
 
@@ -18,7 +17,7 @@ Two modes:
   goodput is also measured DIRECT (in-process ``router.output``)
   interleaved A/B-style, so ``vs_direct`` is the HTTP overhead ratio —
   host-load drift divides out, which is the only host-timed series
-  worth gating on (the bench_diff discipline).
+  worth gating on.
 - ``--workers N``: spawns a real ``tools/serve.py`` fleet (separate
   processes + proxy + shared store) and drives it over the proxy.
   ``--kill-drill`` additionally SIGKILLs one worker mid-load and

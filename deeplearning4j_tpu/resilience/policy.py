@@ -35,6 +35,7 @@ Everything here no-ops/fails open under ``DL4J_TPU_RESILIENCE=0``.
 """
 from __future__ import annotations
 
+import collections
 import os
 import random
 import threading
@@ -225,26 +226,41 @@ _STATE_NAMES = {CLOSED: "closed", HALF_OPEN: "half_open", OPEN: "open"}
 #: live breakers by id(breaker) for /debug/resilience + bundle snapshots.
 #: WEAK values: a breaker abandoned without retire() (its owner dropped on
 #: an error path) must not leak here forever, nor keep pinning the shared
-#: {op} gauge at OPEN — a finalizer re-publishes the op when one is GC'd
+#: {op} gauge at OPEN — its finalizer names the op for re-publishing
 _breakers: "weakref.WeakValueDictionary[int, CircuitBreaker]" = \
     weakref.WeakValueDictionary()
-# RLock: a CircuitBreaker's weakref.finalize callback re-acquires this
-# lock, and cyclic GC can fire that callback on a thread ALREADY inside a
-# locked region (any allocation under the lock can trigger collection) —
-# a plain Lock would self-deadlock there
-_breakers_lock = threading.RLock()
+# a plain Lock: nothing that holds it takes it again (a breaker's finalizer,
+# which the collector may run under it, takes no lock at all)
+_breakers_lock = threading.Lock()
+#: ops whose breaker was garbage-collected and whose gauge nobody has
+#: re-published yet. A finalizer runs inside the collector: at any bytecode
+#: of any thread, under whatever locks that thread holds. It therefore takes
+#: NO lock (``deque.append`` is atomic): re-publishing from it re-entered
+#: the registry's lock under a thread that was creating an instrument, and
+#: that thread waited for itself for ever. The gauge is re-published by the
+#: next ``_publish``, snapshot or ``/health`` evaluation instead: the two
+#: readers that decide something (``CircuitOpenRule``, ``circuit_snapshot``)
+#: always see it fresh, while a bare ``/metrics`` scrape of
+#: ``dl4j_circuit_state`` may show an abandoned breaker's OPEN until one of
+#: those three runs.
+_orphaned_ops: "collections.deque[str]" = collections.deque()
 
 
-def _republish_op(op: str):
-    """Recompute one op's worst-of-live-breakers gauge value (runs from
-    CircuitBreaker finalizers after a breaker is garbage-collected)."""
-    try:
-        with _breakers_lock:
-            states = [b._state for b in list(_breakers.values())
-                      if b.op == op]
-        _circuit_gauge(op).set(max(states, default=CLOSED))
-    except Exception:  # graftlint: disable=typed-errors — best-effort
-        pass           # gauge publish; no request outcome flows here
+def _republish_orphans():
+    """Recompute the worst-of-live-breakers gauge of every op that lost a
+    breaker to the collector since the last call."""
+    while _orphaned_ops:
+        try:
+            op = _orphaned_ops.popleft()
+        except IndexError:             # another thread drained it first
+            return
+        try:
+            with _breakers_lock:
+                states = [b._state for b in list(_breakers.values())
+                          if b.op == op]
+            _circuit_gauge(op).set(max(states, default=CLOSED))
+        except Exception:  # graftlint: disable=typed-errors — best-effort
+            pass           # gauge publish; no request outcome flows here
 
 
 class CircuitBreaker:
@@ -266,7 +282,7 @@ class CircuitBreaker:
         self._retired = False
         with _breakers_lock:
             _breakers[id(self)] = self
-        weakref.finalize(self, _republish_op, op)
+        weakref.finalize(self, _orphaned_ops.append, op)
         self._publish()
 
     # state reads/writes under self._lock; the gauge publish happens
@@ -349,6 +365,7 @@ class CircuitBreaker:
         # ParallelInference): the shared {op} series reports the WORST
         # live state, so a fresh/retiring CLOSED breaker can never mask
         # another instance's OPEN circuit on /health
+        _republish_orphans()
         try:
             with _breakers_lock:
                 peers = [b._state for b in list(_breakers.values())
@@ -384,6 +401,7 @@ class CircuitBreaker:
 
 
 def circuit_snapshot() -> list:
+    _republish_orphans()
     with _breakers_lock:
         live = list(_breakers.values())
     return [b.snapshot() for b in live]
@@ -401,6 +419,7 @@ class CircuitOpenRule(SLORule):
         self.metric = metric
 
     def _evaluate(self, registry) -> dict:
+        _republish_orphans()
         inst = registry.get(self.metric)
         if inst is None:
             return {"status": OK, "detail": "no data"}

@@ -13,6 +13,7 @@ import urllib.request
 import jax
 import numpy as np
 import pytest
+from conftest import time_limit
 
 from deeplearning4j_tpu.data.iterators import ArrayDataSetIterator
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
@@ -109,7 +110,8 @@ class TestElasticCheckpointer:
         for _ in range(3):
             net.fit(x, y)
             ck.save(net._iteration, net)          # async
-        ck.wait()
+        with time_limit(60.0):
+            ck.wait()
         assert ck.last_error is None
         # the coalescing latest-slot queue may supersede older pending
         # saves, but the NEWEST one is always committed
@@ -463,7 +465,7 @@ class TestElasticTrainer:
 
 
 # ------------------------------------------------------- subprocess drills
-def _run_drill(args, timeout=300):
+def _run_drill(args, timeout=60):
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)
     env.pop("JAX_NUM_CPU_DEVICES", None)

@@ -612,7 +612,7 @@ def test_fleet_chaos_drill_end_to_end(tmp_path):
          os.path.join(_REPO, "benchmarks", "http_load.py"),
          "--fleet-chaos", "--qps", "10", "--duration-s", "24",
          "--state-dir", str(tmp_path / "fleet"), "--out", str(out)],
-        capture_output=True, text=True, timeout=560,
+        capture_output=True, text=True, timeout=240,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stdout + r.stderr
     rec = json.loads(out.read_text())

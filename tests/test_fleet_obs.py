@@ -5,9 +5,8 @@ federation (worker-label injection, top-N fold, dead-worker partial
 scrape that never 500s), the fleet health rollup (worst-worker
 attribution, leader-published verdict), coordinated incident capture
 (one incident id, every live worker's bundle), the proxy's own metrics
-+ admin surface, the ``tools/bench_diff.py`` OBSFLEET grading, and the
-kill switch (``DL4J_TPU_FLEET_OBS=0`` = byte-identical pre-plane
-behavior). The live 2-worker subprocess drill is ``slow``.
++ admin surface, and the kill switch (``DL4J_TPU_FLEET_OBS=0`` =
+byte-identical pre-plane behavior). The live 2-worker subprocess drill is ``slow``.
 """
 import importlib.util
 import json
@@ -623,55 +622,6 @@ def test_fleet_obs_enabled_reads_live(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# bench_diff grading
-# ---------------------------------------------------------------------------
-
-def test_bench_diff_learns_obsfleet_schema(tmp_path):
-    """OBSFLEET_r*.json (http_load.py --fleet-obs): trace coverage and
-    federation completeness grade sustained-only, scrape p99 is never
-    gated, driver wrappers unwrap, alien JSON is ignored, empty dir is
-    green."""
-    mod = _load_tool("bench_diff")
-    assert mod.load_obsfleet(str(tmp_path)) == []
-    assert mod.main([str(tmp_path)]) == 0               # empty = green
-
-    def write(rnd, cov, comp, p99=20.0, wrap=False):
-        rec = {"metric": "obsfleet_drill", "platform": "cpu",
-               "value": cov, "trace_coverage": cov,
-               "federation_completeness": comp, "scrape_p99_ms": p99}
-        doc = {"n": rnd, "parsed": rec} if wrap else rec
-        (tmp_path / f"OBSFLEET_r{rnd:02d}.json").write_text(
-            json.dumps(doc))
-
-    write(1, 1.0, 1.0)
-    write(2, 0.98, 1.0, wrap=True)                      # wrapper unwraps
-    write(3, 1.0, 1.0, p99=500.0)                       # p99 never gated
-    samples = mod.load_obsfleet(str(tmp_path))
-    assert [s.round for s in samples] == [1, 2, 3]
-    assert samples[1].trace_coverage == pytest.approx(0.98)
-    assert mod.check_obsfleet(samples) == []
-    assert mod.main([str(tmp_path)]) == 0
-    # one bad round is weather...
-    write(4, 0.5, 1.0)
-    assert mod.check_obsfleet(mod.load_obsfleet(str(tmp_path))) == []
-    # ...two in a row is a sustained coverage regression
-    write(5, 0.5, 1.0)
-    regs = mod.check_obsfleet(mod.load_obsfleet(str(tmp_path)))
-    assert [(r.metric, r.series) for r in regs] == [
-        ("obsfleet_drill", "trace_coverage")]
-    assert mod.main([str(tmp_path)]) == 1
-    # a completeness collapse grades the same way
-    write(4, 1.0, 0.5)
-    write(5, 1.0, 0.5)
-    regs = mod.check_obsfleet(mod.load_obsfleet(str(tmp_path)))
-    assert [r.series for r in regs] == ["federation_completeness"]
-    # alien / unreadable JSON is ignored, never fatal
-    (tmp_path / "OBSFLEET_r06.json").write_text("not json {")
-    (tmp_path / "OBSFLEET_r07.json").write_text('{"whatever": 1}')
-    assert len(mod.load_obsfleet(str(tmp_path))) == 5
-
-
-# ---------------------------------------------------------------------------
 # the live 2-worker drill (subprocess; slow)
 # ---------------------------------------------------------------------------
 
@@ -683,7 +633,7 @@ def test_fleet_obs_drill_live(tmp_path):
          "--fleet-obs", "--obs-requests", "20", "--obs-scrapes", "8",
          "--state-dir", str(tmp_path / "fleet"), "--out", str(out)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=600)
+        capture_output=True, text=True, timeout=240)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     rec = json.loads(out.read_text())
     assert rec["ok_verdict"]

@@ -542,7 +542,7 @@ def test_two_worker_fleet_kill_drill_over_real_http(tmp_path):
          "--qps", "12", "--duration-s", "20", "--workers", "2",
          "--kill-drill", "--state-dir", str(tmp_path / "fleet"),
          "--out", str(out)],
-        capture_output=True, text=True, timeout=560,
+        capture_output=True, text=True, timeout=240,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stdout + r.stderr
     rec = json.loads(out.read_text())

@@ -3,9 +3,9 @@ hard-won invariants (ISSUE 14).
 
 Per rule: a fixture snippet the rule MUST flag and one it must NOT
 flag; plus the framework contracts — inline suppressions, baseline
-freezing, one shared parse, CLI exit codes — and the tier-1 gates:
-the whole package is green against the checked-in baseline, and
-``tools/lint_all.py`` (graftlint + bench_diff) passes.
+freezing, one shared parse, CLI exit codes — and the tier-1 gate:
+the whole package is green against the checked-in baseline, through
+``run_lint`` and through the command line.
 """
 from __future__ import annotations
 
@@ -780,17 +780,11 @@ def test_package_is_green_against_the_baseline():
     # the checked-in baseline stays empty: exemptions are inline
     doc = json.loads(open(default_baseline_path()).read())
     assert doc["entries"] == []
-    # budget: the full-repo run must never pressure the tier-1 window
-    # (<10 s target; generous bar for noisy CI boxes)
-    assert res.seconds < 30.0
 
 
-def test_lint_all_single_exit_code(capsys):
-    """The one CI entry: graftlint + bench_diff trajectory grading —
-    including the benchmarks/ab archive that holds the DECODE/SERVE/QOS
-    records (bench_diff's root glob is non-recursive)."""
-    sys.path.insert(0, os.path.join(_REPO_ROOT, "tools"))
-    import lint_all
-    assert lint_all.main([]) == 0
-    out = capsys.readouterr().out
-    assert "== bench_diff (benchmarks/ab) ==" in out
+def test_cli_exits_zero_on_the_repo(capsys):
+    """The command a shell or CI step runs, ``python -m tools.graftlint``:
+    exit code 0 on the package as it stands."""
+    from tools.graftlint.cli import main
+    assert main([]) == 0
+    assert "graftlint: OK" in capsys.readouterr().out

@@ -66,7 +66,6 @@ class TestArrowBridge:
 
 
 class TestGraphRunner:
-    @pytest.mark.slow
     def test_runs_frozen_tf_graph_on_ndarrays(self):
         tf = pytest.importorskip("tensorflow")
         from deeplearning4j_tpu.interop import GraphRunner

@@ -32,7 +32,7 @@ def test_import_suite_under_legacy_keras2():
     r = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", *_REPRESENTATIVE],
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        capture_output=True, text=True, timeout=900, env=env)
+        capture_output=True, text=True, timeout=240, env=env)
     assert r.returncode == 0, (
         f"Keras-2 compat subset failed:\n{r.stdout[-2000:]}\n"
         f"{r.stderr[-1000:]}")

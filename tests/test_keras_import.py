@@ -17,9 +17,6 @@ def _save(model, tmp_path, name="m.h5"):
     return p
 
 
-@pytest.mark.slow
-
-
 def test_sequential_dense(tmp_path):
     m = tf.keras.Sequential([
         tf.keras.Input((6,)),
@@ -82,9 +79,6 @@ def test_locally_connected_impl2_dense_kernel_extraction():
                                np.asarray(pb["0"]["W"]), atol=0)
 
 
-@pytest.mark.slow
-
-
 def test_sequential_cnn_with_bn(tmp_path):
     m = tf.keras.Sequential([
         tf.keras.Input((12, 12, 3)),
@@ -124,9 +118,6 @@ def test_sequential_separable_conv(tmp_path):
                        atol=1e-5)
 
 
-@pytest.mark.slow
-
-
 def test_sequential_lstm(tmp_path):
     m = tf.keras.Sequential([
         tf.keras.Input((7, 5)),
@@ -140,9 +131,6 @@ def test_sequential_lstm(tmp_path):
     expected = m.predict(x, verbose=0)
     got = np.asarray(net.output(x))
     assert np.allclose(got, expected, atol=1e-4), np.abs(got - expected).max()
-
-
-@pytest.mark.slow
 
 
 def test_sequential_gru(tmp_path):
@@ -284,9 +272,6 @@ def test_sequential_timedistributed_dense(tmp_path):
     expected = m.predict(x, verbose=0)
     got = np.asarray(net.output(x))
     assert np.allclose(got, expected, atol=1e-5)
-
-
-@pytest.mark.slow
 
 
 def test_sequential_bidirectional_lstm(tmp_path):
@@ -594,8 +579,6 @@ class TestLongTailLayers:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
 
-    @pytest.mark.slow
-
     def test_conv_lstm_2d(self, tmp_path):
         for ret_seq in (False, True):
             m = tf.keras.Sequential([
@@ -729,7 +712,13 @@ def test_conv2d_transpose_dilation(tmp_path):
             path)
         ref = tf.keras.models.load_model(path)   # artifact semantics
         x = rng.rand(2, 7, 9, 3).astype("f4")
-        expected = ref.predict(x, verbose=0)
+        try:
+            expected = ref.predict(x, verbose=0)
+        except tf.errors.InvalidArgumentError as e:
+            # which CPU kernel TensorFlow picks depends on the machine: on
+            # the chip's host (PR 29) it refused dilation > 1 outright
+            pytest.skip(f"TensorFlow cannot compute the reference here: "
+                        f"{str(e).splitlines()[-1][:120]}")
         got = np.asarray(net.output(x))
         assert got.shape == expected.shape, (kw, got.shape, expected.shape)
         assert np.allclose(got, expected, atol=1e-4), (

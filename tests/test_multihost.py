@@ -12,6 +12,7 @@ import os
 import socket
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -85,6 +86,30 @@ def _clean_env():
     return env
 
 
+def _communicate_all(procs, seconds, what, wait_for=None):
+    """The output of every rank in ``wait_for`` (default: all of them), all
+    inside ONE limit; a rank still running at the limit fails the test, and
+    no rank of ``procs`` outlives the call either way."""
+    deadline = time.monotonic() + seconds
+    try:
+        outs = []
+        for p in (procs if wait_for is None else wait_for):
+            try:
+                out, _ = p.communicate(
+                    timeout=max(deadline - time.monotonic(), 0.1))
+            except subprocess.TimeoutExpired:
+                pytest.fail(f"{what}: a worker had not ended after "
+                            f"{seconds:g} s")
+            outs.append(out)
+        return outs
+    finally:
+        # never leak a worker blocked in a cross-process collective
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+                p.communicate(timeout=30)
+
+
 @pytest.mark.parametrize("nprocs", [2, 4])
 def test_n_process_training_matches_single_process(
         tmp_path, nprocs, _needs_multiprocess_collectives):
@@ -98,15 +123,7 @@ def test_n_process_training_matches_single_process(
         [sys.executable, WORKER, str(i), str(nprocs), str(port), out_n],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env)
         for i in range(nprocs)]
-    outs = []
-    for p in procs:
-        try:
-            out, _ = p.communicate(timeout=420)
-        except subprocess.TimeoutExpired:
-            for q in procs:
-                q.kill()
-            pytest.fail(f"{nprocs}-process multihost worker timed out")
-        outs.append(out)
+    outs = _communicate_all(procs, 120.0, f"{nprocs}-process multihost")
     for i, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {i} failed:\n{out[-4000:]}"
 
@@ -133,7 +150,7 @@ for step in range(5):
     tr.fit(x, y)
 np.save({ref_out!r}, np.asarray(net.params().buf()))
 """],
-        capture_output=True, text=True, env=env, timeout=420)
+        capture_output=True, text=True, env=env, timeout=120)
     assert single.returncode == 0, single.stderr[-4000:]
 
     np.testing.assert_allclose(np.load(out_n), np.load(ref_out),
@@ -143,42 +160,27 @@ np.save({ref_out!r}, np.asarray(net.params().buf()))
 ELASTIC = os.path.join(REPO, "tests", "elastic_worker.py")
 
 
-def _run_elastic(nsteps, port, ckpt_dir, out, die_at=-1, timeout=420,
-                 expect_kill=False):
+def _run_elastic(nsteps, port, ckpt_dir, out, die_at=-1, expect_kill=False):
+    timeout = 90.0          # three runs a test, inside the per-test limit
     env = _clean_env()
     procs = [subprocess.Popen(
         [sys.executable, ELASTIC, str(i), "2", str(port), ckpt_dir, out,
          str(nsteps), str(die_at)],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env)
         for i in range(2)]
-    try:
-        if not expect_kill:
-            outs = []
-            for p in procs:
-                try:
-                    o, _ = p.communicate(timeout=timeout)
-                except subprocess.TimeoutExpired:
-                    pytest.fail("elastic worker timed out")
-                outs.append(o)
-            for i, (p, o) in enumerate(zip(procs, outs)):
-                assert p.returncode == 0, f"elastic worker {i}:\n{o[-4000:]}"
-            return outs
-        # fault arm: worker 1 SIGKILLs itself; worker 0 then hangs in the
-        # next collective and is reaped below (the Spark-analog "job fails,
-        # restart from checkpoint" path)
-        try:
-            o1, _ = procs[1].communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            pytest.fail("fault-arm worker 1 neither died nor finished")
-        assert procs[1].returncode == -9, \
-            f"worker1 expected SIGKILL, rc={procs[1].returncode}:\n{o1[-2000:]}"
-        return None
-    finally:
-        # never leak a worker blocked in a cross-process collective
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-                p.communicate()
+    if not expect_kill:
+        outs = _communicate_all(procs, timeout, "elastic")
+        for i, (p, o) in enumerate(zip(procs, outs)):
+            assert p.returncode == 0, f"elastic worker {i}:\n{o[-4000:]}"
+        return outs
+    # fault arm: worker 1 SIGKILLs itself; worker 0 then hangs in the next
+    # collective and is reaped by the helper (the Spark-analog "job fails,
+    # restart from checkpoint" path)
+    (o1,) = _communicate_all(procs, timeout, "fault-arm worker 1",
+                             wait_for=procs[1:])
+    assert procs[1].returncode == -9, \
+        f"worker1 expected SIGKILL, rc={procs[1].returncode}:\n{o1[-2000:]}"
+    return None
 
 
 def test_sigkill_mid_run_then_resume_matches_uninterrupted(

@@ -416,8 +416,6 @@ class TestFactoryTranche2:
                                    [1, 2])
         assert int(nd.argMax(a).item()) == 3
 
-    @pytest.mark.slow
-
     def test_random_statics_reproducible(self):
         from deeplearning4j_tpu.ndarray import factory as nd
         nd.setSeed(99)

@@ -235,8 +235,6 @@ class TestExplicitPreprocessors:
         assert net.score() < s0
         assert np.asarray(net.output(x)).shape == (8, 3)
 
-    @pytest.mark.slow
-
     def test_rnn_ff_round_trip_preprocessors(self):
         from deeplearning4j_tpu.nn.conf.preprocessors import (
             FeedForwardToRnnPreProcessor, RnnToFeedForwardPreProcessor)
@@ -276,9 +274,6 @@ class TestExplicitPreprocessors:
         net = MultiLayerNetwork(conf2).init()
         out = net.output(np.zeros((2, 16), np.float32))
         assert np.asarray(out).shape == (2, 2)
-
-
-@pytest.mark.slow
 
 
 def test_computation_graph_rnn_time_step():
@@ -330,9 +325,6 @@ def test_computation_graph_tbptt_trains():
     for _ in range(8):
         cg.fit((x,), (y,))
     assert cg.score() < s0
-
-
-@pytest.mark.slow
 
 
 def test_computation_graph_tbptt_with_masks():

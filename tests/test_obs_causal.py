@@ -81,7 +81,8 @@ def test_trace_context_crosses_threads_with_flow_events():
 
         t = threading.Thread(target=worker)
         t.start()
-        t.join()
+        t.join(30.0)
+        assert not t.is_alive()
     recs = {r.name: r for r in sink.spans()}
     assert recs["consumer"].trace_id == recs["producer"].trace_id
     assert recs["consumer"].parent_id == recs["producer"].span_id
@@ -435,7 +436,8 @@ def test_flight_recorder_thread_excepthook_dumps(tmp_path, monkeypatch):
 
         t = threading.Thread(target=die, name="crasher")
         t.start()
-        t.join()
+        t.join(30.0)
+        assert not t.is_alive()
         assert rec.dumps, "fatal thread exception did not dump"
         cfg = json.loads(open(os.path.join(rec.dumps[0],
                                            "config.json")).read())

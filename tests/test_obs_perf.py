@@ -1,6 +1,6 @@
 """Performance observatory (ISSUE 6): XLA cost-model accounting, live
-MFU/roofline, perf-regression SLO, on-demand profiler capture, bench
-trajectory diff, metric/knob lints."""
+MFU/roofline, perf-regression SLO, on-demand profiler capture,
+metric/knob lints."""
 import importlib.util
 import json
 import os
@@ -409,10 +409,11 @@ def test_debug_profile_http_roundtrip(tmp_path, monkeypatch):
         assert ei.value.code == 403
     finally:
         stop.set()
-        t.join()
+        t.join(30.0)
         server.stop()
         pc.reset_global_profile_capture()
         reset_global_registry()
+    assert not t.is_alive()
 
 
 def test_real_device_profiler_capture(tmp_path):
@@ -437,327 +438,14 @@ def test_real_device_profiler_capture(tmp_path):
         rec = cap.capture(steps=1, timeout_s=15)
     finally:
         stop.set()
-        t.join()
+        t.join(30.0)
+    assert not t.is_alive()
     assert rec["steps_seen"] >= 1
     assert os.path.isdir(rec["trace_dir"])
     if "parse_error" not in rec:
         assert isinstance(rec["top_ops"], list)
         assert rec["source"] in ("device", "host")
     reset_global_registry()
-
-
-# ---------------------------------------------------------------------------
-# bench trajectory diff (tools/bench_diff.py)
-# ---------------------------------------------------------------------------
-
-def test_bench_diff_green_on_repo_history(capsys):
-    """The archived BENCH_r*.json trajectory holds no sustained
-    regression (the round-4 single-sample dip is weather, not climate)."""
-    mod = _load_tool("bench_diff")
-    assert mod.main([_REPO_ROOT]) == 0
-
-
-def _sample(rnd, vs_baseline, platform="tpu", metric="m", mfu=None):
-    mod = _load_tool("bench_diff")
-    return mod.Sample(round=rnd, path=f"BENCH_r{rnd:02d}.json",
-                      metric=metric, platform=platform,
-                      vs_baseline=vs_baseline, mfu=mfu,
-                      device_timed=mfu is not None, value=1.0)
-
-
-def test_bench_diff_detects_sustained_regression():
-    mod = _load_tool("bench_diff")
-    history = [_sample(r, v) for r, v in
-               enumerate([1.0, 1.02, 0.98, 0.6, 0.62], start=1)]
-    regs = mod.check_trajectory(history)
-    assert len(regs) == 1
-    assert regs[0].series == "vs_baseline" and regs[0].rounds == (4, 5)
-
-
-def test_bench_diff_single_dip_is_not_a_regression():
-    """One bad round (this box's ±40% weather) never fails the gate —
-    only a SUSTAINED drop does."""
-    mod = _load_tool("bench_diff")
-    history = [_sample(r, v) for r, v in
-               enumerate([1.0, 1.02, 0.98, 0.6, 1.01], start=1)]
-    assert mod.check_trajectory(history) == []
-
-
-def test_bench_diff_ignores_platform_changes():
-    """A CPU-fallback round is incomparable with the TPU trajectory: the
-    gate only grades rounds on the newest round's platform."""
-    mod = _load_tool("bench_diff")
-    history = ([_sample(r, 1.0) for r in (1, 2, 3)]
-               + [_sample(4, 0.4, platform="cpu"),
-                  _sample(5, 0.4, platform="cpu")])
-    # newest platform is cpu → only 2 comparable rounds → thin-data skip
-    assert mod.check_trajectory(history) == []
-    history = [_sample(r, 1.0) for r in (1, 2, 3)] \
-        + [_sample(4, 0.4, platform="cpu"), _sample(5, 1.0)]
-    assert mod.check_trajectory(history) == []
-
-
-def test_bench_diff_grades_device_mfu_series():
-    mod = _load_tool("bench_diff")
-    history = [_sample(r, None, mfu=m) for r, m in
-               enumerate([0.46, 0.45, 0.47, 0.30, 0.31], start=1)]
-    regs = mod.check_trajectory(history)
-    assert len(regs) == 1 and regs[0].series == "device_mfu"
-
-
-def test_bench_diff_empty_or_missing_trajectory_is_clean(tmp_path):
-    """A fresh checkout (no BENCH_r*/MULTICHIP_r* archives) or a bogus
-    root grades clean — exit 0, no crash, an explicit message."""
-    mod = _load_tool("bench_diff")
-    assert mod.main([str(tmp_path)]) == 0
-    assert mod.main([str(tmp_path / "never_created")]) == 0
-    assert mod.check_trajectory([]) == []
-    assert mod.check_multichip([]) == []
-
-
-def test_bench_diff_learns_multichip_dryruns(tmp_path):
-    """MULTICHIP_r*.json driver dryruns ({n_devices, rc, ok, skipped,
-    tail} — no 'metric' key) load as a boolean trajectory: newest
-    non-skipped round failing = a break; an OLD failure healed by a
-    newer pass, and skipped rounds, stay green. Unreadable/alien JSON is
-    ignored, never fatal."""
-    import json as _json
-    mod = _load_tool("bench_diff")
-
-    def write(rnd, **doc):
-        p = tmp_path / f"MULTICHIP_r{rnd:02d}.json"
-        p.write_text(_json.dumps(doc))
-        return p
-
-    write(1, n_devices=8, rc=1, ok=False, skipped=False, tail="boom")
-    write(2, n_devices=8, rc=0, ok=True, skipped=False, tail="OK")
-    write(3, skipped=True)
-    (tmp_path / "MULTICHIP_r04.json").write_text("not json {")
-    samples = mod.load_multichip(str(tmp_path))
-    assert [(s.round, s.ok, s.skipped) for s in samples] == [
-        (1, False, False), (2, True, False), (3, False, True)]
-    # newest non-skipped round (r02) passes → the r01 failure is history
-    assert mod.check_multichip(samples) == []
-    assert mod.main([str(tmp_path)]) == 0
-    # a failing newest round IS a break (boolean — no noise to sustain)
-    write(5, n_devices=8, rc=3, ok=False, skipped=False, tail="died")
-    samples = mod.load_multichip(str(tmp_path))
-    breaks = mod.check_multichip(samples)
-    assert len(breaks) == 1 and "r05" in breaks[0]
-    assert mod.main([str(tmp_path)]) == 1
-
-
-def test_bench_diff_learns_decode_schema(tmp_path):
-    """DECODE_r*.json decode-bench archives: the combined {kv, cb}
-    document loads both records, the A/B ratios + slot-occupancy mean
-    grade sustained-only like the bench ratios, raw tokens/s is never
-    gated, and alien/unreadable JSON is ignored."""
-    import json as _json
-    mod = _load_tool("bench_diff")
-
-    def write(rnd, kv_ratio, occ):
-        p = tmp_path / f"DECODE_r{rnd:02d}.json"
-        p.write_text(_json.dumps({
-            "kv": {"metric": "decode_kv_cache", "platform": "cpu",
-                   "vs_naive": kv_ratio, "value": 500.0},
-            "cb": {"metric": "decode_continuous_batching",
-                   "platform": "cpu", "vs_static": 1.4,
-                   "slot_occupancy": occ, "value": 700.0}}))
-
-    for rnd, ratio in enumerate([7.0, 6.6, 7.2], start=1):
-        write(rnd, ratio, [0.85, 0.9])
-    samples = mod.load_decode(str(tmp_path))
-    assert len(samples) == 6               # 2 records per round
-    assert {s.metric for s in samples} == {
-        "decode_kv_cache", "decode_continuous_batching"}
-    assert mod.check_decode(samples) == []
-    assert mod.main([str(tmp_path)]) == 0
-    # a single dip is weather; a sustained collapse is a regression
-    write(4, 2.0, [0.86])
-    assert mod.check_decode(mod.load_decode(str(tmp_path))) == []
-    write(5, 2.1, [0.87])
-    regs = mod.check_decode(mod.load_decode(str(tmp_path)))
-    assert len(regs) == 1
-    assert regs[0].metric == "decode_kv_cache"
-    assert regs[0].series == "ab_ratio" and regs[0].rounds == (4, 5)
-    assert mod.main([str(tmp_path)]) == 1
-    # occupancy trajectory collapse is graded the same way
-    write(4, 7.0, [0.3]), write(5, 7.0, [0.3])
-    regs = mod.check_decode(mod.load_decode(str(tmp_path)))
-    assert [r.series for r in regs] == ["slot_occupancy"]
-    # alien / unreadable JSON is ignored, never fatal
-    (tmp_path / "DECODE_r06.json").write_text("not json {")
-    (tmp_path / "DECODE_r07.json").write_text('{"whatever": 1}')
-    assert len(mod.load_decode(str(tmp_path))) == 10
-
-
-def test_bench_diff_decode_raw_rate_is_not_gated(tmp_path):
-    """Raw tokens/s may crater (box weather) without failing the gate —
-    only the interleaved A/B ratios and occupancy grade."""
-    import json as _json
-    mod = _load_tool("bench_diff")
-    for rnd, rate in enumerate([900.0, 880.0, 910.0, 100.0, 95.0],
-                               start=1):
-        (tmp_path / f"DECODE_r{rnd:02d}.json").write_text(_json.dumps(
-            {"kv": {"metric": "decode_kv_cache", "platform": "cpu",
-                    "vs_naive": 7.0, "value": rate}}))
-    assert mod.check_decode(mod.load_decode(str(tmp_path))) == []
-    assert mod.main([str(tmp_path)]) == 0
-
-
-def test_bench_diff_learns_paged_quant_spec_fields(tmp_path):
-    """The PR-13 decode arms: vs_dense_cache / vs_f32 / vs_no_spec are
-    graded as each metric's A/B ratio (sustained-only), while the
-    speculative accept ratio is loaded and REPORTED but never gated —
-    an accept-rate collapse alone cannot fail the trajectory."""
-    import json as _json
-    mod = _load_tool("bench_diff")
-
-    def write(rnd, paged=2.0, quant=0.8, spec=1.5, accept=0.8):
-        (tmp_path / f"DECODE_r{rnd:02d}.json").write_text(_json.dumps({
-            "paged": {"metric": "decode_paged_cache", "platform": "cpu",
-                      "vs_dense_cache": paged, "value": 600.0},
-            "quant": {"metric": "decode_kv_quant", "platform": "cpu",
-                      "vs_f32": quant, "value": 450.0},
-            "spec": {"metric": "decode_speculative", "platform": "cpu",
-                     "vs_no_spec": spec, "spec_accept_ratio": accept,
-                     "value": 900.0}}))
-
-    for rnd in (1, 2, 3):
-        write(rnd)
-    samples = mod.load_decode(str(tmp_path))
-    assert {s.metric for s in samples} == {
-        "decode_paged_cache", "decode_kv_quant", "decode_speculative"}
-    spec = [s for s in samples if s.metric == "decode_speculative"][0]
-    assert spec.ratio == 1.5 and spec.accept_ratio == 0.8
-    assert mod.check_decode(samples) == []
-    # accept-rate collapse alone: reported, never a regression
-    write(4, accept=0.05), write(5, accept=0.05)
-    assert mod.check_decode(mod.load_decode(str(tmp_path))) == []
-    # a sustained vs_no_spec collapse IS one, attributed to its metric
-    write(4, spec=0.5, accept=0.8), write(5, spec=0.5, accept=0.8)
-    regs = mod.check_decode(mod.load_decode(str(tmp_path)))
-    assert [(r.metric, r.series) for r in regs] == [
-        ("decode_speculative", "ab_ratio")]
-    # same discipline for the paged and quant ratios
-    write(4, paged=0.9, spec=1.5), write(5, paged=0.9, spec=1.5)
-    regs = mod.check_decode(mod.load_decode(str(tmp_path)))
-    assert [(r.metric, r.series) for r in regs] == [
-        ("decode_paged_cache", "ab_ratio")]
-    assert mod.main([str(tmp_path)]) == 1
-
-
-def test_bench_diff_learns_serve_schema(tmp_path):
-    """SERVE_r*.json HTTP-load archives (benchmarks/http_load.py): the
-    interleaved vs_direct ratio + goodput grade sustained-only, raw
-    p50/p99 latency is never gated, driver wrappers are unwrapped, and
-    alien/unreadable JSON is ignored."""
-    import json as _json
-    mod = _load_tool("bench_diff")
-
-    def write(rnd, ratio, goodput, p99=150.0, wrap=False):
-        rec = {"metric": "http_serve", "platform": "cpu",
-               "vs_direct": ratio, "goodput": goodput, "value": goodput,
-               "p99_ms": p99, "failed": 0}
-        doc = {"n": rnd, "parsed": rec} if wrap else rec
-        (tmp_path / f"SERVE_r{rnd:02d}.json").write_text(_json.dumps(doc))
-
-    for rnd, (ratio, gp) in enumerate(
-            [(0.5, 100.0), (0.46, 104.0), (0.52, 98.0)], start=1):
-        write(rnd, ratio, gp, wrap=(rnd == 2))   # wrapper unwrapped too
-    samples = mod.load_serve(str(tmp_path))
-    assert [s.round for s in samples] == [1, 2, 3]
-    assert samples[1].vs_direct == pytest.approx(0.46)
-    assert mod.check_serve(samples) == []
-    assert mod.main([str(tmp_path)]) == 0
-    # one bad round is weather...
-    write(4, 0.2, 101.0)
-    assert mod.check_serve(mod.load_serve(str(tmp_path))) == []
-    # ...two in a row is a sustained ratio regression
-    write(5, 0.21, 99.0)
-    regs = mod.check_serve(mod.load_serve(str(tmp_path)))
-    assert len(regs) == 1
-    assert regs[0].metric == "http_serve"
-    assert regs[0].series == "ab_ratio" and regs[0].rounds == (4, 5)
-    assert mod.main([str(tmp_path)]) == 1
-    # goodput collapse is graded the same way; p99 never is
-    write(4, 0.5, 20.0, p99=9000.0)
-    write(5, 0.5, 19.0, p99=9000.0)
-    regs = mod.check_serve(mod.load_serve(str(tmp_path)))
-    assert [r.series for r in regs] == ["goodput"]
-    # platform filter: CPU-fallback history doesn't grade a TPU round
-    write(4, 0.5, 100.0)
-    (tmp_path / "SERVE_r05.json").write_text(_json.dumps(
-        {"metric": "http_serve", "platform": "tpu", "vs_direct": 0.9,
-         "goodput": 5000.0}))
-    assert mod.check_serve(mod.load_serve(str(tmp_path))) == []
-    # alien / unreadable JSON is ignored, never fatal
-    (tmp_path / "SERVE_r06.json").write_text("not json {")
-    (tmp_path / "SERVE_r07.json").write_text('{"whatever": 1}')
-    assert len(mod.load_serve(str(tmp_path))) == 5
-    assert mod.main([str(tmp_path)]) == 0
-
-
-def test_bench_diff_learns_fleet_schema(tmp_path):
-    """FLEET_r*.json chaos-drill archives (http_load.py --fleet-chaos):
-    goodput-under-chaos + the duplicate-execution ratio grade
-    sustained-only, the leader-term/stage booleans gate like MULTICHIP
-    (newest round must pass), raw p99 is never gated, and alien/empty
-    JSON is green."""
-    import json as _json
-    mod = _load_tool("bench_diff")
-
-    def write(rnd, goodput, dups=0, terms=True, regressed=False,
-              p99=300.0, wrap=False):
-        rec = {"metric": "fleet_chaos", "platform": "cpu",
-               "goodput_ratio": goodput, "value": goodput,
-               "duplicate_executions": dups, "terms_monotonic": terms,
-               "stage_regressed": regressed, "p99_ms": p99}
-        doc = {"n": rnd, "parsed": rec} if wrap else rec
-        (tmp_path / f"FLEET_r{rnd:02d}.json").write_text(_json.dumps(doc))
-
-    for rnd, gp in enumerate([0.97, 0.95, 0.98], start=1):
-        write(rnd, gp, wrap=(rnd == 2))           # wrapper unwrapped too
-    samples = mod.load_fleet(str(tmp_path))
-    assert [s.round for s in samples] == [1, 2, 3]
-    assert samples[0].dup_free == pytest.approx(1.0)
-    assert mod.check_fleet(samples) == []
-    assert mod.check_fleet_bool(samples) == []
-    assert mod.main([str(tmp_path)]) == 0
-    # one bad goodput round is weather...
-    write(4, 0.5)
-    assert mod.check_fleet(mod.load_fleet(str(tmp_path))) == []
-    # ...two in a row is a sustained regression
-    write(5, 0.52)
-    regs = mod.check_fleet(mod.load_fleet(str(tmp_path)))
-    assert [r.series for r in regs] == ["goodput"]
-    assert regs[0].rounds == (4, 5)
-    assert mod.main([str(tmp_path)]) == 1
-    # duplicate executions drive the dup_free ratio below the floor
-    write(4, 0.97, dups=2)
-    write(5, 0.96, dups=1)
-    regs = mod.check_fleet(mod.load_fleet(str(tmp_path)))
-    assert [r.series for r in regs] == ["dup_free"]
-    # the boolean audit gates like MULTICHIP: newest round failing = break
-    write(4, 0.97)
-    write(5, 0.96, terms=False, regressed=True)
-    assert mod.check_fleet(mod.load_fleet(str(tmp_path))) == []
-    breaks = mod.check_fleet_bool(mod.load_fleet(str(tmp_path)))
-    assert len(breaks) == 2 and "leader-term" in breaks[0]
-    assert mod.main([str(tmp_path)]) == 2
-    # p99 collapse alone never gates
-    write(5, 0.97, p99=90000.0)
-    assert mod.check_fleet(mod.load_fleet(str(tmp_path))) == []
-    assert mod.check_fleet_bool(mod.load_fleet(str(tmp_path))) == []
-    # alien / unreadable JSON is ignored, never fatal; empty dir green
-    (tmp_path / "FLEET_r06.json").write_text("not json {")
-    (tmp_path / "FLEET_r07.json").write_text('{"whatever": 1}')
-    assert len(mod.load_fleet(str(tmp_path))) == 5
-    assert mod.main([str(tmp_path)]) == 0
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert mod.load_fleet(str(empty)) == []
-    assert mod.main([str(empty)]) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -784,53 +472,3 @@ def test_cost_model_module_has_no_date_dependence():
     json.dumps(snap, default=str)
     assert set(snap) >= {"enabled", "device_kind", "peak_flops",
                          "hbm_bytes_per_second", "ridge_intensity", "fns"}
-
-
-# ---------------------------------------------------------------------------
-# bench_diff: TRACEQ trace-intelligence trajectory grading
-# ---------------------------------------------------------------------------
-
-def test_bench_diff_learns_traceq_schema(tmp_path):
-    """TRACEQ_r*.json (http_load.py --trace-intel): retention coverage
-    and assembly completeness grade sustained-only, assembly p99 is
-    reported but never gated, driver wrappers unwrap, alien JSON is
-    ignored, empty dir is green."""
-    mod = _load_tool("bench_diff")
-    assert mod.load_traceq(str(tmp_path)) == []
-    assert mod.main([str(tmp_path)]) == 0               # empty = green
-
-    def write(rnd, cov, comp, p99=15.0, wrap=False):
-        rec = {"metric": "traceq_drill", "platform": "cpu",
-               "value": cov, "retention_coverage": cov,
-               "assembly_completeness": comp, "assembly_p99_ms": p99}
-        doc = {"n": rnd, "parsed": rec} if wrap else rec
-        (tmp_path / f"TRACEQ_r{rnd:02d}.json").write_text(
-            json.dumps(doc))
-
-    write(1, 1.0, 1.0)
-    write(2, 0.99, 1.0, wrap=True)                      # wrapper unwraps
-    write(3, 1.0, 1.0, p99=800.0)                       # p99 never gated
-    samples = mod.load_traceq(str(tmp_path))
-    assert [s.round for s in samples] == [1, 2, 3]
-    assert samples[1].retention_coverage == pytest.approx(0.99)
-    assert samples[2].assembly_p99_ms == pytest.approx(800.0)
-    assert mod.check_traceq(samples) == []
-    assert mod.main([str(tmp_path)]) == 0
-    # one bad round is weather...
-    write(4, 0.5, 1.0)
-    assert mod.check_traceq(mod.load_traceq(str(tmp_path))) == []
-    # ...two in a row is a sustained retention regression
-    write(5, 0.5, 1.0)
-    regs = mod.check_traceq(mod.load_traceq(str(tmp_path)))
-    assert [(r.metric, r.series) for r in regs] == [
-        ("traceq_drill", "retention_coverage")]
-    assert mod.main([str(tmp_path)]) == 1
-    # an assembly collapse grades the same way
-    write(4, 1.0, 0.4)
-    write(5, 1.0, 0.4)
-    regs = mod.check_traceq(mod.load_traceq(str(tmp_path)))
-    assert [r.series for r in regs] == ["assembly_completeness"]
-    # alien / unreadable JSON is ignored, never fatal
-    (tmp_path / "TRACEQ_r06.json").write_text("not json {")
-    (tmp_path / "TRACEQ_r07.json").write_text('{"whatever": 1}')
-    assert len(mod.load_traceq(str(tmp_path))) == 5

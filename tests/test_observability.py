@@ -140,7 +140,8 @@ def test_parallel_inference_batched_and_instant():
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(30.0)
+        assert not any(t.is_alive() for t in threads)
         got = np.concatenate([results[0], results[2]])
         assert np.allclose(got, direct, atol=1e-5)
     finally:
@@ -588,7 +589,8 @@ def test_metrics_registry_thread_safety():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(30.0)
+    assert not any(t.is_alive() for t in threads)
     total = sum(c.labels(t=str(i)).value for i in range(4))
     assert total == 8000 and h.count == 8000
 

@@ -1959,14 +1959,8 @@ case("hardsigmoid_derivative", "hardsigmoid_derivative",
                         np.float32(0.0)))
 
 
-# torch-twin cases pay a one-time ~15s torch import the moment the first
-# one runs; the ops they cover also have tf/optax/numpy twins or jit
-# coverage elsewhere, so tier-1 skips the torch family whole (marking
-# only the first case would just move the import to the second)
 @pytest.mark.parametrize(
-    "spec", [pytest.param(c, marks=pytest.mark.slow)
-             if c[0].endswith("_torch") else c for c in CASES],
-    ids=[c[0] for c in CASES])
+    "spec", CASES, ids=[c[0] for c in CASES])
 def test_op_matches_twin(spec):
     id_, op, args, attrs, twin, rtol, atol, out, dtype_strict = spec
     # This jax build's platform default lowers f32 matmuls to bf16 passes
@@ -2005,9 +1999,6 @@ def test_conformance_sweep_coverage_gate():
     assert len(swept) >= 470, (
         f"conformance sweep covers {len(swept)} registry ops; the gate "
         f"floor is 470 — do not shrink the sweep")
-
-
-@pytest.mark.slow
 
 
 def test_ctc_loss_matches_tf():
@@ -2245,9 +2236,6 @@ def test_binomial_and_bernoulli_moments():
     assert set(np.unique(b)) <= {0.0, 1.0}
 
 
-@pytest.mark.slow
-
-
 def test_random_gamma_poisson_exponential_moments():
     import jax as _jax
     key = _jax.random.key(0)
@@ -2268,9 +2256,6 @@ def test_random_shuffle_is_permutation():
     y = np.asarray(exec_op("random_shuffle", _jax.random.key(2), x))
     assert not np.array_equal(y, x)
     assert np.array_equal(np.sort(y), x)
-
-
-@pytest.mark.slow
 
 
 def test_random_categorical_frequencies():

@@ -188,8 +188,6 @@ class TestImage:
         out = exec_op("adjust_saturation", x, 0.0)
         assert out.shape == x.shape
 
-    @pytest.mark.slow
-
     def test_crop_and_resize(self):
         x = jnp.arange(16.0).reshape(1, 4, 4, 1)
         out = exec_op("crop_and_resize", x,
@@ -294,7 +292,6 @@ class TestRnnLayerOps:
 
 
 class TestRandomExtended:
-    @pytest.mark.slow
     def test_distributions(self):
         key = jax.random.key(0)
         g = exec_op("random_gamma", key, 2.0, shape=(1000,))
@@ -329,8 +326,6 @@ class TestSpectralAndLinalgTranche:
         assert r.shape == (4, 9)
         back_r = exec_op("irfft", r)
         np.testing.assert_allclose(_np(back_r), _np(x), atol=1e-5)
-
-    @pytest.mark.slow
 
     def test_ctc_loss_learns_alignment(self):
         import jax

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import time_limit
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import MeshSpec
@@ -59,7 +60,8 @@ class TestShardedCheckpointer:
         with ShardedCheckpointer(str(tmp_path / "ck"),
                                  async_save=True) as ck:
             ck.save(1, {"x": jnp.ones((128,))})
-            ck.wait()
+            with time_limit(60.0):
+                ck.wait()
             assert ck.latest_step() == 1
 
     def test_abstract_like_builder(self):

@@ -47,7 +47,8 @@ def _profile_with_worker_span(log_dir):
             pass
         t = threading.Thread(target=work)
         t.start()
-        t.join()
+        t.join(30.0)
+        assert not t.is_alive()
         with jax.profiler.TraceAnnotation("bracket_close"):
             pass
     finally:
@@ -85,7 +86,7 @@ def test_trace_kill_switch_keeps_spans_out_of_the_profile(tmp_path):
         "print('OK')\n" % (ROOT, os.path.dirname(__file__), str(tmp_path)))
     env = dict(os.environ, DL4J_TPU_TRACE="0")
     out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=300)
+                         capture_output=True, text=True, timeout=120)
     assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-2000:]
 
 
@@ -101,7 +102,7 @@ def test_tracing_binds_the_annotation_lazily_and_no_backend():
             "assert not xla_bridge._backends, 'a span initialized a backend'\n"
             "print('OK')\n" % ROOT)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=300)
+                         text=True, timeout=120)
     assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-2000:]
 
 

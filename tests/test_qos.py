@@ -6,8 +6,7 @@ front-door quota admission + Retry-After surface, the flooding-tenant
 chaos drill (flooder + victims x faults x deadlines — every request
 resolves typed-or-correct, victims hold, flooder sheds counted per
 tenant), the DL4J_TPU_QOS=0 byte-identical kill switch, the
-default-tenant passthrough, bench_diff's QOS_r*.json trajectory, and
-the tenant-label cardinality lint rule.
+default-tenant passthrough, and the tenant-label cardinality lint rule.
 """
 import json
 import os
@@ -608,9 +607,17 @@ def test_flooding_tenant_chaos_drill():
             target=victim_stream, args=("v1",), daemon=True))
         threads.append(threading.Thread(
             target=victim_stream, args=("v2",), daemon=True))
+    # the flood arrives at once whatever the box: threads started one by
+    # one on a loaded core trickle in slower than the batcher drains them,
+    # the queue never fills and nobody is shed
+    flood_gate = threading.Barrier(160)
+
+    def flood():
+        flood_gate.wait(timeout=60.0)
+        one("flood", 2000)
+
     for _ in range(160):
-        threads.append(threading.Thread(
-            target=one, args=("flood", 2000), daemon=True))
+        threads.append(threading.Thread(target=flood, daemon=True))
     for t in threads:
         t.start()
     for t in threads:
@@ -638,41 +645,8 @@ def test_flooding_tenant_chaos_drill():
 
 
 # ---------------------------------------------------------------------------
-# bench_diff trajectory + lint
+# lint
 # ---------------------------------------------------------------------------
-
-def test_bench_diff_qos_trajectory(tmp_path):
-    from bench_diff import QosSample, check_qos, load_qos, main
-
-    def s(r, ratio, path="x"):
-        return QosSample(round=r, path=path, metric="qos_drill",
-                         platform="cpu", victim_goodput_ratio=ratio,
-                         victim_p99_ratio=1.2, flooder_shed=100)
-
-    # healthy trajectory: green
-    assert check_qos([s(1, 1.0), s(2, 0.98), s(3, 1.01)]) == []
-    # one bad round is weather, two sustained is a regression
-    assert check_qos([s(1, 1.0), s(2, 1.0), s(3, 0.5)]) == []
-    regs = check_qos([s(1, 1.0), s(2, 1.0), s(3, 0.5), s(4, 0.5)])
-    assert len(regs) == 1 and regs[0].series == "victim_goodput"
-    # alien JSON is ignored, a real record parses
-    (tmp_path / "QOS_r01.json").write_text(json.dumps({"foo": 1}))
-    (tmp_path / "QOS_r02.json").write_text(json.dumps({
-        "metric": "qos_drill", "platform": "cpu",
-        "victim_goodput_ratio": 0.97, "victim_p99_ratio": 1.3,
-        "flooder_shed": 42}))
-    samples = load_qos(str(tmp_path))
-    assert len(samples) == 1
-    assert samples[0].victim_goodput_ratio == 0.97
-    assert samples[0].flooder_shed == 42
-    # empty trajectory grades clean (rc 0)
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert main([str(empty)]) == 0
-    # the real repo's archived trajectory grades clean too
-    assert main([os.path.join(os.path.dirname(TOOLS),
-                              "benchmarks", "ab")]) == 0
-
 
 def test_metric_lint_tenant_label_rule():
     from check_metric_names import check_source

@@ -25,9 +25,6 @@ def _conf(remat):
     return b.build()
 
 
-@pytest.mark.slow
-
-
 def test_remat_matches_plain_training():
     rng = np.random.RandomState(0)
     x = rng.rand(8, 8).astype("float32")
@@ -97,9 +94,6 @@ def test_remat_policy_json_roundtrip_and_validation():
         checkpoint_policy("bogus")
 
 
-@pytest.mark.slow
-
-
 def test_transformer_scan_remat_dots_matches():
     """The scan_layers OOM-fix combo (scan + remat + dots policy) is
     numerically identical to the plain loop — only backward memory
@@ -125,9 +119,6 @@ def test_transformer_scan_remat_dots_matches():
     np.testing.assert_allclose(
         np.asarray(outs["loop"][1]["tok_emb"]),
         np.asarray(outs["scan_dots"][1]["tok_emb"]), rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.slow
 
 
 def test_transformer_remat_matches():

@@ -6,6 +6,7 @@ and converges to the same params as an unfaulted run; quarantine skips
 exactly the poisoned batch; kill switch ``DL4J_TPU_RESILIENCE=0``
 restores the pre-resilience behavior.
 """
+import gc
 import json
 import os
 import threading
@@ -261,6 +262,41 @@ def test_circuit_gauge_worst_state_wins_across_instances():
         a.retire()
     assert global_registry().get(
         "dl4j_circuit_state").labels(op="shared.op").value == 0
+
+
+def test_a_breaker_collected_inside_the_registrys_lock_deadlocks_nothing():
+    """The collector runs a dead breaker's finalizer at any bytecode of any
+    thread, so also on a thread that is creating an instrument and holds
+    the registry's lock (tier-1 of PR 28 stopped there, in
+    ``test_sessions.py``, for 24 minutes). The finalizer takes no lock; the
+    op's gauge is re-published when it is next read."""
+    abandoned = CircuitBreaker("orphan.op", failure_threshold=1,
+                               reset_timeout_seconds=60)
+    abandoned.record_failure()
+    assert CircuitOpenRule().evaluate(global_registry())["status"] \
+        == "failing"
+    retired = CircuitBreaker("retired.op")
+    retired.retire()                   # what a pipeline's shutdown does
+    abandoned.cycle, retired.cycle = abandoned, retired
+    del abandoned, retired             # only the collector frees them now
+    # a new registry re-publishes the breakers it still knows, not the
+    # retired one: to re-publish that op is to create its gauge again
+    reset_global_registry()
+    registry = global_registry()
+    collected = threading.Event()
+
+    def collect_under_the_lock():
+        with registry._lock:           # what ``registry.gauge()`` holds
+            gc.collect()
+        collected.set()
+
+    t = threading.Thread(target=collect_under_the_lock, daemon=True)
+    t.start()
+    assert collected.wait(60.0), \
+        "the finalizer waited for a lock its own thread holds"
+    # an OPEN breaker that nobody retired no longer pins /health
+    verdict = CircuitOpenRule().evaluate(global_registry())
+    assert verdict["status"] == "ok", verdict
 
 
 # ------------------------------------------------------------------ serving

@@ -497,8 +497,6 @@ class TestEmissionPeepholes:
                                    hist_off.loss_curve(),
                                    rtol=1e-4, atol=1e-6)
 
-    @pytest.mark.slow
-
     def test_tf_imported_moments_rewrites_and_matches(self):
         """Live-TF e2e: a frozen graph using tf.nn.moments imports and the
         emitted program matches TF's own output (the BERT-layernorm path)."""

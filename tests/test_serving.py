@@ -2,12 +2,14 @@
 rollout with SLO-gated auto-rollback, graceful drain under chaos, the
 persistent compile cache, and the ``DL4J_TPU_ROLLOUT=0`` kill switch.
 """
+import itertools
 import json
 import os
 import subprocess
 import sys
 import threading
 import time
+import types
 import urllib.request
 
 import numpy as np
@@ -26,6 +28,7 @@ from deeplearning4j_tpu.resilience import faults
 from deeplearning4j_tpu.resilience.faults import InjectedFault
 from deeplearning4j_tpu.resilience.policy import (DeadlineExceeded, ShedError,
                                                   ShutdownError)
+from deeplearning4j_tpu.serving import router as router_mod
 from deeplearning4j_tpu.serving import (ModelRegistry, RolloutPolicy,
                                         RolloutState, ServingRouter)
 
@@ -159,9 +162,18 @@ def test_retire_drain_leaves_no_threads_or_inflight_claims():
 
 
 # ------------------------------------------------------------------ rollout
-def test_healthy_rollout_advances_to_full_and_promotes():
+def test_healthy_rollout_advances_to_full_and_promotes(monkeypatch):
     net_a, net_b, _ = _nets()
     reg = _deploy_pair(net_a, net_b)
+    # the latency gate stays on, at the ratio a healthy canary has: the
+    # router reads its clock twice a request, and here every reading is one
+    # tick after the last, so both versions measure the same latency however
+    # loaded the machine is (what a slow canary does to the gate is
+    # test_latency_degraded_canary_rolls_back)
+    ticks = itertools.count()
+    monkeypatch.setattr(
+        router_mod, "time",
+        types.SimpleNamespace(perf_counter=lambda: next(ticks) * 1e-3))
     try:
         router = ServingRouter(reg, "v1")
         ro = router.begin_rollout("v2", _fast_policy())
@@ -518,7 +530,7 @@ def test_compile_cache_dir_from_environment_second_process_hits(tmp_path):
 
     def run():
         r = subprocess.run([sys.executable, "-c", _CACHE_CHILD],
-                           capture_output=True, text=True, timeout=600,
+                           capture_output=True, text=True, timeout=120,
                            env=env)
         assert r.returncode == 0, r.stdout + r.stderr
         return json.loads(r.stdout.strip().splitlines()[-1])

@@ -406,7 +406,7 @@ def test_trace_intel_drill_live(tmp_path):
          "--trace-intel", "--state-dir", str(tmp_path / "fleet"),
          "--out", str(out)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=600)
+        capture_output=True, text=True, timeout=240)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     rec = json.loads(out.read_text())
     assert rec["ok_verdict"]

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models import zoo
-from tests._subproc import run_in_subprocess
 
 
 def test_lenet_mnist():
@@ -280,16 +279,11 @@ def test_zoo_pretrained_cache_round_trip(tmp_path, monkeypatch):
                                np.asarray(net.output(x[:4])), atol=1e-6)
 
 
-@run_in_subprocess
 @pytest.mark.slow
 def test_facenet_nn4_small2_forward_and_center_loss_train():
     """FaceNetNN4Small2 (the last reference zoo architecture): NN4 inception
     modules, L2-normalised 128-d embedding, CenterLossOutputLayer head.
-    Training must decrease the loss AND move the class centers off zero.
-
-    Runs in a fresh interpreter: this is the suite's single biggest XLA
-    compile, and on a 1-core/small-RAM box it was the round-3 whole-suite
-    crash point when run at the end of a ~1000-test process."""
+    Training must decrease the loss AND move the class centers off zero."""
     m = zoo.FaceNetNN4Small2(num_classes=4, input_shape=(32, 32, 3),
                              width_mult=0.15, embedding_size=16)
     net = m.init_model()
