@@ -12,7 +12,8 @@ present, and the whole train step jits into one GSPMD program.
 Sharding map (Megatron-style):
 - embeddings  (V, C):      P(None, 'model'); the head gathers the cast copy
   whole and splits its tokens over 'model' too (``_head_operands``)
-- attn qkvo   (C, C):      qkv P(None, 'model') / out P('model', None)
+- attn qkvo   (C, C):      qkv P(None, 'model') / out P('model', None); the
+  fused (C, 3C) leaf is stored the same way and read by heads (``_qkv``)
 - mlp up/down (C, 4C)/(4C, C): up P(None, 'model') / down P('model', None)
 - activations (B, T, C):   P('data', 'seq', None)
 
@@ -202,7 +203,7 @@ class TransformerLM:
     def __init__(self, config: TransformerConfig, mesh: Optional[Mesh] = None):
         self.config = config
         self.mesh = mesh
-        self._attn_said = None      # the last trace's backend line
+        self._said = {}             # the last trace's log lines, by subject
 
     # ------------------------------------------------------------------ params
     def init_params(self, key) -> Dict:
@@ -262,6 +263,10 @@ class TransformerLM:
 
     def param_shardings(self, mesh: Mesh):
         """PartitionSpec pytree (Megatron column/row split over ``model``).
+        The fused ``wqkv`` is split like any column-parallel leaf, in
+        contiguous shares of its ``[q | k | v]`` columns: a share is not a
+        set of heads, and ``_qkv`` gathers the cast leaf to compute by heads
+        rather than store another column order than the unsharded model's.
         ``tok_emb`` is stored split on the embedding axis, not the vocabulary:
         50257 is odd and not padded, and jax refuses an uneven split. The head
         therefore gathers its cast whole and runs token-parallel over the
@@ -322,15 +327,35 @@ class TransformerLM:
             y = y * p["g"].astype(jnp.float32) + p["b"].astype(jnp.float32)
             return y.astype(x.dtype)
 
-    def _qkv(self, p, x):
+    def _qkv(self, p, x, mesh=None):
         """Project one (B, T, C) activation into (B, T, H, hd) q/k/v —
         shared by the training/scoring attention and the prefill path
-        (which must cache exactly the k/v the full forward would see)."""
+        (which must cache exactly the k/v the full forward would see).
+
+        On a ``model`` axis the fused leaf's stored share is no chip's own
+        heads: ``(C, 3C)``, columns ``[q | k | v]``, split in contiguous
+        shares (``param_shardings``), so of two chips one holds q and half of
+        k, the other the rest. ``x @ wqkv`` then leaves each chip columns the
+        attention wants on another, and (B, T, ·) activations cross the model
+        axis for layout alone, forward and backward. Where the axis divides
+        the heads the WEIGHT moves instead (``_wqkv_by_heads``: one gather of
+        the cast leaf a layer, its transpose for the gradient), and the
+        contraction gives each chip its own heads' q, k and v, laid out as
+        ``_flash_attention`` and ``ring_attention`` take them. The stored
+        layout stays ``[q | k | v]``: it is what the unsharded model, a
+        checkpoint and whoever hands this model its weights agree on. With
+        no ``mesh`` (decode, the pipeline body), no model axis, heads it does
+        not divide, or the unfused leaves (whole heads a share already) the
+        lines are what they were."""
         c = self.config
         b, t, _ = x.shape
         h, hd = c.n_heads, c.d_model // c.n_heads
         with jax.named_scope("attn_qkv"):
-            if "wqkv" in p:
+            if self._qkv_by_heads(p, mesh):
+                qkv = jnp.einsum("btc,cshd->btshd", x,
+                                 self._wqkv_by_heads(p["wqkv"], mesh))
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            elif "wqkv" in p:
                 qkv = x @ p["wqkv"]                   # one MXU op, one x read
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q = q.reshape(b, t, h, hd)
@@ -345,13 +370,14 @@ class TransformerLM:
     def _attn(self, p, x, mesh, return_kv: bool = False):
         c = self.config
         b, t, _ = x.shape
-        q, k, v = self._qkv(p, x)
+        q, k, v = self._qkv(p, x, mesh)
         if mesh is not None and SEQ_AXIS in mesh.axis_names:
             backend, why = "ring", "the mesh has a seq axis"
         else:
             backend = "flash" if _use_flash_attention(t) else "xla"
             why = _attention_policy(t)[1]
-        self._say_backend(backend, why)
+        # ``attention backend: flash | xla | ring: <reason>``
+        self._say_once("attention backend", backend, why)
         with jax.named_scope("attn_core"):
             if backend == "ring":
                 o = ring_attention(q, k, v, mesh, causal=c.causal)
@@ -365,22 +391,64 @@ class TransformerLM:
             return out, k, v
         return out
 
-    def _say_backend(self, backend, why):
-        """``attention backend: flash | xla | ring: <reason>``, once a trace
-        (every layer asks; the trace's identity tells a new one)."""
-        said = (jax.core.get_opaque_trace_state(), backend, why)
-        if said != self._attn_said:
-            self._attn_said = said
-            logging.getLogger(__name__).info(
-                "attention backend: %s: %s", backend, why)
+    def _qkv_by_heads(self, p, mesh) -> bool:
+        """Whether ``_qkv`` reads the fused leaf by heads, decided from what
+        is there to see (the leaf, the mesh's model axis, the head count) and
+        logged as ``qkv layout: heads | columns: <reason>``, once a trace."""
+        h = self.config.n_heads
+        tp = 1 if mesh is None else axis_size(mesh, MODEL_AXIS)
+        if "wqkv" not in p:
+            layout, why = "columns", "wq, wk, wv are split in whole heads"
+        elif tp == 1:
+            layout, why = "columns", ("no mesh" if mesh is None
+                                      else "no model axis")
+        elif h % tp:
+            layout, why = "columns", (f"{h} heads do not divide over the "
+                                      f"model axis ({tp})")
+        else:
+            layout, why = "heads", f"{h} heads over the model axis ({tp})"
+        self._say_once("qkv layout", layout, why)
+        return layout == "heads"
+
+    def _wqkv_by_heads(self, w, mesh):
+        """The stored ``(C, 3C)`` leaf, columns ``[q | k | v]`` in contiguous
+        shares over ``model``, as ``(C, 3, H, hd)`` with the heads over
+        ``model``: every chip gathers the leaf whole and keeps its own heads'
+        columns. Spelled as a ``shard_map`` so that the collective is this one
+        and in ``w``'s dtype (the bfloat16 cast, not the float32 master), and
+        so that its transpose is the reduce-scatter that hands the gradient
+        back in the stored layout."""
+        c = self.config
+        h, hd = c.n_heads, c.d_model // c.n_heads
+        own = h // axis_size(mesh, MODEL_AXIS)
+
+        def gather(w_share):
+            whole = lax.all_gather(w_share, MODEL_AXIS, axis=1, tiled=True)
+            return lax.dynamic_slice_in_dim(
+                whole.reshape(c.d_model, 3, h, hd),
+                lax.axis_index(MODEL_AXIS) * own, own, axis=2)
+
+        return shard_map(gather, mesh=mesh, in_specs=P(None, MODEL_AXIS),
+                         out_specs=P(None, None, MODEL_AXIS, None))(w)
+
+    def _say_once(self, subject, choice, why):
+        """``<subject>: <choice>: <reason>``, once a trace (every layer asks;
+        the trace's identity tells a new one)."""
+        said = (jax.core.get_opaque_trace_state(), choice, why)
+        if said != self._said.get(subject):
+            self._said[subject] = said
+            logging.getLogger(__name__).info("%s: %s: %s", subject, choice,
+                                             why)
 
     def _flash_attention(self, q, k, v, mesh):
         """The Pallas kernels over (B, T, H, hd) as ``_qkv`` makes them (no
         transpose: the kernels read the (B, T, H·hd) array by slabs of
         lanes). A kernel has no partitioning rule, so on a mesh the call is
         wrapped in ``shard_map`` with the batch over ``data`` and the heads
-        over ``model`` (the layout ``ring_attention`` uses); inside the
-        pipeline body (``mesh=None``) it is already per-shard."""
+        over ``model`` (the layout ``ring_attention`` uses). ``_qkv`` hands
+        q, k and v over in that layout already (it moved the projection's
+        weight to get there), so these specs move nothing; inside the
+        pipeline body (``mesh=None``) the call is already per-shard."""
         from deeplearning4j_tpu.kernels.flash_attention import (
             flash_attention_bthd)
         fn = functools.partial(flash_attention_bthd, causal=self.config.causal)

@@ -118,6 +118,17 @@ def _token_dims(rows, t):
     return {n for n in rs | ts | {r * x for r in rs for x in ts} if n > 1}
 
 
+def _backend_compiles():
+    """(the list every backend compile from now on is appended to, a call
+    that stops the appending: a listener cannot be taken back)."""
+    compiles, listening = [], [True]
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, seconds, **kw: compiles.append(event)
+        if listening[0] and event.endswith("backend_compile_duration")
+        else None)
+    return compiles, lambda: listening.__setitem__(0, False)
+
+
 @pytest.fixture(scope="module")
 def weights():
     """One set of float32 weights for every case (``ce_chunks`` does not
@@ -216,11 +227,7 @@ def test_make_sharded_lm_places_the_moments_and_compiles_the_step_once():
     assert {d for leaf in jax.tree.leaves(opt_state)
             for d in leaf.sharding.device_set} == set(mesh.devices.flat)
 
-    compiles, listening = [], [True]   # a listener cannot be taken back
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda event, seconds, **kw: compiles.append(event)
-        if listening[0] and event.endswith("backend_compile_duration")
-        else None)
+    compiles, stop_listening = _backend_compiles()
     watch = global_compile_watch()
     traced0 = watch.count_for(TRAIN_STEP_FN)
     step = model.make_train_step(opt)
@@ -231,7 +238,7 @@ def test_make_sharded_lm_places_the_moments_and_compiles_the_step_once():
         params, opt_state, loss = step(params, opt_state, toks, tgts)
         if after_first is None:
             after_first = len(compiles)
-    listening[0] = False
+    stop_listening()
     assert np.isfinite(float(loss))
     assert watch.count_for(TRAIN_STEP_FN) - traced0 == 1
     # a state left on one device would compile the step again for the
