@@ -1,69 +1,82 @@
 """HybridLM - a decoder built from a per-layer description.
 
 Each layer names its mixer (``kda``: gated delta-rule linear attention with a
-short convolution; ``mla``: latent attention without positions) and its
-feed-forward (``dense`` SwiGLU, or ``moe``: routed experts of which this chip
-holds a share, ``parallel/moe.py::routed_experts_ffn``). Pre-norm residual
-blocks with RMSNorm, no position table, untied head. Parameters are held in
+short convolution; ``mla``: latent attention without positions; ``mamba2``: a
+state-space layer with a scalar decay a head; ``gqa``: softmax attention with
+grouped key/value heads and no positions) and its feed-forward (``dense``
+SwiGLU, or ``moe``: routed experts of which this chip holds a share,
+``parallel/moe.py::routed_experts_ffn``). Either may be absent: a layer is then
+ONE pre-norm residual part, a mixer alone or a feed-forward alone, with one
+norm. RMSNorm, no position table, untied head. Parameters are held in
 ``param_dtype`` and computed with as they are: nothing is cast per call.
 
 Three spellings of the same mathematics:
 
-- :meth:`apply` - the full forward: chunked KDA (the UT / WY form, every
-  decay exponent a difference that is <= 0, so no channel's decay can
-  overflow), expanded MLA in blocks of queries.
+- :meth:`apply` - the full forward: chunked KDA (the UT / WY form) and the
+  chunked Mamba-2 scan (every decay exponent a difference that is <= 0, so
+  no channel's decay can overflow), expanded MLA and grouped-query attention
+  in blocks of queries.
 - :meth:`prefill_cache` - the same over a padded bucket, returning the logits
-  of the prompt's last token and what the cache needs: the latent rows of every
-  position and, for each KDA layer, the state and the convolution's 3-row tail
-  at the prompt's TRUE last token (rows beyond it are identity updates).
-- :meth:`decode_paged` - one token a slot: the recurrent KDA step on the
-  slot's state, absorbed MLA over the slot's latent pages.
+  of the prompt's last token and what the cache needs: the rows of every
+  position for a paged layer and, for a recurrent layer, the state and the
+  convolution's 3-row tail at the prompt's TRUE last token (rows beyond it
+  are identity updates).
+- :meth:`decode_paged` - one token a slot: the recurrent step on the slot's
+  state, attention over the slot's pages.
 
 The cache protocol ``DecodeEngine`` drives (``models/generation.py``) is the
 block of methods under "cache protocol" below; ``TransformerLM`` implements
-the same block. This model's cache is two kinds of state side by side: a
-paged pool of latent rows (one array an MLA layer, pages shared through the
-engine's allocator and tables) and a fixed per-slot state (one matrix state
-and one convolution tail a KDA layer), each layer's array a leaf of its own so
-that a step rewrites it in place.
+the same block. **Mixer kinds own their cache leaves**: ``MIXERS`` holds, for
+every kind, the leaves a layer of it keeps - paged rows (one pool a layer,
+pages shared through the engine's allocator and tables: ``latent``, ``kv``)
+or a fixed state a slot (``kda_s``, ``kda_conv``, ``ssm_s``, ``ssm_conv``),
+shape and dtype - and its full and one-step functions. The protocol's methods
+walk the layers over that table and name no leaf; each layer's array is a
+leaf of the cache of its own, so that a step rewrites it in place.
 
 Named scopes: the outer names are the fixed vocabulary of
 ``models/transformer.py`` (``embed``, ``ln``, ``attn_qkv``, ``attn_core``,
 ``attn_out``, ``mlp``, ``head``, ``kv_write``, ``kv_gather``); inside them
 ``kda_proj``, ``kda_conv``, ``kda_state``, ``kda_out``, ``mla_proj``,
-``mla_attend`` and, from the expert layer, ``moe_route``, ``moe_experts``,
-``moe_shared``, ``moe_combine``.
+``mla_attend``, ``ssm_proj``, ``ssm_conv``, ``ssm_state``, ``ssm_out``,
+``gqa_proj``, ``gqa_attend`` and, from the expert layer, ``moe_route``,
+``moe_experts``, ``moe_shared``, ``moe_combine``, ``moe_latent``. One log
+line a trace, ``layer kinds: ...``, names the layers and the experts' form.
 """
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.parallel.moe import (RoutedExpertsConfig,
-                                             routed_experts_ffn, swiglu)
+from deeplearning4j_tpu.parallel.moe import (EXPERT_FORMS,
+                                             RoutedExpertsConfig,
+                                             feed_forward,
+                                             routed_experts_ffn)
 
 _HI = lax.Precision.HIGHEST
 #: rows of the KDA chunk handled pairwise (exactly); blocks further apart go
 #: through a reference point between them
 _SUB = 16
-#: queries a block of the expanded latent attention (its scores are
+#: queries a block of the expanded attentions (their scores are
 #: heads x block x T float32)
 _QUERY_BLOCK = 512
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    mixer: str                      # "kda" | "mla"
-    ffn: str                        # "dense" | "moe"
+    mixer: Optional[str]            # a key of ``MIXERS``, or None
+    ffn: Optional[str]              # "dense" | "moe" | None
 
     def __post_init__(self):
-        if self.mixer not in ("kda", "mla") or self.ffn not in ("dense",
-                                                                "moe"):
+        if (self.mixer not in (None, *MIXERS)
+                or self.ffn not in (None, "dense", "moe")
+                or (self.mixer is None and self.ffn is None)):
             raise ValueError(f"unknown layer {self}")
 
 
@@ -87,8 +100,19 @@ class HybridConfig:
     qk_rope_dim: int = 64           # carried, never rotated (mla_use_nope)
     v_head_dim: int = 128
     kv_lora_rank: int = 512
+    ssm_heads: int = 128
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8             # heads i uses B, C of group i // (H / G)
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    gqa_heads: int = 32
+    gqa_kv_heads: int = 2           # query head i on kv head i // (Hq / Hkv)
+    gqa_head_dim: int = 128
     dense_ff: int = 9216
     expert_ff: int = 1024
+    shared_ff: Optional[int] = None       # None: expert_ff
+    expert_latent: Optional[int] = None   # None: experts in the width d
 
     def __post_init__(self):
         self.layers = tuple(self.layers)
@@ -96,6 +120,9 @@ class HybridConfig:
             raise ValueError("a layer with routed experts needs `experts`")
         if self.kda_chunk % _SUB:
             raise ValueError(f"kda_chunk must be a multiple of {_SUB}")
+        if self.ssm_heads % self.ssm_groups \
+                or self.gqa_heads % self.gqa_kv_heads:
+            raise ValueError("heads must divide into their groups")
 
     @property
     def n_layers(self) -> int:
@@ -114,6 +141,47 @@ class HybridConfig:
         its scatter and gather need (compile rehearsals, PR 27)."""
         return -(-self.latent_dim // 128) * 128
 
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the Mamba-2 convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def gqa_kv_row(self) -> int:
+        """A cached row of a grouped-query layer: [k heads | v heads]."""
+        return 2 * self.gqa_kv_heads * self.gqa_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLeaf:
+    """One array a layer of a mixer kind keeps in the decode cache:
+    ``paged`` rows of every position, ``(pages, page_tokens, *shape)``, the
+    pages handed out by the engine's allocator; or a fixed state a slot,
+    ``(slots, *shape)``."""
+
+    name: str
+    paged: bool
+    shape: Tuple[int, ...]
+    dtype: Any
+
+
+@dataclasses.dataclass(frozen=True)
+class MixerKind:
+    """What the model and the cache protocol need of a kind of mixer.
+    ``full(model, p, h, valid, last_idx) -> (y, *entries)`` over (B, T, d),
+    the entries in the order of ``leaves``; ``decode(model, p, h, held,
+    tables, positions, page_tokens) -> (y, *held)`` for one token a slot;
+    ``init(model, w, ones, resid) -> params``."""
+
+    leaves: Callable
+    full: Callable
+    decode: Callable
+    init: Callable
+
 
 def _rms(x, g, eps):
     x32 = x.astype(jnp.float32)
@@ -123,6 +191,33 @@ def _rms(x, g, eps):
 
 def _mm(x, w):
     return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+
+
+# ------------------------------------------- the short causal convolution
+def _conv_full(pre, taps):
+    """Depthwise causal convolution over whole sequences: pre (B, T, C),
+    taps (n, C), the last tap on the row itself -> float32 (B, T, C)."""
+    w = taps.astype(jnp.float32)
+    n, T = w.shape[0], pre.shape[1]
+    rows = jnp.pad(pre.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
+    return sum(w[i] * rows[:, i:i + T] for i in range(n))
+
+
+def _conv_tail(pre, n: int, last_idx, dtype):
+    """The n - 1 rows of ``pre`` the convolution needs before the row after
+    ``last_idx`` (zeros where the prompt is shorter): (B, n - 1, C)."""
+    at = last_idx + jnp.arange(2 - n, 1)
+    return jnp.where((at >= 0)[None, :, None],
+                     jnp.take(pre, jnp.maximum(at, 0), axis=1),
+                     0).astype(dtype)
+
+
+def _conv_step(tail, pre, taps):
+    """One row: tail (B, n - 1, C) and the new row pre (B, C) -> (the
+    convolved row float32 (B, C), the n rows; the next tail is rows[:, 1:])."""
+    rows = jnp.concatenate([tail, pre[:, None]], axis=1)
+    return jnp.sum(taps.astype(jnp.float32) * rows.astype(jnp.float32),
+                   axis=1), rows
 
 
 # ------------------------------------------------------------------- KDA
@@ -212,6 +307,77 @@ def kda_step(s, q, k, v, log_a, beta):
     return s, jnp.sum(q[..., None] * s, axis=-2)
 
 
+# --------------------------------------------------------------- Mamba-2
+def ssd_chunked(x, dt, log_a, b, c, s0, chunk: int):
+    """The state-space recurrence with a scalar decay a head over whole
+    sequences, chunk by chunk. x (B, T, H, P); dt, log_a (B, T, H) with
+    ``log_a = -exp(A) dt <= 0``; b, c (B, T, G, N), head ``i`` on group
+    ``i // (H / G)``; s0 (B, H, P, N); all float32, T a multiple of
+    ``chunk``. ``S_t = e^{log_a_t} S_{t-1} + dt_t x_t b_t^T``, ``y_t = S_t
+    c_t``. Returns (y (B, T, H, P), the state after the last row).
+
+    Within a chunk, with ``g`` the running sum of ``log_a``:
+    ``Y = e^g (S0 C^T)^T + (L * (C B^T)) (dt X)`` where ``L[i, j] =
+    e^{g_i - g_j}`` for ``j <= i``, else 0; ``S_end = e^{g_C} S0 +
+    (dt X e^{g_C - g})^T B``. Every exponent is a difference <= 0."""
+    B, T, H, P = x.shape
+    G, N = b.shape[-2:]
+    K, n = H // G, T // chunk
+
+    def chunks(a, *tail):       # (B, T, ...) -> (n, B, chunk, ...)
+        return a.reshape(B, n, chunk, *tail).swapaxes(0, 1)
+
+    xc = chunks(x * dt[..., None], G, K, P).transpose(0, 1, 3, 4, 2, 5)
+    g = jnp.cumsum(chunks(log_a, G, K).transpose(0, 1, 3, 4, 2), axis=-1)
+    bc = chunks(b, G, N).swapaxes(2, 3)                     # (n,B,G,C,N)
+    cc = chunks(c, G, N).swapaxes(2, 3)
+    cb = jnp.einsum("nbgis,nbgjs->nbgij", cc, bc, precision=_HI)
+    ii, jj = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    decay = jnp.exp(jnp.where(jj <= ii, g[..., :, None] - g[..., None, :],
+                              -jnp.inf))                    # (n,B,G,K,C,C)
+    y = jnp.einsum("nbgkij,nbgkjp->nbgkip", cb[:, :, :, None] * decay, xc,
+                   precision=_HI)
+    g_end = g[..., -1:]                                     # (n,B,G,K,1)
+    ds = jnp.einsum("nbgkcp,nbgcs->nbgkps",
+                    xc * jnp.exp(g_end - g)[..., None], bc, precision=_HI)
+
+    def step(s, xs):            # s (B, G, K, P, N)
+        ds_n, ge_n, c_n, eg_n = xs
+        y_n = jnp.einsum("bgkps,bgcs->bgkcp", s, c_n,
+                         precision=_HI) * eg_n[..., None]
+        return jnp.exp(ge_n)[..., None] * s + ds_n, y_n
+
+    s_end, y0 = lax.scan(step, s0.reshape(B, G, K, P, N),
+                         (ds, g_end, cc, jnp.exp(g)))
+    y = (y + y0).transpose(1, 0, 4, 2, 3, 5).reshape(B, T, H, P)
+    return y, s_end.reshape(B, H, P, N)
+
+
+def ssd_step(s, x, dt, log_a, b, c):
+    """One row of the recurrence for every slot: s (B, H, P, N) float32;
+    x (B, H, P); dt, log_a (B, H); b, c (B, G, N).
+    ``S <- e^{log_a} S + dt x b^T``, ``y = S c``."""
+    B, H, P, N = s.shape
+    G = b.shape[1]
+    s = s.reshape(B, G, H // G, P, N)
+    s = jnp.exp(log_a).reshape(B, G, -1, 1, 1) * s \
+        + (dt[..., None] * x).reshape(B, G, -1, P, 1) \
+        * b[:, :, None, None, :]
+    y = jnp.sum(s * c[:, :, None, None, :], axis=-1)
+    return s.reshape(B, H, P, N), y.reshape(B, H, P)
+
+
+def _slot_page(tables, positions, page_tokens: int, n_pages: int):
+    """The page each slot's row at ``positions`` goes to. A position past
+    the last logical page (a retired slot) goes to the trash page, the
+    pool's last, which no table row owns."""
+    return jnp.where(
+        positions < tables.shape[1] * page_tokens,
+        tables[jnp.arange(tables.shape[0]),
+               jnp.minimum(positions // page_tokens, tables.shape[1] - 1)],
+        n_pages - 1)
+
+
 class HybridLM:
     """See the module doc."""
 
@@ -228,12 +394,21 @@ class HybridLM:
         self.config = config
         self.mesh = None
         c = config
-        self.kda_layers = [i for i, s in enumerate(c.layers)
-                           if s.mixer == "kda"]
-        self.mla_layers = [i for i, s in enumerate(c.layers)
-                           if s.mixer == "mla"]
         self.moe_layers = [i for i, s in enumerate(c.layers)
                            if s.ffn == "moe"]
+        #: per layer, its rank among the layers of its mixer kind: where its
+        #: arrays stand in the lists of its kind's leaves
+        seen: Dict[str, int] = {}
+        self._rank = []
+        for s in c.layers:
+            self._rank.append(seen.get(s.mixer, 0))
+            seen[s.mixer] = self._rank[-1] + 1
+        #: (leaf, layers that own it) over the kinds present, the paged
+        #: leaves first
+        leaves = [(leaf, n) for kind, n in seen.items() if kind is not None
+                  for leaf in MIXERS[kind].leaves(c)]
+        self.cache_leaves = sorted(leaves, key=lambda ln: not ln[0].paged)
+        self._said: Dict[str, Any] = {}
 
     # ------------------------------------------------------------ params
     def init_params(self, key) -> Dict:
@@ -242,8 +417,7 @@ class HybridLM:
         c = self.config
         keys = iter(jax.random.split(key, 8 + 24 * c.n_layers))
         resid = 0.02 / math.sqrt(2 * c.n_layers)
-        H, K = c.kda_heads, c.kda_head_dim
-        d, r = c.d_model, c.kda_gate_rank
+        d = c.d_model
 
         def w(shape, std=0.02):
             return (std * jax.random.normal(next(keys), shape, jnp.float32)
@@ -252,45 +426,78 @@ class HybridLM:
         def ones(n):
             return jnp.ones((n,), c.param_dtype)
 
-        def ffn(width, lead=()):
-            return {"w_gu": w(lead + (d, 2 * width)),
-                    "w_down": w(lead + (width, d), resid)}
+        def ffn(width, lead=(), d_in=d, form="swiglu"):
+            return {EXPERT_FORMS[form]: w(lead + (
+                d_in, (2 if form == "swiglu" else 1) * width)),
+                "w_down": w(lead + (width, d_in), resid)}
 
         blocks = []
         for spec in c.layers:
-            if spec.mixer == "kda":
-                mixer = {
-                    "w_qkv": w((d, 3 * H * K)), "conv": w((c.kda_conv,
-                                                           3 * H * K), 0.3),
-                    "w_f1": w((d, r)), "w_f2": w((r, H * K)),
-                    "b_dt": jnp.full((H * K,), -2.0, jnp.float32),
-                    "a_log": jnp.zeros((H,), jnp.float32),
-                    "w_beta": w((d, H)), "w_g1": w((d, r)),
-                    "w_g2": w((r, H * K)), "b_g2": jnp.zeros((H * K,),
-                                                             c.param_dtype),
-                    "o_norm": ones(K), "w_o": w((H * K, d), resid)}
-            else:
-                hm = c.mla_heads
-                mixer = {
-                    "w_q": w((d, hm * (c.qk_nope_dim + c.qk_rope_dim))),
-                    "w_kva": w((d, c.latent_dim)),
-                    "kv_norm": ones(c.kv_lora_rank),
-                    "w_kvb": w((c.kv_lora_rank,
-                                hm * (c.qk_nope_dim + c.v_head_dim))),
-                    "w_o": w((hm * c.v_head_dim, d), resid)}
+            blk = {}
+            if spec.mixer is not None:
+                blk["ln1"] = ones(d)
+                blk["mixer"] = MIXERS[spec.mixer].init(self, w, ones, resid)
             if spec.ffn == "dense":
-                feed = ffn(c.dense_ff)
-            else:
+                blk["ffn"] = ffn(c.dense_ff)
+            elif spec.ffn == "moe":
                 e = c.experts
-                feed = {"w_router": w((d, e.router_width)),
-                        "b_select": jnp.zeros((e.router_width,),
-                                              jnp.float32),
-                        **ffn(c.expert_ff, (e.held[1],)),
-                        "shared": ffn(c.expert_ff)}
-            blocks.append({"ln1": ones(d), "ln2": ones(d), "mixer": mixer,
-                           "ffn": feed})
+                wide = c.expert_latent or d
+                blk["ffn"] = {
+                    "w_router": w((d, e.router_width)),
+                    "b_select": jnp.zeros((e.router_width,), jnp.float32),
+                    **ffn(c.expert_ff, (e.held[1],), wide, e.form),
+                    "shared": ffn(c.shared_ff or c.expert_ff, form=e.form)}
+                if c.expert_latent:
+                    blk["ffn"].update(w_latent_in=w((d, wide)),
+                                      w_latent_out=w((wide, d), resid))
+            if spec.ffn is not None:
+                blk["ln2"] = ones(d)
+            blocks.append(blk)
         return {"tok_emb": w((c.vocab_size, d)), "head": w((d, c.vocab_size)),
                 "ln_f": ones(d), "blocks": blocks}
+
+    def _init_kda(self, w, ones, resid):
+        c = self.config
+        H, K, d, r = c.kda_heads, c.kda_head_dim, c.d_model, c.kda_gate_rank
+        return {
+            "w_qkv": w((d, 3 * H * K)), "conv": w((c.kda_conv,
+                                                   3 * H * K), 0.3),
+            "w_f1": w((d, r)), "w_f2": w((r, H * K)),
+            "b_dt": jnp.full((H * K,), -2.0, jnp.float32),
+            "a_log": jnp.zeros((H,), jnp.float32),
+            "w_beta": w((d, H)), "w_g1": w((d, r)),
+            "w_g2": w((r, H * K)), "b_g2": jnp.zeros((H * K,),
+                                                     c.param_dtype),
+            "o_norm": ones(K), "w_o": w((H * K, d), resid)}
+
+    def _init_mla(self, w, ones, resid):
+        c = self.config
+        hm, d = c.mla_heads, c.d_model
+        return {
+            "w_q": w((d, hm * (c.qk_nope_dim + c.qk_rope_dim))),
+            "w_kva": w((d, c.latent_dim)),
+            "kv_norm": ones(c.kv_lora_rank),
+            "w_kvb": w((c.kv_lora_rank,
+                        hm * (c.qk_nope_dim + c.v_head_dim))),
+            "w_o": w((hm * c.v_head_dim, d), resid)}
+
+    def _init_mamba2(self, w, ones, resid):
+        c = self.config
+        H, d, di = c.ssm_heads, c.d_model, c.ssm_inner
+        return {
+            "w_in": w((d, di + c.ssm_conv_dim + H)),     # [z | xBC | dt]
+            "conv": w((c.ssm_conv, c.ssm_conv_dim), 0.3),
+            "b_conv": jnp.zeros((c.ssm_conv_dim,), c.param_dtype),
+            "a_log": jnp.zeros((H,), jnp.float32),
+            "d_skip": jnp.ones((H,), jnp.float32),
+            "dt_bias": jnp.full((H,), -2.0, jnp.float32),
+            "norm": ones(di), "w_out": w((di, d), resid)}
+
+    def _init_gqa(self, w, ones, resid):
+        c = self.config
+        d, hq = c.d_model, c.gqa_heads * c.gqa_head_dim
+        return {"w_q": w((d, hq)), "w_kv": w((d, c.gqa_kv_row)),  # [k | v]
+                "w_o": w((hq, d), resid)}
 
     # ------------------------------------------------------------ pieces
     # The residual stream and the norms' outputs are float32 (a few MB);
@@ -316,8 +523,7 @@ class HybridLM:
         with jax.named_scope("mlp"):
             p = blk["ffn"]
             if spec.ffn == "dense":
-                return swiglu(h, p["w_gu"], p["w_down"]).astype(h.dtype), \
-                    None
+                return feed_forward(h, p).astype(h.dtype), None
             flat = h.reshape(-1, h.shape[-1])
             mask = None if token_mask is None else token_mask.reshape(-1)
             y, stats = routed_experts_ffn(
@@ -370,16 +576,8 @@ class HybridLM:
         with jax.named_scope("attn_qkv"):
             pre, log_a, beta, gate = self._kda_project(p, h)
             with jax.named_scope("kda_conv"):
-                w = p["conv"].astype(jnp.float32)
-                n = c.kda_conv
-                rows = jnp.pad(pre.astype(jnp.float32),
-                               ((0, 0), (n - 1, 0), (0, 0)))
-                conved = sum(w[i] * rows[:, i:i + T] for i in range(n))
-                q, k, v = self._kda_qkv(conved)
-                at = last_idx + jnp.arange(2 - n, 1)
-                tail = jnp.where((at >= 0)[None, :, None],
-                                 jnp.take(pre, jnp.maximum(at, 0), axis=1),
-                                 0).astype(c.dtype)
+                q, k, v = self._kda_qkv(_conv_full(pre, p["conv"]))
+                tail = _conv_tail(pre, c.kda_conv, last_idx, c.dtype)
         with jax.named_scope("attn_core"), jax.named_scope("kda_state"):
             log_a = jnp.where(valid[None, :, None, None], log_a, 0.0)
             beta = jnp.where(valid[None, :, None], beta, 0.0)
@@ -401,9 +599,7 @@ class HybridLM:
         with jax.named_scope("attn_qkv"):
             pre, log_a, beta, gate = self._kda_project(p, h)
             with jax.named_scope("kda_conv"):
-                rows = jnp.concatenate([tail, pre[:, None]], axis=1)
-                conved = jnp.sum(p["conv"].astype(jnp.float32)
-                                 * rows.astype(jnp.float32), axis=1)
+                conved, rows = _conv_step(tail, pre, p["conv"])
                 q, k, v = self._kda_qkv(conved)
                 tail = rows[:, 1:]
         with jax.named_scope("attn_core"), jax.named_scope("kda_state"):
@@ -495,13 +691,7 @@ class HybridLM:
                                  preferred_element_type=jnp.float32
                                  ).astype(c.dtype)
         with jax.named_scope("kv_write"):
-            # a position past the last logical page (a retired slot) goes to
-            # the trash page, the pool's last, which no table row owns
-            page = jnp.where(
-                positions < S,
-                tables[jnp.arange(B), jnp.minimum(positions // P,
-                                                  tables.shape[1] - 1)],
-                pool.shape[0] - 1)
+            page = _slot_page(tables, positions, P, pool.shape[0])
             pool = pool.at[page, positions % P].set(row)
         with jax.named_scope("kv_gather"):
             view = pool.at[tables].get(mode="promise_in_bounds").reshape(
@@ -523,28 +713,206 @@ class HybridLM:
                            preferred_element_type=jnp.float32).astype(c.dtype)
             return _mm(o.reshape(B, -1), p["w_o"]).astype(c.dtype), pool
 
+    # ---------------------------------------------------------- Mamba-2
+    def _ssm_project(self, p, h):
+        """h (..., d) -> z (..., d_inner) float32, the rows the convolution
+        takes (..., conv_dim), dt and log a (..., H) float32."""
+        c = self.config
+        di = c.ssm_inner
+        with jax.named_scope("ssm_proj"):
+            zxd = _mm(h, p["w_in"])
+            pre = zxd[..., di:di + c.ssm_conv_dim].astype(c.dtype)
+            dt = jax.nn.softplus(zxd[..., di + c.ssm_conv_dim:]
+                                 + p["dt_bias"])
+            log_a = -jnp.exp(p["a_log"]) * dt
+        return zxd[..., :di], pre, dt, log_a
+
+    def _ssm_xbc(self, conved):
+        """SiLU of the convolved rows (float32, bias added), split into
+        x (..., H, P), B and C (..., G, N)."""
+        c = self.config
+        act = jax.nn.silu(conved)
+        di, gn = c.ssm_inner, c.ssm_groups * c.ssm_state
+        lead = act.shape[:-1]
+        return (act[..., :di].reshape(*lead, c.ssm_heads, c.ssm_head_dim),
+                act[..., di:di + gn].reshape(*lead, c.ssm_groups,
+                                             c.ssm_state),
+                act[..., di + gn:].reshape(*lead, c.ssm_groups, c.ssm_state))
+
+    def _ssm_out(self, p, y, x, z):
+        """y, x (..., H, P), z (..., d_inner): the skip, the gate, the norm
+        in groups of d_inner / G, the output projection."""
+        c = self.config
+        with jax.named_scope("ssm_out"):
+            y = y + p["d_skip"][:, None] * x
+            y = y.reshape(*z.shape) * jax.nn.silu(z)
+            yg = y.reshape(*z.shape[:-1], c.ssm_groups, -1)
+            yg = yg * lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
+                                + c.rms_eps)
+            y = (yg.reshape(*z.shape) * p["norm"].astype(jnp.float32)
+                 ).astype(c.dtype)
+            return _mm(y, p["w_out"]).astype(c.dtype)
+
+    def _ssm_full(self, p, h, valid, last_idx):
+        """h (B, T, d); rows where ``valid`` is False are identity updates
+        (dt = 0: a = 1 and nothing is added). Returns (y, state at the last
+        valid row, the 3 rows the convolution would need before the next)."""
+        c = self.config
+        B, T, _ = h.shape
+        with jax.named_scope("attn_qkv"):
+            z, pre, dt, log_a = self._ssm_project(p, h)
+            with jax.named_scope("ssm_conv"):
+                x, b, cm = self._ssm_xbc(_conv_full(pre, p["conv"])
+                                         + p["b_conv"].astype(jnp.float32))
+                tail = _conv_tail(pre, c.ssm_conv, last_idx, c.dtype)
+        with jax.named_scope("attn_core"), jax.named_scope("ssm_state"):
+            dt = jnp.where(valid[None, :, None], dt, 0.0)
+            log_a = jnp.where(valid[None, :, None], log_a, 0.0)
+            pad = -T % c.ssm_chunk
+            xs = [x, dt, log_a, b, cm]
+            if pad:
+                xs = [jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                      for a in xs]
+            s0 = jnp.zeros((B, c.ssm_heads, c.ssm_head_dim, c.ssm_state),
+                           jnp.float32)
+            y, s = ssd_chunked(*xs, s0, c.ssm_chunk)
+            y = y[:, :T]
+        with jax.named_scope("attn_out"):
+            return self._ssm_out(p, y, x, z), s, tail
+
+    def _ssm_decode(self, p, h, s, tail):
+        """h (B, d), s (B, H, P, N), tail (B, 3, conv_dim)."""
+        with jax.named_scope("attn_qkv"):
+            z, pre, dt, log_a = self._ssm_project(p, h)
+            with jax.named_scope("ssm_conv"):
+                conved, rows = _conv_step(tail, pre, p["conv"])
+                x, b, cm = self._ssm_xbc(conved
+                                         + p["b_conv"].astype(jnp.float32))
+                tail = rows[:, 1:]
+        with jax.named_scope("attn_core"), jax.named_scope("ssm_state"):
+            s, y = ssd_step(s, x, dt, log_a, b, cm)
+        with jax.named_scope("attn_out"):
+            return self._ssm_out(p, y, x, z), s, tail
+
+    # ------------------------------------------- grouped-query attention
+    def _gqa_project(self, p, h):
+        """h (..., d) -> q (..., Hkv, Hq / Hkv, hd) and the row the cache
+        keeps, [k heads | v heads] (..., 2 Hkv hd). No positions."""
+        c = self.config
+        g = c.gqa_kv_heads
+        with jax.named_scope("gqa_proj"):
+            q = _mm(h, p["w_q"]).astype(c.dtype).reshape(
+                *h.shape[:-1], g, c.gqa_heads // g, c.gqa_head_dim)
+            row = _mm(h, p["w_kv"]).astype(c.dtype)
+        return q, row
+
+    def _gqa_kv(self, rows):
+        """(..., 2 Hkv hd) -> k, v (..., Hkv, hd)."""
+        c = self.config
+        half = c.gqa_kv_row // 2
+        shape = (*rows.shape[:-1], c.gqa_kv_heads, c.gqa_head_dim)
+        return rows[..., :half].reshape(shape), rows[..., half:].reshape(shape)
+
+    def _gqa_full(self, p, h):
+        """Causal softmax attention over (B, T, d) in blocks of queries.
+        Returns (y, the rows of K and V (B, T, 2 Hkv hd))."""
+        c = self.config
+        B, T, _ = h.shape
+        scale = c.gqa_head_dim ** -0.5
+        with jax.named_scope("attn_qkv"):
+            q, row = self._gqa_project(p, h)
+            k, v = self._gqa_kv(row)
+        with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+            bq = min(_QUERY_BLOCK, T)
+            pad = -T % bq
+            nb = (T + pad) // bq
+            qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
+            qb = qb.reshape(B, nb, bq, *q.shape[2:]).swapaxes(0, 1)
+
+            def one(args):
+                q_b, i0 = args
+                s = jnp.einsum("bqgkd,btgd->bgkqt", q_b, k,
+                               preferred_element_type=jnp.float32) * scale
+                ok = (i0 + jnp.arange(bq))[:, None] >= jnp.arange(T)[None, :]
+                pr = jax.nn.softmax(jnp.where(ok, s, -1e30),
+                                    axis=-1).astype(c.dtype)
+                return jnp.einsum("bgkqt,btgd->bqgkd", pr, v,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(c.dtype)
+
+            o = lax.map(one, (qb, jnp.arange(nb) * bq))
+            o = o.swapaxes(0, 1).reshape(B, T + pad, -1)[:, :T]
+        with jax.named_scope("attn_out"), jax.named_scope("gqa_proj"):
+            return _mm(o, p["w_o"]).astype(c.dtype), row
+
+    def _gqa_decode(self, p, h, pool, tables, positions, page_tokens):
+        """h (B, d) against the slot's pages of K and V rows. The step's own
+        row is written first, then read back with the rest."""
+        c = self.config
+        B = h.shape[0]
+        P = int(page_tokens)
+        S = tables.shape[1] * P
+        with jax.named_scope("attn_qkv"):
+            q, row = self._gqa_project(p, h)
+        with jax.named_scope("kv_write"):
+            page = _slot_page(tables, positions, P, pool.shape[0])
+            pool = pool.at[page, positions % P].set(row)
+        with jax.named_scope("kv_gather"):
+            k, v = self._gqa_kv(pool.at[tables].get(
+                mode="promise_in_bounds").reshape(B, S, c.gqa_kv_row))
+        with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+            s = jnp.einsum("bgkd,bsgd->bgks", q, k,
+                           preferred_element_type=jnp.float32) \
+                * c.gqa_head_dim ** -0.5
+            live = jnp.arange(S)[None, :] <= positions[:, None]
+            pr = jax.nn.softmax(jnp.where(live[:, None, None, :], s, -1e30),
+                                axis=-1).astype(c.dtype)
+            o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
+                           preferred_element_type=jnp.float32).astype(c.dtype)
+        with jax.named_scope("attn_out"), jax.named_scope("gqa_proj"):
+            return _mm(o.reshape(B, -1), p["w_o"]).astype(c.dtype), pool
+
     # ------------------------------------------------------ full forward
+    def _say_layers(self):
+        """``layer kinds: <one word a layer>: <the experts' form>``, once a
+        trace (as ``TransformerLM`` says its layouts)."""
+        c = self.config
+        said = jax.core.get_opaque_trace_state()
+        if said == self._said.get("layer kinds"):
+            return
+        self._said["layer kinds"] = said
+        kinds = " ".join("+".join(k for k in (s.mixer, s.ffn) if k)
+                         for s in c.layers)
+        e = c.experts
+        why = "no routed experts" if not self.moe_layers else (
+            f"experts {e.form}"
+            + (f" in a {c.expert_latent}-wide latent space"
+               if c.expert_latent else "")
+            + f", {e.top_k} of {e.router_width} a token, {e.held[1]} held "
+            f"from {e.held[0]}")
+        logging.getLogger(__name__).info("layer kinds: %s: %s", kinds, why)
+
     def _trunk(self, params, tokens, last_idx):
         """tokens (B, T) -> (x (B, T, d) before the final norm, cache
         entries). Rows after ``last_idx`` are padding."""
         c = self.config
+        self._say_layers()
         T = tokens.shape[1]
         valid = jnp.arange(T) <= last_idx
         x = self._embed(params, tokens)
-        entries = {"latent": [], "kda_s": [], "kda_conv": []}
+        entries = {leaf.name: [] for leaf, _n in self.cache_leaves}
         for blk, spec in zip(params["blocks"], c.layers):
-            h = self._ln(blk["ln1"], x).astype(c.dtype)
-            if spec.mixer == "kda":
-                y, s, tail = self._kda_full(blk["mixer"], h, valid, last_idx)
-                entries["kda_s"].append(s)
-                entries["kda_conv"].append(tail)
-            else:
-                y, row = self._mla_full(blk["mixer"], h)
-                entries["latent"].append(row)
-            x = x + y
-            y, _ = self._ffn(blk, spec, self._ln(blk["ln2"], x),
-                             jnp.broadcast_to(valid, tokens.shape))
-            x = x + y
+            if spec.mixer is not None:
+                kind = MIXERS[spec.mixer]
+                h = self._ln(blk["ln1"], x).astype(c.dtype)
+                y, *new = kind.full(self, blk["mixer"], h, valid, last_idx)
+                for leaf, entry in zip(kind.leaves(c), new):
+                    entries[leaf.name].append(entry)
+                x = x + y
+            if spec.ffn is not None:
+                y, _ = self._ffn(blk, spec, self._ln(blk["ln2"], x),
+                                 jnp.broadcast_to(valid, tokens.shape))
+                x = x + y
         return x, entries
 
     def apply(self, params, tokens):
@@ -560,10 +928,13 @@ class HybridLM:
         last = lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
         return self._head(params, last), entries
 
-    @staticmethod
-    def entries_tokens(entries) -> int:
-        return entries["latent"][0].shape[1] if entries["latent"] \
-            else 1
+    def entries_tokens(self, entries) -> int:
+        """Positions a prefill's entries cover: the bucket's length where a
+        layer keeps pages, else 1 (a state alone needs no page)."""
+        for leaf, _n in self.cache_leaves:
+            if leaf.paged:
+                return entries[leaf.name][0].shape[1]
+        return 1
 
     @staticmethod
     def entries_row(entries, b: int):
@@ -571,43 +942,40 @@ class HybridLM:
 
     def new_paged_cache(self, slots: int, n_pages: int, page_tokens: int,
                         quant: bool = False) -> Dict:
-        c = self.config
-        H, K = c.kda_heads, c.kda_head_dim
-        return {
-            "latent": [jnp.zeros((n_pages, page_tokens, c.latent_row),
-                                 c.dtype) for _ in self.mla_layers],
-            "kda_s": [jnp.zeros((slots, H, K, K), jnp.float32)
-                      for _ in self.kda_layers],
-            "kda_conv": [jnp.zeros((slots, c.kda_conv - 1, 3 * H * K),
-                                   c.dtype) for _ in self.kda_layers]}
+        """{leaf name: one array a layer that owns it}."""
+        return {leaf.name: [
+            jnp.zeros(((n_pages, page_tokens) if leaf.paged else (slots,))
+                      + leaf.shape, leaf.dtype) for _ in range(n)]
+            for leaf, n in self.cache_leaves}
+
+    def _leaf_bytes(self, paged: bool) -> int:
+        return sum(n * math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
+                   for leaf, n in self.cache_leaves if leaf.paged == paged)
 
     def page_bytes(self, page_tokens: int, quant: bool = False) -> int:
-        c = self.config
-        return (len(self.mla_layers) * page_tokens * c.latent_row
-                * jnp.dtype(c.dtype).itemsize)
+        return page_tokens * self._leaf_bytes(True)
 
     def slot_state_bytes(self) -> int:
-        c = self.config
-        H, K = c.kda_heads, c.kda_head_dim
-        return len(self.kda_layers) * (
-            H * K * K * 4
-            + (c.kda_conv - 1) * 3 * H * K * jnp.dtype(c.dtype).itemsize)
+        return self._leaf_bytes(False)
 
     def insert_paged(self, arrays, entries, page_ids, slot, page_tokens):
-        """One prefilled prompt (batch 1) into ``slot``: its latent rows
-        into the slot's pages, its states over whatever the slot held."""
-        out = {"latent": [], "kda_s": [], "kda_conv": []}
+        """One prefilled prompt (batch 1) into ``slot``: its rows into the
+        slot's pages, its states over whatever the slot held."""
+        out = {}
         with jax.named_scope("kv_write"):
-            for pool, rows in zip(arrays["latent"], entries["latent"]):
-                tb = rows.shape[1]
-                npb = -(-tb // page_tokens)
-                rows = jnp.pad(rows[0], ((0, npb * page_tokens - tb), (0, 0)))
-                out["latent"].append(pool.at[page_ids].set(
-                    rows.reshape(npb, page_tokens, -1)))
-            for name in ("kda_s", "kda_conv"):
-                for held, new in zip(arrays[name], entries[name]):
-                    out[name].append(lax.dynamic_update_slice_in_dim(
-                        held, new.astype(held.dtype), slot, axis=0))
+            for leaf, _n in self.cache_leaves:
+                out[leaf.name] = []
+                for held, new in zip(arrays[leaf.name], entries[leaf.name]):
+                    if leaf.paged:
+                        tb = new.shape[1]
+                        npb = -(-tb // page_tokens)
+                        rows = jnp.pad(new[0], ((0, npb * page_tokens - tb),
+                                                (0, 0)))
+                        out[leaf.name].append(held.at[page_ids].set(
+                            rows.reshape(npb, page_tokens, -1)))
+                    else:
+                        out[leaf.name].append(lax.dynamic_update_slice_in_dim(
+                            held, new.astype(held.dtype), slot, axis=0))
         return out
 
     def decode_paged(self, params, arrays, tables, tokens, positions,
@@ -616,30 +984,73 @@ class HybridLM:
         arrays, stats int32[3] summed over the expert layers). A slot whose
         table points at the trash page is free: it routes to no expert."""
         c = self.config
-        occupied = tables[:, 0] != (arrays["latent"][0].shape[0] - 1) \
-            if arrays["latent"] else None
+        self._say_layers()
+        pools = [arrays[leaf.name][0] for leaf, _n in self.cache_leaves
+                 if leaf.paged]
+        occupied = tables[:, 0] != (pools[0].shape[0] - 1) if pools else None
         x = self._embed(params, tokens)
-        out = {"latent": [], "kda_s": [], "kda_conv": []}
+        out = {leaf.name: [] for leaf, _n in self.cache_leaves}
         stats = jnp.zeros((len(self.step_stats),), jnp.int32)
-        i_kda = i_mla = 0
-        for blk, spec in zip(params["blocks"], c.layers):
-            h = self._ln(blk["ln1"], x).astype(c.dtype)
-            if spec.mixer == "kda":
-                y, s, tail = self._kda_decode(
-                    blk["mixer"], h, arrays["kda_s"][i_kda],
-                    arrays["kda_conv"][i_kda])
-                out["kda_s"].append(s)
-                out["kda_conv"].append(tail)
-                i_kda += 1
-            else:
-                y, pool = self._mla_decode(
-                    blk["mixer"], h, arrays["latent"][i_mla], tables,
-                    positions, page_tokens)
-                out["latent"].append(pool)
-                i_mla += 1
-            x = x + y
-            y, st = self._ffn(blk, spec, self._ln(blk["ln2"], x), occupied)
-            if st is not None:
-                stats = stats + st
-            x = x + y
+        for blk, spec, rank in zip(params["blocks"], c.layers, self._rank):
+            if spec.mixer is not None:
+                kind = MIXERS[spec.mixer]
+                names = [leaf.name for leaf in kind.leaves(c)]
+                h = self._ln(blk["ln1"], x).astype(c.dtype)
+                y, *held = kind.decode(
+                    self, blk["mixer"], h, [arrays[n][rank] for n in names],
+                    tables, positions, page_tokens)
+                for n, a in zip(names, held):
+                    out[n].append(a)
+                x = x + y
+            if spec.ffn is not None:
+                y, st = self._ffn(blk, spec, self._ln(blk["ln2"], x),
+                                  occupied)
+                if st is not None:
+                    stats = stats + st
+                x = x + y
         return self._head(params, x), out, stats
+
+
+def _kda_leaves(c: HybridConfig):
+    H, K = c.kda_heads, c.kda_head_dim
+    return (CacheLeaf("kda_s", False, (H, K, K), jnp.float32),
+            CacheLeaf("kda_conv", False, (c.kda_conv - 1, 3 * H * K),
+                      c.dtype))
+
+
+def _ssm_leaves(c: HybridConfig):
+    return (CacheLeaf("ssm_s", False,
+                      (c.ssm_heads, c.ssm_head_dim, c.ssm_state),
+                      jnp.float32),
+            CacheLeaf("ssm_conv", False, (c.ssm_conv - 1, c.ssm_conv_dim),
+                      c.dtype))
+
+
+#: every kind of mixer: the cache leaves a layer of it owns and its three
+#: functions. A recurrent kind's ``full`` takes the padding's mask and the
+#: prompt's last index; an attention's needs neither (padding comes after
+#: every real row and is never read back)
+MIXERS: Dict[str, MixerKind] = {
+    "kda": MixerKind(
+        _kda_leaves,
+        lambda m, p, h, valid, last: m._kda_full(p, h, valid, last),
+        lambda m, p, h, held, tables, pos, pt: m._kda_decode(p, h, *held),
+        HybridLM._init_kda),
+    "mla": MixerKind(
+        lambda c: (CacheLeaf("latent", True, (c.latent_row,), c.dtype),),
+        lambda m, p, h, valid, last: m._mla_full(p, h),
+        lambda m, p, h, held, tables, pos, pt: m._mla_decode(
+            p, h, *held, tables, pos, pt),
+        HybridLM._init_mla),
+    "mamba2": MixerKind(
+        _ssm_leaves,
+        lambda m, p, h, valid, last: m._ssm_full(p, h, valid, last),
+        lambda m, p, h, held, tables, pos, pt: m._ssm_decode(p, h, *held),
+        HybridLM._init_mamba2),
+    "gqa": MixerKind(
+        lambda c: (CacheLeaf("kv", True, (c.gqa_kv_row,), c.dtype),),
+        lambda m, p, h, valid, last: m._gqa_full(p, h),
+        lambda m, p, h, held, tables, pos, pt: m._gqa_decode(
+            p, h, *held, tables, pos, pt),
+        HybridLM._init_gqa),
+}

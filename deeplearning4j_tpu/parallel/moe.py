@@ -187,17 +187,21 @@ def moe_reference_dense(params, x, cfg: MoEConfig):
 # --------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class RoutedExpertsConfig:
-    """A layer of many small SwiGLU experts of which this chip holds a
-    contiguous range. ``router_width`` is the published number of experts
-    and is never cut: every token is scored against all of them and chooses
-    ``top_k``; ``held = (first, count)`` says which of them live here (the
-    deployment's configuration, as under expert parallelism)."""
+    """A layer of many small experts of which this chip holds a contiguous
+    range. ``router_width`` is the published number of experts and is never
+    cut: every token is scored against all of them and chooses ``top_k``;
+    ``held = (first, count)`` says which of them live here (the deployment's
+    configuration, as under expert parallelism). ``form`` is what an expert
+    (and the shared expert) computes: ``swiglu``, a fused gate|up matrix and
+    a down matrix, or ``relu2``, two matrices with a squared ReLU between
+    them and no gate."""
 
     router_width: int
     top_k: int
     held: tuple                     # (first, count)
     scale: float = 1.0              # routed_scaling_factor
     renormalize: bool = True
+    form: str = "swiglu"            # "swiglu" | "relu2"
 
     def __post_init__(self):
         first, count = self.held
@@ -208,15 +212,32 @@ class RoutedExpertsConfig:
         if not 1 <= self.top_k <= self.router_width:
             raise ValueError(f"top_k {self.top_k} outside [1, "
                              f"{self.router_width}]")
+        if self.form not in EXPERT_FORMS:
+            raise ValueError(f"form {self.form!r} is none of "
+                             f"{sorted(EXPERT_FORMS)}")
 
 
-def swiglu(x, w_gu, w_down):
-    """SwiGLU with one fused gate|up matrix: x (.., d), w_gu (d, 2f), w_down
-    (f, d) -> float32 (.., d)."""
-    h = jnp.matmul(x, w_gu, preferred_element_type=jnp.float32)
-    f = w_down.shape[0]
-    act = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(x.dtype)
-    return jnp.matmul(act, w_down, preferred_element_type=jnp.float32)
+#: form -> the name of the first matrix's leaf (the second is ``w_down``)
+EXPERT_FORMS = {"swiglu": "w_gu", "relu2": "w_up"}
+
+
+def _activate(h, form: str, dtype):
+    """What stands between an expert's two products: h (.., 2f) float32 ->
+    (.., f) for ``swiglu`` (gate columns then up columns), h (.., f) ->
+    (.., f) for ``relu2``."""
+    if form == "swiglu":
+        f = h.shape[-1] // 2
+        return (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(dtype)
+    return jnp.square(jax.nn.relu(h)).astype(dtype)
+
+
+def feed_forward(x, p, form: str = "swiglu"):
+    """One feed-forward of ``form``: x (.., d), ``p`` its two matrices ->
+    float32 (.., d)."""
+    h = jnp.matmul(x, p[EXPERT_FORMS[form]],
+                   preferred_element_type=jnp.float32)
+    return jnp.matmul(_activate(h, form, x.dtype), p["w_down"],
+                      preferred_element_type=jnp.float32)
 
 
 def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
@@ -238,15 +259,29 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
     where the 8th and 9th are close; in float32 at highest precision the
     router costs microseconds.
 
-    ``params``: ``w_router`` (d, E), ``b_select`` (E,), ``w_gu``
-    (held, d, 2f: gate columns then up columns), ``w_down`` (held, f, d),
-    ``shared`` {``w_gu`` (d, 2f), ``w_down`` (f, d)}.
+    ``params``: ``w_router`` (d, E), ``b_select`` (E,), the experts' two
+    matrices in ``cfg.form`` - ``w_gu`` (held, w, 2f: gate columns then up
+    columns) or ``w_up`` (held, w, f), and ``w_down`` (held, f, w) -, and
+    ``shared``, one feed-forward of the same form over the full width d.
+    The experts' width w is d, or, where ``params`` holds the latent pair
+    ``w_latent_in`` (d, w) and ``w_latent_out`` (w, d), the width of that
+    latent space: every token is projected into it once before the experts,
+    and the weighted sum of the chosen experts' outputs once out of it (the
+    projection is linear, so the chips' shares still add up).
     ``stats``: [held experts with at least one pair, pairs on held experts,
     pairs routed anywhere (real tokens x ``top_k``)].
     """
     T, d = x.shape
     k = cfg.top_k
     first, count = cfg.held
+    latent = "w_latent_in" in params
+    if latent:
+        with jax.named_scope("moe_latent"):
+            u = jnp.matmul(x, params["w_latent_in"],
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+    else:
+        u = x
     with jax.named_scope("moe_route"):
         xr = x if x_route is None else x_route
         s = jax.nn.sigmoid(jnp.matmul(
@@ -266,26 +301,29 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
         order = jnp.argsort(local, stable=True)
         sizes = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)[:count]
         n_held = jnp.sum(sizes)
-        rows = x[order // k]                                     # (T k, d)
+        rows = u[order // k]                                     # (T k, w)
         valid = (jnp.arange(T * k) < n_held)[:, None]
         n_routed = k * (T if token_mask is None else jnp.sum(token_mask))
         stats = jnp.stack([jnp.sum(sizes > 0), n_held,
                            n_routed]).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
-        f = params["w_down"].shape[1]
-        h = lax.ragged_dot(rows, params["w_gu"], sizes,
+        h = lax.ragged_dot(rows, params[EXPERT_FORMS[cfg.form]], sizes,
                            preferred_element_type=jnp.float32)
-        act = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
-        y = lax.ragged_dot(act, params["w_down"], sizes,
+        y = lax.ragged_dot(_activate(h, cfg.form, x.dtype),
+                           params["w_down"], sizes,
                            preferred_element_type=jnp.float32)
         # rows behind the last group belong to no expert held here
         y = jnp.where(valid, y, 0.0)
     with jax.named_scope("moe_shared"):
-        shared = swiglu(x, params["shared"]["w_gu"],
-                         params["shared"]["w_down"])
+        shared = feed_forward(x, params["shared"], cfg.form)
     with jax.named_scope("moe_combine"):
         inverse = jnp.zeros((T * k,), jnp.int32).at[order].set(
             jnp.arange(T * k, dtype=jnp.int32))
-        pairs = y[inverse].reshape(T, k, d)
+        pairs = y[inverse].reshape(T, k, -1)
         routed = jnp.einsum("tk,tkd->td", jnp.where(here, w, 0.0), pairs)
+        if not latent:
+            return (shared + routed).astype(x.dtype), stats
+    with jax.named_scope("moe_latent"):
+        routed = jnp.matmul(routed.astype(x.dtype), params["w_latent_out"],
+                            preferred_element_type=jnp.float32)
         return (shared + routed).astype(x.dtype), stats

@@ -404,6 +404,62 @@ def test_transformer_uses_the_vocabulary_and_nothing_else():
     assert used == _named.SCOPES
 
 
+def test_hybrid_and_the_expert_layer_use_the_vocabulary_and_inner_names():
+    """``models/hybrid.py`` and ``parallel/moe.py`` name nothing outside the
+    fixed vocabulary and the inner names the benchmark's two readers know
+    (``_inner.INNER``: PR 27's; ``_nemotron.NAMES``: the state-space and
+    grouped-query layers and the experts' latent pair), and use every one
+    of the inner names."""
+    from perfbench.layer_metrics import _inner, _nemotron
+    used = set()
+    for rel in ("models/hybrid.py", "parallel/moe.py"):
+        with open(os.path.join(ROOT, "deeplearning4j_tpu", rel)) as f:
+            used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
+    inner = _inner.INNER | _nemotron.NAMES
+    assert used <= _named.SCOPES | inner
+    assert used >= inner
+    assert _nemotron.NAMES == {
+        "ssm_proj", "ssm_conv", "ssm_state", "ssm_out", "gqa_proj",
+        "gqa_attend", "moe_latent"}
+
+
+def test_hybrid_decode_program_names_the_new_scopes_inside_the_vocabulary():
+    """The compiled decode step of a Mamba-2 / grouped-query / latent-expert
+    model: every operation under one of the new inner names also sits under
+    the vocabulary's scope for that part of the block, so the accepted
+    readers (``kv_move``, ``unscoped``) and the new ones read one program."""
+    import json
+    from perfbench import harness
+    from perfbench.layer_metrics import _nemotron
+    mod = harness.load_module("models", "nemotron_h.py")
+    cfg = harness.load_json("configs",
+                            "nemotron-3-super-120b-a12b-ep4share.json")
+    cfg.update(cfg["rehearsal"])
+    model = mod.build_model(cfg)
+    shapes = mod.weight_shapes(cfg)
+    eng = DecodeEngine(model, shapes, max_len=cfg["n_positions"],
+                       prefill_buckets=[16], page_tokens=8)
+    cache = jax.eval_shape(lambda: model.new_paged_cache(4, 9, 8))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)     # noqa: E731
+    text = eng._decode_paged_jit.lower(
+        shapes, cache, i32(4, 16), i32(4), i32(4), i32()).compile().as_text()
+    outer = {"ssm_proj": {"attn_qkv"}, "ssm_conv": {"attn_qkv"},
+             "ssm_state": {"attn_core"}, "ssm_out": {"attn_out"},
+             "gqa_proj": {"attn_qkv", "attn_out"},
+             "gqa_attend": {"attn_core"}, "moe_latent": {"mlp"}}
+    seen = {}
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        inner = _nemotron.inner_of(name)
+        if inner:
+            seen.setdefault(inner, set()).add(_named.scope_of(name))
+    assert seen == outer, json.dumps({k: sorted(map(str, v))
+                                      for k, v in seen.items()})
+    found = {_named.scope_of(n)
+             for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert {"kv_write", "kv_gather", "mlp", "head", "embed", "ln"} <= found
+    assert found - {None} <= _named.SCOPES
+
+
 # ------------------------------------------------------ (d) the readers
 @pytest.mark.parametrize("op_name, scope", [
     ("jit(step)/jvp(ln)/mul", "ln"),
