@@ -7,7 +7,11 @@ Pallas kernels that keep the (T, T) scores in VMEM; the training path from
 ``models.transformer.FLASH_MIN_SEQ`` up, read by slabs of lanes straight from
 the model's (B, T, H, hd) projections) and the Strom-2015 threshold gradient
 codec (the distributed-training compressor, kept for the DCN cross-slice
-path).
+path). Serving added a third, the routed experts' grouped feed-forward
+(``kernels/grouped_ffn.py``, imported from its module by the trace that takes
+it, not from here: both of an expert's products and the activation between
+them in one kernel that streams each touched expert's weights once, for the
+one row tile a decode step's rows are).
 """
 from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
                                                         flash_attention_bthd)

@@ -384,7 +384,8 @@ class HybridLM:
     # ---- cache protocol: what the decode engine may ask for
     cache_features = frozenset()     # no dense cache, int8 pages or draft
     max_positions = None             # no position table bounds the cache
-    step_stats = ("experts_touched", "pairs_held", "pairs_routed")
+    step_stats = ("experts_touched", "pairs_held", "pairs_routed",
+                  "expert_visits")
     prefill_all_logits = False       # the prompt's last token's (B, 1, V)
 
     def __init__(self, config: HybridConfig, mesh=None):
@@ -981,7 +982,7 @@ class HybridLM:
     def decode_paged(self, params, arrays, tables, tokens, positions,
                      page_tokens):
         """One token a slot: tokens, positions (B,) -> (logits (B, V),
-        arrays, stats int32[3] summed over the expert layers). A slot whose
+        arrays, stats int32[4] summed over the expert layers). A slot whose
         table points at the trash page is free: it routes to no expert."""
         c = self.config
         self._say_layers()

@@ -195,6 +195,13 @@ class _GenMetrics:
             "held experts that received at least one token, summed over "
             "expert layers and decode steps (the expert weights a step "
             "had to read)")
+        self.moe_visits = reg.counter(
+            "dl4j_moe_expert_visits_total",
+            "times the grouped feed-forward kernel streamed an expert's "
+            "weights, summed over expert layers and decode steps: equal to "
+            "dl4j_moe_experts_touched_total where the kernel reads each "
+            "touched expert once, 0 where lax.ragged_dot computes the "
+            "experts")
         self.slots_in_use = reg.gauge(
             "dl4j_decode_slots_in_use",
             "slots occupied by in-flight generations (sampled per step "
@@ -1344,6 +1351,7 @@ class GenerationPipeline:
                 if counts:
                     held = counts["pairs_held"]
                     obs.moe_touched.inc(counts["experts_touched"])
+                    obs.moe_visits.inc(counts["expert_visits"])
                     obs.moe_pairs["1"].inc(held)
                     obs.moe_pairs["0"].inc(counts["pairs_routed"] - held)
                 if self._fresh_decode_compile():

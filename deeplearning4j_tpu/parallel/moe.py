@@ -8,11 +8,18 @@ under jit (no data-dependent gather/scatter), and sharding the expert axis
 over the ``expert`` mesh dimension makes GSPMD insert the token all-to-alls
 over ICI. Over-capacity tokens are dropped (their output is the residual
 zero), exactly as in Switch/GShard.
+
+Second half of the file: the routed experts a serving chip holds its share
+of (``routed_experts_ffn``: k of many a token, no capacity, pairs sorted by
+expert; one ``lax.ragged_dot`` a matrix, or, where a TPU program's rows fit
+one row tile - a decode step -, one Pallas kernel for an expert's two
+products, ``kernels/grouped_ffn.py``: :func:`expert_backend` decides).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import logging
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -240,18 +247,71 @@ def feed_forward(x, p, form: str = "swiglu"):
                       preferred_element_type=jnp.float32)
 
 
+def _on_tpu() -> bool:
+    """Whether the program is traced for the TPU (as ``_attn`` asks before
+    it takes the flash kernels)."""
+    return jax.default_backend() == "tpu"
+
+
+def expert_backend(rows: int, width: int, inner: int, n_first: int,
+                   experts: int, itemsize: int) -> Tuple[str, str]:
+    """(``grouped-ffn`` | ``ragged_dot``, why) for ``rows`` pair rows through
+    ``experts`` experts ``width`` x ``inner`` (the first matrix ``n_first``
+    times ``inner`` wide): the Pallas kernel (``kernels/grouped_ffn.py``)
+    where the program is traced for the TPU, both widths are whole tiles of
+    128 lanes and the rows are one row tile in VMEM, which a decode step's
+    are and a prefill bucket's are not; the two ``lax.ragged_dot`` everywhere
+    else. Consulted at trace time only. The kernel's module (and Pallas with
+    it, a second of imports) is loaded by the first trace that may take it."""
+    if not _on_tpu():
+        return "ragged_dot", f"on {jax.default_backend()}"
+    if width % 128 or inner % 128:
+        return "ragged_dot", (f"experts {width} x {inner} are not whole "
+                              "tiles of 128 lanes")
+    from deeplearning4j_tpu.kernels import grouped_ffn as kernel
+    if not kernel.fits_one_tile(rows, width, inner, n_first, experts,
+                                itemsize):
+        return "ragged_dot", (f"{rows} pair rows of {width} are more than "
+                              "one row tile")
+    return "grouped-ffn", f"{rows} pair rows of {width} in one row tile"
+
+
+_said = {}
+
+
+def _say_backend(choice, why):
+    """``expert backend: <choice>: <reason>``, once a trace (every expert
+    layer asks; ``models/transformer.py::_say_once``'s spelling)."""
+    said = (jax.core.get_opaque_trace_state(), choice, why)
+    if said != _said.get("expert backend"):
+        _said["expert backend"] = said
+        logging.getLogger(__name__).info("expert backend: %s: %s", choice,
+                                         why)
+
+
 def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
                        x_route=None):
-    """x (T, d) -> (y (T, d) in x's dtype, stats int32[3]).
+    """x (T, d) -> (y (T, d) in x's dtype, stats int32[4]).
 
     ``s = sigmoid(x W_r)`` over all ``router_width`` experts; the ``top_k``
     largest of ``s + b_select`` are chosen; their weights are ``s`` at the
     chosen, divided by their sum and scaled. The token-expert pairs that fall
-    on held experts are sorted by expert and go through one grouped product
-    (``lax.ragged_dot``) a matrix; pairs on absent experts add nothing - what
+    on held experts are sorted by expert and go through the experts' two
+    matrices group by group; pairs on absent experts add nothing - what
     those experts would give is the other chips' part of the result. No pair
     on a held expert is ever dropped: there is no capacity. The shared expert
-    is added once. ``token_mask`` (T,) marks the rows that carry a real
+    is added once.
+
+    What computes the groups follows from what the trace can see
+    (:func:`expert_backend`): one ``lax.ragged_dot`` a matrix, or, in a TPU
+    program whose pair rows are one row tile (a decode step), one Pallas
+    kernel for both products and the activation between them, which reads
+    every expert that has a row once and keeps the hidden rows on the chip.
+    Same rounding either way (bfloat16 operands, float32 sums, the activation
+    rounded to x's dtype). Logged once a trace:
+    ``expert backend: grouped-ffn | ragged_dot: <reason>``.
+
+    ``token_mask`` (T,) marks the rows that carry a real
     token (a free decode slot routes nowhere and touches no expert).
     ``x_route`` (T, d), if given, is what the router scores instead of ``x``:
     the same rows before they were rounded to the experts' dtype. Choosing 8
@@ -269,7 +329,10 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
     and the weighted sum of the chosen experts' outputs once out of it (the
     projection is linear, so the chips' shares still add up).
     ``stats``: [held experts with at least one pair, pairs on held experts,
-    pairs routed anywhere (real tokens x ``top_k``)].
+    pairs routed anywhere (real tokens x ``top_k``), ``expert_visits``: how
+    many times the kernel streamed an expert's weights - the first count
+    again where each touched expert is read once; 0 where ``ragged_dot``
+    ran].
     """
     T, d = x.shape
     k = cfg.top_k
@@ -304,16 +367,27 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
         rows = u[order // k]                                     # (T k, w)
         valid = (jnp.arange(T * k) < n_held)[:, None]
         n_routed = k * (T if token_mask is None else jnp.sum(token_mask))
-        stats = jnp.stack([jnp.sum(sizes > 0), n_held,
-                           n_routed]).astype(jnp.int32)
+        counts = jnp.stack([jnp.sum(sizes > 0), n_held,
+                            n_routed]).astype(jnp.int32)
+    w_first, w_down = params[EXPERT_FORMS[cfg.form]], params["w_down"]
+    _count, inner, width = w_down.shape
+    backend, why = expert_backend(T * k, width, inner,
+                                  w_first.shape[2] // inner, count,
+                                  w_down.dtype.itemsize)
+    _say_backend(backend, why)
     with jax.named_scope("moe_experts"):
-        h = lax.ragged_dot(rows, params[EXPERT_FORMS[cfg.form]], sizes,
-                           preferred_element_type=jnp.float32)
-        y = lax.ragged_dot(_activate(h, cfg.form, x.dtype),
-                           params["w_down"], sizes,
-                           preferred_element_type=jnp.float32)
+        if backend == "grouped-ffn":
+            from deeplearning4j_tpu.kernels.grouped_ffn import grouped_ffn
+            y, visits = grouped_ffn(rows, w_first, w_down, sizes, cfg.form)
+        else:
+            h = lax.ragged_dot(rows, w_first, sizes,
+                               preferred_element_type=jnp.float32)
+            y = lax.ragged_dot(_activate(h, cfg.form, x.dtype), w_down,
+                               sizes, preferred_element_type=jnp.float32)
+            visits = jnp.zeros((), jnp.int32)
         # rows behind the last group belong to no expert held here
         y = jnp.where(valid, y, 0.0)
+        stats = jnp.concatenate([counts, visits[None]])
     with jax.named_scope("moe_shared"):
         shared = feed_forward(x, params["shared"], cfg.form)
     with jax.named_scope("moe_combine"):

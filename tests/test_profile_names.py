@@ -460,6 +460,50 @@ def test_hybrid_decode_program_names_the_new_scopes_inside_the_vocabulary():
     assert found - {None} <= _named.SCOPES
 
 
+def test_hybrid_decode_step_for_the_tpu_has_the_kernel_under_moe_experts(
+        monkeypatch):
+    """The decode step of the same model lowered for the TPU, the experts
+    widened to whole lanes and the process's backend patched (what the
+    program asks before it takes a kernel): every expert layer calls the ONE
+    jitted ``_grouped_ffn``, which holds the one Pallas custom call, on a
+    path ``.../mlp/moe_experts/jit(_grouped_ffn)`` - the compiled call's
+    ``op_name`` is that path + ``/pallas_call`` (compiled for a described
+    v5e in tests/test_grouped_ffn.py), so the accepted readers find the
+    kernel's seconds under ``mlp`` and ``moe_experts`` with no reading by
+    name, and it is no longer ``unscoped``; no ``ragged_dot`` is left."""
+    from perfbench import harness
+    from perfbench.layer_metrics import _inner
+    mod = harness.load_module("models", "nemotron_h.py")
+    cfg = harness.load_json("configs",
+                            "nemotron-3-super-120b-a12b-ep4share.json")
+    cfg.update(cfg["rehearsal"])
+    cfg.update(moe_latent_size=128, moe_intermediate_size=128)
+    model = mod.build_model(cfg)
+    shapes = mod.weight_shapes(cfg)
+    eng = DecodeEngine(model, shapes, max_len=cfg["n_positions"],
+                       prefill_buckets=[16], page_tokens=8)
+    cache = jax.eval_shape(lambda: model.new_paged_cache(4, 9, 8))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)     # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = eng._decode_paged_jit.trace(
+        shapes, cache, i32(4, 16), i32(4), i32(4), i32()).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert '"chlo.ragged_dot"(' not in text
+    assert text.count("custom_call @tpu_custom_call") == 1
+    body = text[text.index("func.func private @_grouped_ffn("):]
+    assert "custom_call @tpu_custom_call" in body[:body.index("\n  }")]
+    calls = [line for line in text.splitlines()
+             if "call @_grouped_ffn(" in line]
+    assert len(calls) == len(model.moe_layers) == 5
+    for call in calls:
+        loc = re.search(r"loc\((#loc\d+)\)\s*$", call).group(1)
+        path = re.search(rf'^{loc} = loc\("([^"]*)"', text, re.M).group(1)
+        assert path.endswith("/mlp/moe_experts/jit(_grouped_ffn)")
+        op_name = path + "/pallas_call"
+        assert _named.scope_of(op_name) == "mlp"
+        assert _inner.inner_of(op_name) == "moe_experts"
+
+
 # ------------------------------------------------------ (d) the readers
 @pytest.mark.parametrize("op_name, scope", [
     ("jit(step)/jvp(ln)/mul", "ln"),
