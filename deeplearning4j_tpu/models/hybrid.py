@@ -1,14 +1,22 @@
 """HybridLM - a decoder built from a per-layer description.
 
 Each layer names its mixer (``kda``: gated delta-rule linear attention with a
-short convolution; ``mla``: latent attention without positions; ``mamba2``: a
-state-space layer with a scalar decay a head; ``gqa``: softmax attention with
-grouped key/value heads and no positions) and its feed-forward (``dense``
-SwiGLU, or ``moe``: routed experts of which this chip holds a share,
-``parallel/moe.py::routed_experts_ffn``). Either may be absent: a layer is then
-ONE pre-norm residual part, a mixer alone or a feed-forward alone, with one
-norm. RMSNorm, no position table, untied head. Parameters are held in
-``param_dtype`` and computed with as they are: nothing is cast per call.
+short convolution; ``mla``: latent attention, its rope dimensions rotated
+where the configuration gives a ``rope_theta`` and carried unrotated where
+not, its queries behind a bottleneck where it gives a ``q_lora_rank``;
+``mamba2``: a state-space layer with a scalar decay a head; ``gqa``: softmax
+attention with grouped key/value heads and no positions) and its feed-forward
+(``dense`` SwiGLU, or ``moe``: routed experts of which this chip holds a
+share, ``parallel/moe.py::routed_experts_ffn``). Either may be absent: a layer
+is then ONE pre-norm residual part, a mixer alone or a feed-forward alone,
+with one norm. A layer that is more than that lists its ``parts``
+(:class:`Part`): each a mixer or a feed-forward with the name of its
+parameters, the norm it reads through (or the rows the part before it read)
+and where its result lands - at once, or after the layer's last part (a
+shortcut around what lies between: the double layer whose expert layer reads
+the first feed-forward's rows and is added behind the second). RMSNorm, no
+position table, untied head. Parameters are held in ``param_dtype`` and
+computed with as they are: nothing is cast per call.
 
 Three spellings of the same mathematics:
 
@@ -37,11 +45,14 @@ leaf of the cache of its own, so that a step rewrites it in place.
 Named scopes: the outer names are the fixed vocabulary of
 ``models/transformer.py`` (``embed``, ``ln``, ``attn_qkv``, ``attn_core``,
 ``attn_out``, ``mlp``, ``head``, ``kv_write``, ``kv_gather``); inside them
-``kda_proj``, ``kda_conv``, ``kda_state``, ``kda_out``, ``mla_proj``,
-``mla_attend``, ``ssm_proj``, ``ssm_conv``, ``ssm_state``, ``ssm_out``,
-``gqa_proj``, ``gqa_attend`` and, from the expert layer, ``moe_route``,
-``moe_experts``, ``moe_shared``, ``moe_combine``, ``moe_latent``. One log
-line a trace, ``layer kinds: ...``, names the layers and the experts' form.
+``kda_proj``, ``kda_conv``, ``kda_state``, ``kda_out``, ``mla_proj`` (and
+in it ``mla_rope``, the rotation), ``mla_attend``, ``ssm_proj``,
+``ssm_conv``, ``ssm_state``, ``ssm_out``, ``gqa_proj``, ``gqa_attend``,
+``ffn_dense`` (a dense feed-forward) and, from the expert layer,
+``moe_route``, ``moe_experts``, ``moe_shared``, ``moe_combine`` (and in it
+``moe_zero``, the identity experts' weighted copy), ``moe_latent``. One log
+line a trace, ``layer kinds: ...``, names the layers' parts, the experts'
+form and the router.
 """
 from __future__ import annotations
 
@@ -68,15 +79,52 @@ _SUB = 16
 _QUERY_BLOCK = 512
 
 
+_FFNS = ("dense", "moe")
+
+
+@dataclasses.dataclass(frozen=True)
+class Part:
+    """One residual part of a layer. ``kind``: a key of ``MIXERS``, ``dense``
+    or ``moe``. ``name``: the key of its parameters in the layer's block.
+    ``norm``: the key of its RMSNorm gain there, or None where it reads the
+    rows the part before it read (normalised once, by that part's gain).
+    ``lands``: ``now``, its result is added to the stream at once, or
+    ``end``, behind the layer's last part."""
+
+    kind: str
+    name: str
+    norm: Optional[str]
+    lands: str = "now"
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    mixer: Optional[str]            # a key of ``MIXERS``, or None
-    ffn: Optional[str]              # "dense" | "moe" | None
+    """A layer: a ``mixer`` and a feed-forward ``ffn``, each through a norm
+    of its own and added at once (either may be absent), or, for a layer that
+    is more than that, its ``parts`` in order."""
+
+    mixer: Optional[str] = None     # a key of ``MIXERS``, or None
+    ffn: Optional[str] = None       # "dense" | "moe" | None
+    parts: Tuple[Part, ...] = ()
 
     def __post_init__(self):
-        if (self.mixer not in (None, *MIXERS)
-                or self.ffn not in (None, "dense", "moe")
-                or (self.mixer is None and self.ffn is None)):
+        if not self.parts:
+            if (self.mixer not in (None, *MIXERS)
+                    or self.ffn not in (None, *_FFNS)
+                    or (self.mixer is None and self.ffn is None)):
+                raise ValueError(f"unknown layer {self}")
+            object.__setattr__(self, "parts", tuple(
+                Part(kind, name, norm) for kind, name, norm in (
+                    (self.mixer, "mixer", "ln1"), (self.ffn, "ffn", "ln2"))
+                if kind is not None))
+            return
+        parts = tuple(self.parts)
+        object.__setattr__(self, "parts", parts)
+        names = [p.name for p in parts] + [p.norm for p in parts if p.norm]
+        if (self.mixer is not None or self.ffn is not None
+                or any(p.kind not in (*MIXERS, *_FFNS)
+                       or p.lands not in ("now", "end") for p in parts)
+                or parts[0].norm is None or len(set(names)) != len(names)):
             raise ValueError(f"unknown layer {self}")
 
 
@@ -97,9 +145,17 @@ class HybridConfig:
     kda_chunk: int = 64
     mla_heads: int = 32
     qk_nope_dim: int = 128
-    qk_rope_dim: int = 64           # carried, never rotated (mla_use_nope)
+    qk_rope_dim: int = 64
+    #: whether the model rotates: the base of the rotary positions on the
+    #: ``qk_rope_dim`` dimensions of every query head and of the shared key
+    #: row (adjacent pairs), or None: they are carried and never rotated
+    rope_theta: Optional[float] = None
     v_head_dim: int = 128
     kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None     # None: no query bottleneck
+    #: the bottlenecks' scales, sqrt(d_model / rank) behind each one's norm
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     ssm_heads: int = 128
     ssm_head_dim: int = 64
     ssm_groups: int = 8             # heads i uses B, C of group i // (H / G)
@@ -116,8 +172,12 @@ class HybridConfig:
 
     def __post_init__(self):
         self.layers = tuple(self.layers)
-        if any(s.ffn == "moe" for s in self.layers) and self.experts is None:
+        if any(p.kind == "moe" for s in self.layers
+               for p in s.parts) and self.experts is None:
             raise ValueError("a layer with routed experts needs `experts`")
+        if self.mla_scale_q_lora and not self.q_lora_rank:
+            raise ValueError("mla_scale_q_lora scales a query bottleneck: "
+                             "give `q_lora_rank`")
         if self.kda_chunk % _SUB:
             raise ValueError(f"kda_chunk must be a multiple of {_SUB}")
         if self.ssm_heads % self.ssm_groups \
@@ -191,6 +251,21 @@ def _rms(x, g, eps):
 
 def _mm(x, w):
     return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+
+
+def _rope(x, positions, theta: float):
+    """Rotary positions on the last axis of x (..., r), float32 out: the
+    adjacent pair (2i, 2i + 1) is turned by ``positions * theta^(-2i / r)``.
+    ``positions`` broadcasts against x's leading axes."""
+    r = x.shape[-1]
+    lane = jnp.arange(r)
+    ang = positions[..., None].astype(jnp.float32) * (
+        theta ** (-(lane - lane % 2).astype(jnp.float32) / r))
+    x = x.astype(jnp.float32)
+    # each lane's partner in its pair, the even lane's negated
+    other = jnp.where(lane % 2 == 0, -jnp.roll(x, -1, axis=-1),
+                      jnp.roll(x, 1, axis=-1))
+    return x * jnp.cos(ang) + other * jnp.sin(ang)
 
 
 # ------------------------------------------- the short causal convolution
@@ -384,8 +459,6 @@ class HybridLM:
     # ---- cache protocol: what the decode engine may ask for
     cache_features = frozenset()     # no dense cache, int8 pages or draft
     max_positions = None             # no position table bounds the cache
-    step_stats = ("experts_touched", "pairs_held", "pairs_routed",
-                  "expert_visits")
     prefill_all_logits = False       # the prompt's last token's (B, 1, V)
 
     def __init__(self, config: HybridConfig, mesh=None):
@@ -396,17 +469,24 @@ class HybridLM:
         self.mesh = None
         c = config
         self.moe_layers = [i for i, s in enumerate(c.layers)
-                           if s.ffn == "moe"]
-        #: per layer, its rank among the layers of its mixer kind: where its
-        #: arrays stand in the lists of its kind's leaves
+                           if any(p.kind == "moe" for p in s.parts)]
+        #: the counts a decode step returns behind its tokens, summed over
+        #: the expert layers (``routed_experts_ffn``'s ``stats``)
+        self.step_stats = ("experts_touched", "pairs_held", "pairs_routed",
+                           "expert_visits") + (
+            ("pairs_zero",) if c.experts and c.experts.identity else ())
+        #: per layer and part, a mixer's rank among the mixers of its kind:
+        #: where its arrays stand in the lists of its kind's leaves
         seen: Dict[str, int] = {}
         self._rank = []
         for s in c.layers:
-            self._rank.append(seen.get(s.mixer, 0))
-            seen[s.mixer] = self._rank[-1] + 1
-        #: (leaf, layers that own it) over the kinds present, the paged
+            self._rank.append([])
+            for p in s.parts:
+                self._rank[-1].append(seen.get(p.kind, 0))
+                seen[p.kind] = self._rank[-1][-1] + 1
+        #: (leaf, mixers that own it) over the kinds present, the paged
         #: leaves first
-        leaves = [(leaf, n) for kind, n in seen.items() if kind is not None
+        leaves = [(leaf, n) for kind, n in seen.items() if kind in MIXERS
                   for leaf in MIXERS[kind].leaves(c)]
         self.cache_leaves = sorted(leaves, key=lambda ln: not ln[0].paged)
         self._said: Dict[str, Any] = {}
@@ -432,27 +512,31 @@ class HybridLM:
                 d_in, (2 if form == "swiglu" else 1) * width)),
                 "w_down": w(lead + (width, d_in), resid)}
 
+        def experts():
+            e = c.experts
+            wide = c.expert_latent or d
+            p = {"w_router": w((d, e.router_width)),
+                 "b_select": jnp.zeros((e.router_width,), jnp.float32),
+                 **ffn(c.expert_ff, (e.held[1],), wide, e.form)}
+            if e.shared:
+                p["shared"] = ffn(c.shared_ff or c.expert_ff, form=e.form)
+            if c.expert_latent:
+                p.update(w_latent_in=w((d, wide)),
+                         w_latent_out=w((wide, d), resid))
+            return p
+
         blocks = []
         for spec in c.layers:
             blk = {}
-            if spec.mixer is not None:
-                blk["ln1"] = ones(d)
-                blk["mixer"] = MIXERS[spec.mixer].init(self, w, ones, resid)
-            if spec.ffn == "dense":
-                blk["ffn"] = ffn(c.dense_ff)
-            elif spec.ffn == "moe":
-                e = c.experts
-                wide = c.expert_latent or d
-                blk["ffn"] = {
-                    "w_router": w((d, e.router_width)),
-                    "b_select": jnp.zeros((e.router_width,), jnp.float32),
-                    **ffn(c.expert_ff, (e.held[1],), wide, e.form),
-                    "shared": ffn(c.shared_ff or c.expert_ff, form=e.form)}
-                if c.expert_latent:
-                    blk["ffn"].update(w_latent_in=w((d, wide)),
-                                      w_latent_out=w((wide, d), resid))
-            if spec.ffn is not None:
-                blk["ln2"] = ones(d)
+            for part in spec.parts:
+                if part.norm is not None:
+                    blk[part.norm] = ones(d)
+                if part.kind in MIXERS:
+                    blk[part.name] = MIXERS[part.kind].init(self, w, ones,
+                                                            resid)
+                else:
+                    blk[part.name] = ffn(c.dense_ff) \
+                        if part.kind == "dense" else experts()
             blocks.append(blk)
         return {"tok_emb": w((c.vocab_size, d)), "head": w((d, c.vocab_size)),
                 "ln_f": ones(d), "blocks": blocks}
@@ -473,9 +557,12 @@ class HybridLM:
 
     def _init_mla(self, w, ones, resid):
         c = self.config
-        hm, d = c.mla_heads, c.d_model
+        hm, d, rq = c.mla_heads, c.d_model, c.q_lora_rank
+        hq = hm * (c.qk_nope_dim + c.qk_rope_dim)
         return {
-            "w_q": w((d, hm * (c.qk_nope_dim + c.qk_rope_dim))),
+            **({"w_qa": w((d, rq)), "q_norm": ones(rq),
+                "w_qb": w((rq, hq), rq ** -0.5)} if rq
+               else {"w_q": w((d, hq))}),
             "w_kva": w((d, c.latent_dim)),
             "kv_norm": ones(c.kv_lora_rank),
             "w_kvb": w((c.kv_lora_rank,
@@ -517,14 +604,15 @@ class HybridLM:
         with jax.named_scope("head"):
             return _mm(x, params["head"])
 
-    def _ffn(self, blk, spec, h32, token_mask):
-        """h32 (..., d) float32 -> (y, stats or None). The router scores the
-        float32 rows; the experts take them in ``dtype``."""
+    def _ffn(self, p, kind, h32, token_mask):
+        """A feed-forward of ``kind`` (``dense`` or ``moe``) with parameters
+        ``p``: h32 (..., d) float32 -> (y, stats or None). The router scores
+        the float32 rows; the experts take them in ``dtype``."""
         h = h32.astype(self.config.dtype)
         with jax.named_scope("mlp"):
-            p = blk["ffn"]
-            if spec.ffn == "dense":
-                return feed_forward(h, p).astype(h.dtype), None
+            if kind == "dense":
+                with jax.named_scope("ffn_dense"):
+                    return feed_forward(h, p).astype(h.dtype), None
             flat = h.reshape(-1, h.shape[-1])
             mask = None if token_mask is None else token_mask.reshape(-1)
             y, stats = routed_experts_ffn(
@@ -608,20 +696,44 @@ class HybridLM:
         with jax.named_scope("attn_out"):
             return self._kda_out(p, o, gate), s, tail
 
-    def _mla_project(self, p, h):
-        """h (..., d) -> q_nope (..., H, n), q_rope (..., H, r) and the row
-        the cache keeps: [rms(c), k_rope, zeros] (..., latent_row)."""
+    def _mla_project(self, p, h, positions=None):
+        """h (..., d) at ``positions`` (broadcast against h's leading axes;
+        None: whole sequences, 0 .. T - 1 along the axis before the last)
+        -> q_nope (..., H, n), q_rope (..., H, r) and the row the cache
+        keeps: [rms(c), k_rope, zeros] (..., latent_row). Where the model
+        rotates, q_rope and k_rope are rotated here, the key row once, before
+        it is kept. Where the queries have a bottleneck, ``q = rms(h W_qa)
+        W_qb``; each bottleneck's scale acts behind its norm."""
         c = self.config
         with jax.named_scope("mla_proj"):
-            q = _mm(h, p["w_q"]).astype(c.dtype).reshape(
+            if c.q_lora_rank:
+                cq = _rms(_mm(h, p["w_qa"]), p["q_norm"], c.rms_eps)
+                if c.mla_scale_q_lora:
+                    cq = cq * math.sqrt(c.d_model / c.q_lora_rank)
+                q = _mm(cq.astype(c.dtype), p["w_qb"])
+            else:
+                q = _mm(h, p["w_q"])
+            q = q.astype(c.dtype).reshape(
                 *h.shape[:-1], c.mla_heads, c.qk_nope_dim + c.qk_rope_dim)
             kva = _mm(h, p["w_kva"])
             lat = _rms(kva[..., :c.kv_lora_rank], p["kv_norm"], c.rms_eps)
-            row = jnp.concatenate([lat, kva[..., c.kv_lora_rank:]],
-                                  axis=-1).astype(c.dtype)
+            if c.mla_scale_kv_lora:
+                lat = lat * math.sqrt(c.d_model / c.kv_lora_rank)
+            k_r = kva[..., c.kv_lora_rank:]
+            if c.rope_theta:
+                if positions is None:
+                    positions = jnp.arange(h.shape[-2])
+                with jax.named_scope("mla_rope"):
+                    k_r = _rope(k_r, positions, c.rope_theta)
+            row = jnp.concatenate([lat, k_r], axis=-1).astype(c.dtype)
             row = jnp.pad(row, [(0, 0)] * (row.ndim - 1)
                           + [(0, c.latent_row - c.latent_dim)])
-        return q[..., :c.qk_nope_dim], q[..., c.qk_nope_dim:], row
+            q_n, q_r = q[..., :c.qk_nope_dim], q[..., c.qk_nope_dim:]
+            if c.rope_theta:
+                with jax.named_scope("mla_rope"):
+                    q_r = _rope(q_r, positions[..., None],
+                                c.rope_theta).astype(c.dtype)
+        return q_n, q_r, row
 
     def _mla_kvb(self, p):
         c = self.config
@@ -685,7 +797,7 @@ class HybridLM:
         R = c.kv_lora_rank
         scale = (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
         with jax.named_scope("attn_qkv"):
-            q_n, q_r, row = self._mla_project(p, h)
+            q_n, q_r, row = self._mla_project(p, h, positions)
             with jax.named_scope("mla_proj"):
                 wk, wv = self._mla_kvb(p)
                 q_c = jnp.einsum("bhn,chn->bhc", q_n, wk,
@@ -875,23 +987,50 @@ class HybridLM:
 
     # ------------------------------------------------------ full forward
     def _say_layers(self):
-        """``layer kinds: <one word a layer>: <the experts' form>``, once a
-        trace (as ``TransformerLM`` says its layouts)."""
+        """``layer kinds: <a layer's parts, joined by +, a layer>: <the
+        experts' form and the router>``, once a trace (as ``TransformerLM``
+        says its layouts)."""
         c = self.config
         said = jax.core.get_opaque_trace_state()
         if said == self._said.get("layer kinds"):
             return
         self._said["layer kinds"] = said
-        kinds = " ".join("+".join(k for k in (s.mixer, s.ffn) if k)
-                         for s in c.layers)
+        # a part that lands at the layer's end stands in brackets
+        kinds = " ".join("+".join(
+            p.kind if p.lands == "now" else f"[{p.kind}]" for p in s.parts)
+            for s in c.layers)
         e = c.experts
         why = "no routed experts" if not self.moe_layers else (
             f"experts {e.form}"
             + (f" in a {c.expert_latent}-wide latent space"
                if c.expert_latent else "")
-            + f", {e.top_k} of {e.router_width} a token, {e.held[1]} held "
-            f"from {e.held[0]}")
+            + ("" if e.score == "sigmoid" else f", {e.score}")
+            + f", {e.top_k} of {e.router_width} a token"
+            + (f": {e.router_width - e.identity} experts + {e.identity} "
+               f"identity" if e.identity else "")
+            + f", {e.held[1]} held from {e.held[0]}")
         logging.getLogger(__name__).info("layer kinds: %s: %s", kinds, why)
+
+    def _layers(self, params, x, run):
+        """The one walk over the description: every layer's parts in order,
+        ``run(part, rank, p, h32) -> y`` for each (``rank``: a mixer's among
+        the mixers of its kind, ``p`` its parameters, ``h32`` the normalised
+        rows it reads, float32), each result added to the stream where the
+        part says it lands."""
+        for blk, spec, ranks in zip(params["blocks"], self.config.layers,
+                                    self._rank):
+            late = []
+            for part, rank in zip(spec.parts, ranks):
+                if part.norm is not None:
+                    h32 = self._ln(blk[part.norm], x)
+                y = run(part, rank, blk[part.name], h32)
+                if part.lands == "end":
+                    late.append(y)
+                else:
+                    x = x + y
+            for y in late:
+                x = x + y
+        return x
 
     def _trunk(self, params, tokens, last_idx):
         """tokens (B, T) -> (x (B, T, d) before the final norm, cache
@@ -900,21 +1039,19 @@ class HybridLM:
         self._say_layers()
         T = tokens.shape[1]
         valid = jnp.arange(T) <= last_idx
-        x = self._embed(params, tokens)
         entries = {leaf.name: [] for leaf, _n in self.cache_leaves}
-        for blk, spec in zip(params["blocks"], c.layers):
-            if spec.mixer is not None:
-                kind = MIXERS[spec.mixer]
-                h = self._ln(blk["ln1"], x).astype(c.dtype)
-                y, *new = kind.full(self, blk["mixer"], h, valid, last_idx)
-                for leaf, entry in zip(kind.leaves(c), new):
-                    entries[leaf.name].append(entry)
-                x = x + y
-            if spec.ffn is not None:
-                y, _ = self._ffn(blk, spec, self._ln(blk["ln2"], x),
-                                 jnp.broadcast_to(valid, tokens.shape))
-                x = x + y
-        return x, entries
+
+        def run(part, _rank, p, h32):
+            if part.kind not in MIXERS:
+                return self._ffn(p, part.kind, h32,
+                                 jnp.broadcast_to(valid, tokens.shape))[0]
+            kind = MIXERS[part.kind]
+            y, *new = kind.full(self, p, h32.astype(c.dtype), valid, last_idx)
+            for leaf, entry in zip(kind.leaves(c), new):
+                entries[leaf.name].append(entry)
+            return y
+
+        return self._layers(params, self._embed(params, tokens), run), entries
 
     def apply(self, params, tokens):
         """tokens (B, T) int32 -> logits (B, T, V) float32."""
@@ -982,8 +1119,9 @@ class HybridLM:
     def decode_paged(self, params, arrays, tables, tokens, positions,
                      page_tokens):
         """One token a slot: tokens, positions (B,) -> (logits (B, V),
-        arrays, stats int32[4] summed over the expert layers). A slot whose
-        table points at the trash page is free: it routes to no expert."""
+        arrays, stats int32[len(step_stats)] summed over the expert layers).
+        A slot whose table points at the trash page is free: it routes to no
+        expert."""
         c = self.config
         self._say_layers()
         pools = [arrays[leaf.name][0] for leaf, _n in self.cache_leaves
@@ -992,23 +1130,25 @@ class HybridLM:
         x = self._embed(params, tokens)
         out = {leaf.name: [] for leaf, _n in self.cache_leaves}
         stats = jnp.zeros((len(self.step_stats),), jnp.int32)
-        for blk, spec, rank in zip(params["blocks"], c.layers, self._rank):
-            if spec.mixer is not None:
-                kind = MIXERS[spec.mixer]
-                names = [leaf.name for leaf in kind.leaves(c)]
-                h = self._ln(blk["ln1"], x).astype(c.dtype)
-                y, *held = kind.decode(
-                    self, blk["mixer"], h, [arrays[n][rank] for n in names],
-                    tables, positions, page_tokens)
-                for n, a in zip(names, held):
-                    out[n].append(a)
-                x = x + y
-            if spec.ffn is not None:
-                y, st = self._ffn(blk, spec, self._ln(blk["ln2"], x),
-                                  occupied)
+
+        def run(part, rank, p, h32):
+            nonlocal stats
+            if part.kind not in MIXERS:
+                y, st = self._ffn(p, part.kind, h32, occupied)
                 if st is not None:
                     stats = stats + st
-                x = x + y
+                return y
+            kind = MIXERS[part.kind]
+            names = [leaf.name for leaf in kind.leaves(c)]
+            y, *held = kind.decode(
+                self, p, h32.astype(c.dtype),
+                [arrays[n][rank] for n in names], tables, positions,
+                page_tokens)
+            for n, a in zip(names, held):
+                out[n].append(a)
+            return y
+
+        x = self._layers(params, x, run)
         return self._head(params, x), out, stats
 
 

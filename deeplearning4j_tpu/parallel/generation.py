@@ -186,10 +186,15 @@ class _GenMetrics:
             "pipelines (capacity + the trash page, x page bytes)")
         pairs = reg.counter(
             "dl4j_moe_pairs_total",
-            "token-expert pairs decode steps routed, by whether the "
-            "chosen expert is held on this chip (held=\"0\": another "
-            "chip's part of the result)", label_names=("held",))
+            "token-expert pairs decode steps routed to an expert with "
+            "weights, by whether it is held on this chip (held=\"0\": "
+            "another chip's part of the result)", label_names=("held",))
         self.moe_pairs = {h: pairs.labels(held=h) for h in ("1", "0")}
+        self.moe_zero = reg.counter(
+            "dl4j_moe_zero_pairs_total",
+            "token-expert pairs decode steps routed to an identity "
+            "(zero-compute) expert: the token itself, weighted, computed "
+            "where the token lives; 0 for a router without such experts")
         self.moe_touched = reg.counter(
             "dl4j_moe_experts_touched_total",
             "held experts that received at least one token, summed over "
@@ -1350,10 +1355,13 @@ class GenerationPipeline:
                 _cost.global_cost_model().observe_time(DECODE_FN, dt)
                 if counts:
                     held = counts["pairs_held"]
+                    zero = counts.get("pairs_zero", 0)
                     obs.moe_touched.inc(counts["experts_touched"])
                     obs.moe_visits.inc(counts["expert_visits"])
                     obs.moe_pairs["1"].inc(held)
-                    obs.moe_pairs["0"].inc(counts["pairs_routed"] - held)
+                    obs.moe_pairs["0"].inc(counts["pairs_routed"] - held
+                                           - zero)
+                    obs.moe_zero.inc(zero)
                 if self._fresh_decode_compile():
                     self.engine.account_decode(
                         self._cache, self._tokens, self._positions,

@@ -189,19 +189,29 @@ def moe_reference_dense(params, x, cfg: MoEConfig):
 
 
 # --------------------------------------------------------------------------
-# Routed experts, the chip's share: sigmoid router at published width, k a
-# token, nothing dropped, a grouped product over the experts held here
+# Routed experts, the chip's share: a router at published width, k a token,
+# nothing dropped, a grouped product over the experts held here
 # --------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class RoutedExpertsConfig:
     """A layer of many small experts of which this chip holds a contiguous
-    range. ``router_width`` is the published number of experts and is never
-    cut: every token is scored against all of them and chooses ``top_k``;
-    ``held = (first, count)`` says which of them live here (the deployment's
-    configuration, as under expert parallelism). ``form`` is what an expert
-    (and the shared expert) computes: ``swiglu``, a fused gate|up matrix and
-    a down matrix, or ``relu2``, two matrices with a squared ReLU between
-    them and no gate."""
+    range, and what its router may be. ``router_width`` is the published
+    number of router outputs and is never cut: every token is scored against
+    all of them and chooses ``top_k``; ``held = (first, count)`` says which
+    of the experts live here (the deployment's configuration, as under expert
+    parallelism). ``form`` is what an expert (and the shared expert)
+    computes: ``swiglu``, a fused gate|up matrix and a down matrix, or
+    ``relu2``, two matrices with a squared ReLU between them and no gate.
+
+    The router: ``score`` is ``sigmoid`` (each output alone) or ``softmax``
+    (over all ``router_width`` outputs); the chosen scores are the weights,
+    divided by their sum where ``renormalize``, times ``scale``. ``shared``
+    says whether one shared expert is added to every token. The last
+    ``identity`` outputs of the router are identity experts (zero-compute
+    experts): they hold no weights, what they "return" is the token itself,
+    so a token's number of real experts varies from 0 to ``top_k``. They are
+    computed where the token lives (no exchange is needed), on every chip of
+    the deployment for its own tokens."""
 
     router_width: int
     top_k: int
@@ -209,23 +219,35 @@ class RoutedExpertsConfig:
     scale: float = 1.0              # routed_scaling_factor
     renormalize: bool = True
     form: str = "swiglu"            # "swiglu" | "relu2"
+    score: str = "sigmoid"          # "sigmoid" | "softmax"
+    shared: bool = True
+    identity: int = 0
 
     def __post_init__(self):
         first, count = self.held
+        if not 0 <= self.identity < self.router_width:
+            raise ValueError(f"identity {self.identity} outside the "
+                             f"router's {self.router_width} outputs")
         if not (0 <= first and count >= 1
-                and first + count <= self.router_width):
+                and first + count <= self.router_width - self.identity):
             raise ValueError(f"held {self.held} outside the router's "
-                             f"{self.router_width} experts")
+                             f"{self.router_width - self.identity} experts")
         if not 1 <= self.top_k <= self.router_width:
             raise ValueError(f"top_k {self.top_k} outside [1, "
                              f"{self.router_width}]")
         if self.form not in EXPERT_FORMS:
             raise ValueError(f"form {self.form!r} is none of "
                              f"{sorted(EXPERT_FORMS)}")
+        if self.score not in ROUTER_SCORES:
+            raise ValueError(f"score {self.score!r} is none of "
+                             f"{sorted(ROUTER_SCORES)}")
 
 
 #: form -> the name of the first matrix's leaf (the second is ``w_down``)
 EXPERT_FORMS = {"swiglu": "w_gu", "relu2": "w_up"}
+#: what turns the router's logits (T, router_width) into scores
+ROUTER_SCORES = {"sigmoid": jax.nn.sigmoid,
+                 "softmax": lambda z: jax.nn.softmax(z, axis=-1)}
 
 
 def _activate(h, form: str, dtype):
@@ -291,16 +313,20 @@ def _say_backend(choice, why):
 
 def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
                        x_route=None):
-    """x (T, d) -> (y (T, d) in x's dtype, stats int32[4]).
+    """x (T, d) -> (y (T, d) in x's dtype, stats int32[4], or [5] where the
+    router has identity experts).
 
-    ``s = sigmoid(x W_r)`` over all ``router_width`` experts; the ``top_k``
-    largest of ``s + b_select`` are chosen; their weights are ``s`` at the
-    chosen, divided by their sum and scaled. The token-expert pairs that fall
-    on held experts are sorted by expert and go through the experts' two
-    matrices group by group; pairs on absent experts add nothing - what
-    those experts would give is the other chips' part of the result. No pair
-    on a held expert is ever dropped: there is no capacity. The shared expert
-    is added once.
+    ``s = score(x W_r)`` over all ``router_width`` outputs (what a router may
+    be is said once, on :class:`RoutedExpertsConfig`); the ``top_k`` largest
+    of ``s + b_select`` are chosen; their weights are ``s`` at the chosen,
+    divided by their sum where the configuration renormalises, and scaled.
+    The token-expert pairs that fall on held experts are sorted by expert and
+    go through the experts' two matrices group by group; pairs on absent
+    experts add nothing - what those experts would give is the other chips'
+    part of the result. No pair on a held expert is ever dropped: there is no
+    capacity. A pair on an identity expert adds its weight times the token
+    itself, here, and is no row of the grouped product. The shared expert,
+    where there is one, is added once.
 
     What computes the groups follows from what the trace can see
     (:func:`expert_backend`): one ``lax.ragged_dot`` a matrix, or, in a TPU
@@ -321,8 +347,9 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
 
     ``params``: ``w_router`` (d, E), ``b_select`` (E,), the experts' two
     matrices in ``cfg.form`` - ``w_gu`` (held, w, 2f: gate columns then up
-    columns) or ``w_up`` (held, w, f), and ``w_down`` (held, f, w) -, and
-    ``shared``, one feed-forward of the same form over the full width d.
+    columns) or ``w_up`` (held, w, f), and ``w_down`` (held, f, w) -, and,
+    where ``cfg.shared``, ``shared``, one feed-forward of the same form over
+    the full width d.
     The experts' width w is d, or, where ``params`` holds the latent pair
     ``w_latent_in`` (d, w) and ``w_latent_out`` (w, d), the width of that
     latent space: every token is projected into it once before the experts,
@@ -332,7 +359,7 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
     pairs routed anywhere (real tokens x ``top_k``), ``expert_visits``: how
     many times the kernel streamed an expert's weights - the first count
     again where each touched expert is read once; 0 where ``ragged_dot``
-    ran].
+    ran] and, where ``cfg.identity``, the pairs on identity experts.
     """
     T, d = x.shape
     k = cfg.top_k
@@ -347,7 +374,7 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
         u = x
     with jax.named_scope("moe_route"):
         xr = x if x_route is None else x_route
-        s = jax.nn.sigmoid(jnp.matmul(
+        s = ROUTER_SCORES[cfg.score](jnp.matmul(
             xr, params["w_router"].astype(xr.dtype),
             precision=lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32))
@@ -369,6 +396,10 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
         n_routed = k * (T if token_mask is None else jnp.sum(token_mask))
         counts = jnp.stack([jnp.sum(sizes > 0), n_held,
                             n_routed]).astype(jnp.int32)
+        if cfg.identity:
+            zero = idx >= cfg.router_width - cfg.identity
+            if token_mask is not None:
+                zero = zero & token_mask[:, None]
     w_first, w_down = params[EXPERT_FORMS[cfg.form]], params["w_down"]
     _count, inner, width = w_down.shape
     backend, why = expert_backend(T * k, width, inner,
@@ -388,16 +419,34 @@ def routed_experts_ffn(params, x, cfg: RoutedExpertsConfig, token_mask=None,
         # rows behind the last group belong to no expert held here
         y = jnp.where(valid, y, 0.0)
         stats = jnp.concatenate([counts, visits[None]])
-    with jax.named_scope("moe_shared"):
-        shared = feed_forward(x, params["shared"], cfg.form)
+    if cfg.shared:
+        with jax.named_scope("moe_shared"):
+            shared = feed_forward(x, params["shared"], cfg.form)
+
+    def total(routed):
+        """The layer's result from the held experts' weighted sum."""
+        if cfg.shared:
+            routed = shared + routed
+        if cfg.identity:
+            routed = routed + same
+        return routed.astype(x.dtype)
+
     with jax.named_scope("moe_combine"):
         inverse = jnp.zeros((T * k,), jnp.int32).at[order].set(
             jnp.arange(T * k, dtype=jnp.int32))
         pairs = y[inverse].reshape(T, k, -1)
         routed = jnp.einsum("tk,tkd->td", jnp.where(here, w, 0.0), pairs)
+        if cfg.identity:
+            # nested in ``moe_combine``, so that a reader of the ``moe_*``
+            # scopes counts the identity pairs' weighted copy with them
+            with jax.named_scope("moe_zero"):
+                same = jnp.sum(jnp.where(zero, w, 0.0), axis=-1,
+                               keepdims=True) * x.astype(jnp.float32)
+                stats = jnp.concatenate(
+                    [stats, jnp.sum(zero).astype(jnp.int32)[None]])
         if not latent:
-            return (shared + routed).astype(x.dtype), stats
+            return total(routed), stats
     with jax.named_scope("moe_latent"):
         routed = jnp.matmul(routed.astype(x.dtype), params["w_latent_out"],
                             preferred_element_type=jnp.float32)
-        return (shared + routed).astype(x.dtype), stats
+        return total(routed), stats

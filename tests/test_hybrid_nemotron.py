@@ -481,7 +481,7 @@ class _NamedLeaves(hybrid.HybridLM):
                 y, row = self._mla_full(blk["mixer"], h)
                 entries["latent"].append(row)
             x = x + y
-            y, _ = self._ffn(blk, spec, self._ln(blk["ln2"], x),
+            y, _ = self._ffn(blk["ffn"], spec.ffn, self._ln(blk["ln2"], x),
                              jnp.broadcast_to(valid, tokens.shape))
             x = x + y
         return x, entries
@@ -525,7 +525,8 @@ class _NamedLeaves(hybrid.HybridLM):
                 out["latent"].append(pool)
                 i_mla += 1
             x = x + y
-            y, st = self._ffn(blk, spec, self._ln(blk["ln2"], x), occupied)
+            y, st = self._ffn(blk["ffn"], spec.ffn, self._ln(blk["ln2"], x),
+                              occupied)
             if st is not None:
                 stats = stats + st
             x = x + y

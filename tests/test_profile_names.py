@@ -406,18 +406,20 @@ def test_transformer_uses_the_vocabulary_and_nothing_else():
 
 def test_hybrid_and_the_expert_layer_use_the_vocabulary_and_inner_names():
     """``models/hybrid.py`` and ``parallel/moe.py`` name nothing outside the
-    fixed vocabulary and the inner names the benchmark's two readers know
+    fixed vocabulary and the inner names the benchmark's three readers know
     (``_inner.INNER``: PR 27's; ``_nemotron.NAMES``: the state-space and
-    grouped-query layers and the experts' latent pair), and use every one
-    of the inner names."""
-    from perfbench.layer_metrics import _inner, _nemotron
+    grouped-query layers and the experts' latent pair; ``_longcat.NAMES``:
+    the dense feed-forwards, the rotation and the identity experts' copy),
+    and use every one of the inner names."""
+    from perfbench.layer_metrics import _inner, _longcat, _nemotron
     used = set()
     for rel in ("models/hybrid.py", "parallel/moe.py"):
         with open(os.path.join(ROOT, "deeplearning4j_tpu", rel)) as f:
             used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    inner = _inner.INNER | _nemotron.NAMES
+    inner = _inner.INNER | _nemotron.NAMES | _longcat.NAMES
     assert used <= _named.SCOPES | inner
     assert used >= inner
+    assert _longcat.NAMES == {"ffn_dense", "mla_rope", "moe_zero"}
     assert _nemotron.NAMES == {
         "ssm_proj", "ssm_conv", "ssm_state", "ssm_out", "gqa_proj",
         "gqa_attend", "moe_latent"}
