@@ -80,6 +80,7 @@ PREFILL_FN = "TransformerLM.prefill"
 DECODE_FN = "TransformerLM.decode_step"
 VERIFY_FN = "TransformerLM.spec_verify"
 PROPOSE_FN = "DraftLM.spec_propose"
+CARRY_FN = "DecodeEngine.carry_tokens"
 
 #: default KV page size in tokens (``DL4J_TPU_KV_PAGE_TOKENS``; 0 = the
 #: dense per-slot preallocation, byte-identical pre-paged behavior)
@@ -249,6 +250,17 @@ def sample_tokens(logits, rng, sampler: SamplerConfig):
         return jnp.take_along_axis(
             idxs, choice[..., None], axis=-1)[..., 0].astype(jnp.int32)
     return jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
+
+
+@jax.jit
+def _carry_tokens(nxt, own):
+    """A step's sampled tokens as the next step's input, without leaving
+    the device: ``own`` (slots,) holds the scheduler's token for a slot it
+    has one for and -1 elsewhere; whatever rides behind the tokens in
+    ``nxt`` (a model's counts of the step) is cut off. A few bytes, a
+    program of its own beside the decode step's."""
+    _cw.note_trace(CARRY_FN, nxt, own)
+    return jnp.where(own >= 0, own, nxt[:own.shape[0]])
 
 
 def default_prefill_buckets(max_len: int, lo: int = 16) -> Tuple[int, ...]:
@@ -718,7 +730,21 @@ class DecodeEngine:
         must use the returned one (a :class:`DecodeState` is mutated in
         place AND returned). Returns (next_tokens (B,), logits (B, V),
         cache). Paged callers must have ensured pages for every write
-        position (:meth:`ensure_slot_pages`)."""
+        position (:meth:`ensure_slot_pages`).
+
+        Of a step's inputs only ``tokens`` depends on the step before:
+        ``positions`` advance by one a step, the page tables are the
+        host's own books and ``step`` is a counter, so all three are host
+        values here. ``tokens`` may be host values too, or an array still
+        on the device (:meth:`carry_tokens` of the last step's output):
+        the call then returns without waiting for that step, and the
+        runtime starts this one when it ends. Calls run on the device in
+        the order they were made, which is why a scheduler may hand pages
+        it freed after the last call to whatever it calls next (an
+        insert, a later step): a stale write of the step still in flight
+        lands first. A scheduler that keeps a step in flight does so only
+        while every slot is occupied: with a free slot a queued step would
+        stand between an arrival and its prefill."""
         if isinstance(cache, DecodeState) and cache.mode == "paged":
             # back every OCCUPIED slot's write position (positions are
             # host values). Slots with no pages are free: their table
@@ -746,6 +772,22 @@ class DecodeEngine:
             cache.arrays = arrays
             return nxt, logits, cache
         return nxt, logits, arrays
+
+    def carry_tokens(self, nxt, own: np.ndarray):
+        """The ``tokens`` of the step after the one that returned ``nxt``,
+        as a device array: ``nxt``'s tokens, with ``own[slot]`` where it
+        is not -1 (a slot whose request joined since and brings its own
+        first token). Nothing is fetched."""
+        return _carry_tokens(nxt, jnp.asarray(own, jnp.int32))
+
+    def warm_carry(self, slots: int):
+        """Compile :meth:`carry_tokens`'s program for ``slots`` (a model's
+        counts ride behind its tokens, see :meth:`step_counts`): a
+        scheduler calls this when it is built, so that its first full
+        batch compiles nothing."""
+        self.carry_tokens(
+            np.zeros((slots + len(self.model.step_stats),), np.int32),
+            np.full((slots,), -1, np.int32))
 
     def step_counts(self, fetched: np.ndarray, slots: int) -> Dict[str, int]:
         """What rode behind a decode step's tokens in their one transfer:

@@ -61,9 +61,21 @@ another worker's journal through the ordinary admission path; page
 reclamation prefers shedding unjournaled (new) sessions over journaled
 ones; and a fence-stolen session sheds typed (``session_lost``) at the
 next boundary so a stalled worker can never double-decode.
+
+One step in flight (PR 39): of a decode step's inputs only the tokens
+come from the step before; positions, page tables and the step counter
+are the host's own. So while every slot is occupied the loop dispatches
+step k + 1 with step k's tokens still on the device
+(``DecodeEngine.carry_tokens``) and only then fetches, sweeps and
+publishes step k: the host's work of a pass runs beside the device's
+step, not between two steps. With a free slot the loop keeps the order
+dispatch, fetch, sweep, because then an arrival can join at the very
+next boundary and a step already queued would stand between it and its
+prefill (``_iterate``, ``_runs_ahead``).
 """
 from __future__ import annotations
 
+import logging
 import queue
 import threading
 import time
@@ -95,6 +107,8 @@ from deeplearning4j_tpu.resilience.policy import (TYPED_OUTCOMES,
                                                   default_deadline_ms)
 
 _TYPED_OUTCOMES = TYPED_OUTCOMES
+
+_log = logging.getLogger(__name__)
 
 #: the disjoint phases of one decode-loop iteration, in order: admit (joins
 #: and their prefills), reclaim (page backing for this step's writes),
@@ -139,6 +153,12 @@ class _GenMetrics:
             "dl4j_decode_steps_total",
             "decode step boundaries executed (each runs every occupied "
             "slot one token forward)")
+        self.steps_ahead = reg.counter(
+            "dl4j_decode_steps_ahead_total",
+            "decode steps dispatched while the step before was still on "
+            "the device, its tokens not yet fetched (every slot occupied): "
+            "over dl4j_decode_steps_total, the share of steps whose host "
+            "work ran beside the device and not between two steps")
         self.requests = reg.counter(
             "dl4j_decode_requests_total",
             "generation requests resolved (success, typed shed, or error)")
@@ -256,6 +276,23 @@ def _drop_gen_metrics():
     _GenMetrics._instance = None
 
 
+class _Flight:
+    """A decode step that is on the chip, its tokens not fetched yet: what
+    the pass that fetches it needs from the pass that dispatched it.
+    ``reqs`` is the slot table as the step saw it: a slot whose request
+    has left or changed by the fetch was a row nobody reads."""
+
+    __slots__ = ("step", "outputs", "active", "reqs", "live")
+
+    def __init__(self, step: int, outputs, active: List[int], reqs: list,
+                 live: int):
+        self.step = step
+        self.outputs = outputs      # (tokens [+ counts], logits), on device
+        self.active = active
+        self.reqs = reqs
+        self.live = live
+
+
 class _GenRequest(_Request):
     """One generation request riding the shared exactly-once machinery
     (``claim()``): ``x`` is the 1-D int32 prompt, ``out`` accumulates
@@ -365,7 +402,19 @@ class GenerationPipeline:
         # a popped request the pool couldn't back yet — retried at every
         # step boundary (pages free there) before the queue is touched
         self._waiting: Optional[_GenRequest] = None
+        # the index of the next decode step to dispatch (folded into the
+        # sampler's key), and the steps dispatched whose tokens are not
+        # fetched yet, oldest first: at most one between two passes of the
+        # loop, two inside a pass that runs ahead
         self._step = 0
+        self._inflight: List[_Flight] = []
+        self._steps_dispatched = 0
+        self._steps_ahead = 0
+        if not engine.spec:
+            # the few-byte program that hands a step's tokens to the next
+            # one on the device is compiled here, not by the first full
+            # batch inside somebody's window
+            engine.warm_carry(self.slots)
         self._thread = threading.Thread(target=self._decode_loop,
                                         daemon=True, name="dl4j-gen-decode")
         self._thread.start()
@@ -809,7 +858,15 @@ class GenerationPipeline:
     def _free_slot(self, slot: int):
         """Release ``slot``: request pointer, its cache pages (paged),
         and the position/token books — every slot-freeing path must go
-        through here or pages leak."""
+        through here or pages leak.
+
+        The pages go back to the pool at once, also while a step that
+        still writes this slot's row is in flight (dispatched before the
+        sweep that frees it): whatever writes them next, a joiner's insert
+        or a later step of the slot they are handed to, is enqueued behind
+        that step on the same device stream, so the stale row lands first
+        and is overwritten or never read (rows past a slot's position are
+        masked), as a retired slot's scribbles always were."""
         self._slot_req[slot] = None
         self.engine.free_slot(self._cache, slot)
         self._positions[slot] = 0
@@ -823,6 +880,11 @@ class GenerationPipeline:
         rebuild the page pool. Returns the resumable survivors for
         :meth:`_replace_survivors`. With sessions off every slot fails,
         byte-identical to the pre-session behavior."""
+        if self._inflight:
+            # the steps on the chip died with the cache and their tokens
+            # reached no stream: the books go back to the last swept step
+            self._step = self._inflight[0].step
+            self._inflight.clear()
         survivors: List[_GenRequest] = []
         for slot, req in enumerate(self._slot_req):
             if req is not None:
@@ -1198,12 +1260,26 @@ class GenerationPipeline:
         return left
 
     def _decode_loop(self):
+        """The decode thread: one ``decode_iter`` span a pass that has
+        work (:meth:`_iterate`), the phases' seconds into
+        ``dl4j_decode_loop_seconds_total``. A step may stay on the chip
+        from one pass to the next (``_inflight``): of a step's inputs only
+        the tokens depend on the step before (positions, page tables and
+        the step counter are the host's books), so while every slot is
+        occupied a pass dispatches the next step from tokens still on the
+        device and only then fetches and sweeps the one before. Pages a
+        sweep frees may be handed out with a step in flight because
+        whatever writes them next is enqueued behind it on the device's
+        one stream. A free slot switches the order back to dispatch,
+        fetch, sweep: an arrival can then join at the very next boundary,
+        and a step already queued would stand before its prefill."""
         while not self._stop.is_set():
             # re-fetch per iteration: a registry reset mid-flight drops
             # and re-binds the singleton (on_registry_reset) — a cached
             # handle would keep writing to detached instruments
             obs = _GenMetrics.get()
-            if self._waiting is None and self._n_active() == 0:
+            if (self._waiting is None and self._n_active() == 0
+                    and not self._inflight):
                 # idle: the wait for a request is no phase of any
                 # iteration, and a poll that found none records nothing
                 self._waiting = self._take_request(timeout=0.05)
@@ -1218,6 +1294,9 @@ class GenerationPipeline:
                     obs.loop_seconds[phase].inc(s)
         # shutdown: resolve whatever still occupies a slot (and the
         # parked joiner the pool never backed)
+        self._inflight.clear()
+        _log.info("decode loop: %d of %d steps dispatched ahead",
+                  self._steps_ahead, self._steps_dispatched)
         for slot, req in enumerate(self._slot_req):
             if req is not None:
                 self._fail_request(req, ShutdownError(
@@ -1228,13 +1307,113 @@ class GenerationPipeline:
                 "GenerationPipeline shut down"))
             self._waiting = None
 
+    def _owed(self, slot: int) -> int:
+        """Tokens ``slot``'s request still wants beyond the steps in
+        flight, by count alone (``max_new_tokens`` less what was swept and
+        what is on the chip): an end by ``eos_id``, a cancel or a deadline
+        shows only at the sweep. Zero or less: the next step's row for
+        this slot is a scribble nobody reads."""
+        req = self._slot_req[slot]
+        flying = sum(1 for f in self._inflight if f.reqs[slot] is req)
+        return req.max_new_tokens - len(req.out) - flying
+
+    def _runs_ahead(self, active: List[int]) -> bool:
+        """Whether the step after the newest one in flight may be
+        dispatched before that one's tokens are fetched. Decided from what
+        the loop observes, in this order:
+
+        - every slot is occupied. With a free slot an arrival can join at
+          the very next boundary, and a step already queued would put a
+          whole step between it and its prefill; with none it cannot join
+          before a sweep frees one, so nothing is lost by queueing;
+        - some slot has a token to gain from that step (a step in which
+          every row is an overshoot is not worth dispatching);
+        - the pool can back it: running ahead never sheds. If a write of
+          that step needs a page the pool cannot give, the pass drains
+          first (fetch and sweep the step in flight, which may free pages)
+          and the next pass reclaims in the synchronous order, so the
+          victim and the order of ``pages_exhausted`` sheds do not depend
+          on how far the loop ran ahead."""
+        if len(active) < self.slots:
+            return False
+        if not any(self._owed(s) > 0 for s in active):
+            return False
+        if not self.engine.paged:
+            return True
+        eng, st = self.engine, self._cache
+        short = sum(max(0, eng.pages_for(int(self._positions[s]) + 1)
+                        - len(st.slot_pages[s])) for s in active)
+        return short <= st.alloc.free_count
+
+    def _dispatch_step(self, active: List[int]):
+        """Hand step ``self._step`` to the device and put it in flight.
+        Its tokens are the host's (``self._tokens``) when nothing is in
+        flight, else those of the newest step in flight, still on the
+        device, with the host's token put in for every slot whose request
+        joined since that step was dispatched (a joiner's first token is
+        fetched by its prefill). The host's books then move to the step
+        after: positions advance by one for every slot that wants a
+        further token, which no fetch has to tell; a slot that by count
+        ends with the steps in flight keeps its position, so the step
+        ahead rewrites a row it already owns and needs no page."""
+        flying = self._inflight
+        if flying:
+            last = flying[-1]
+            own = np.full((self.slots,), -1, np.int32)
+            for slot in active:
+                if self._slot_req[slot] is not last.reqs[slot]:
+                    own[slot] = self._tokens[slot]
+            tokens = self.engine.carry_tokens(last.outputs[0], own)
+        else:
+            # copies: the device may read a host array after this call
+            # returns, and the books below change before the fetch
+            tokens = self._tokens.copy()
+        # the keys and values this step reads: every active slot's rows
+        # up to and including the one it writes
+        live = int(self._positions[active].sum()) + len(active)
+        nxt, logits, self._cache = self.engine.decode(
+            self._cache, tokens, self._positions.copy(), self._step)
+        flying.append(_Flight(self._step, (nxt, logits), active,
+                              list(self._slot_req), live))
+        self._positions[[s for s in active if self._owed(s) > 0]] += 1
+        self._step += 1
+        self._steps_dispatched += 1
+
     def _iterate(self, obs: "_GenMetrics", sec: Dict[str, float]):
-        """One pass of the decode loop with work in hand: join, back this
-        step's pages, step every occupied slot one token (or one
-        speculative round) forward, sweep, publish. Each phase is a span
-        under the caller's ``decode_iter`` and adds its seconds to
-        ``sec`` (``_LOOP_PHASES``; consecutive clock reads, so the
-        phases leave no gap between them)."""
+        """One pass of the decode loop with work in hand: join, back the
+        next step's pages, dispatch it, fetch a step's tokens, sweep,
+        publish. Each phase is a span under the caller's ``decode_iter``
+        and adds its seconds to ``sec`` (``_LOOP_PHASES``; consecutive
+        clock reads, so the phases leave no gap between them).
+
+        Which step the fetch is of follows from the occupancy
+        (:meth:`_runs_ahead`). Of the inputs of step k + 1 only the tokens
+        depend on step k; positions, page tables and the step counter are
+        the host's own books. So while every slot is occupied the pass
+        dispatches step k + 1 from tokens still on the device and THEN
+        fetches, sweeps and publishes step k: the device goes from one
+        step to the next with nothing between them, and the host's work
+        runs beside it. ``decode_step`` then spans the dispatch of step
+        k + 1 and the fetch of step k (attribute ``ahead`` 1, the other
+        attributes those of the step fetched): the time the loop waited
+        on the device for one step. With a free slot the pass fetches the
+        step it dispatched, the order there always was: an arrival can
+        then join at the very next boundary, and a step already queued
+        would put a whole step between it and its prefill. Between the two,
+        one pass dispatches and fetches nothing (the first with every
+        slot occupied) and one fetches and dispatches nothing (a slot has
+        come free and nobody joined, or the pool cannot back the step
+        ahead).
+
+        What a step in flight changes for the rest of the loop: a request
+        that ends at step k (``eos_id``, cancelled, expired, shed) has one
+        more row computed by the time the sweep learns it, and that row's
+        token is dropped at its fetch (``_Flight.reqs``); its pages are
+        handed out at once, which is safe because whatever writes them
+        next (an insert, a later step) is enqueued behind the step in
+        flight on the same device stream (:meth:`_free_slot`); a
+        fault loses the device results of the steps in flight and nothing
+        a stream has seen (:meth:`_rebuild_after_fault`)."""
         t_prev = time.perf_counter()
 
         def close(phase: str):
@@ -1249,7 +1428,8 @@ class GenerationPipeline:
                   if r is not None]
         obs.slots_in_use.set(len(active))
         close("admit")
-        if not active:
+        flying = self._inflight
+        if not active and not flying:
             return
         try:
             with _span("loop_reclaim"):
@@ -1257,17 +1437,22 @@ class GenerationPipeline:
                     self._retry.call(
                         lambda: _faults.check("generation.step"),
                         op="generation.step")
-                active = self._reclaim_pages(active)
+                # a pass dispatches the next step unless one is in flight
+                # and the next may not go before its fetch
+                older = bool(flying)
+                dispatch = not older or self._runs_ahead(active)
+                if dispatch:
+                    active = self._reclaim_pages(active)
             close("reclaim")
-            if not active:
+            if not active and not flying:
                 self._step += 1
                 self._publish_cache_bytes()
                 close("publish")
                 return
-            # the keys and values this step reads: every active slot's
-            # rows up to and including the one it writes
-            live = int(self._positions[active].sum()) + len(active)
             if self.engine.spec:
+                # a speculative round fetches twice inside itself: it
+                # keeps the synchronous order
+                live = int(self._positions[active].sum()) + len(active)
                 fetch0 = self.engine.spec_fetch_s
                 with _span("decode_step", active=len(active),
                            slots=self.slots, spec=True, live_tokens=live):
@@ -1279,6 +1464,8 @@ class GenerationPipeline:
                     # cache advanced one row per emitted token
                     self._tokens[slot] = toks_l[-1]
                     self._positions[slot] += len(toks_l)
+                self._step += 1
+                self._steps_dispatched += 1
                 close("dispatch")
                 # the round's waits for the device, as the engine timed
                 # them, are fetch; the rest of it is dispatch
@@ -1286,28 +1473,46 @@ class GenerationPipeline:
                 sec["dispatch"] -= waited
                 sec["fetch"] += waited
             else:
-                with _span("decode_step", active=len(active),
-                           slots=self.slots, live_tokens=live) as step_sp:
-                    with _span("decode_dispatch"):
-                        tokens, _logits, self._cache = self.engine.decode(
-                            self._cache, self._tokens, self._positions,
-                            self._step)
+                ahead = older and dispatch
+                with _span("decode_step", slots=self.slots,
+                           ahead=int(ahead)) as step_sp:
+                    if dispatch:
+                        with _span("decode_dispatch", step=self._step):
+                            self._dispatch_step(active)
+                        if ahead:
+                            self._steps_ahead += 1
+                            obs.steps_ahead.inc()
                     close("dispatch")
-                    with _span("token_fetch"):
-                        toks = np.asarray(tokens)  # device→host sync
+                    if not older and self._runs_ahead(active):
+                        # every slot occupied: the step stays on the chip
+                        # and the next pass dispatches the one after it
+                        # before it fetches this one
+                        return
+                    step = flying[0]
+                    with _span("token_fetch", step=step.step):
+                        toks = np.asarray(step.outputs[0])  # device→host
+                    flying.pop(0)
                     # a model's own counts of the step (experts touched,
                     # pairs on held experts) came in the same transfer
                     counts = self.engine.step_counts(toks, self.slots)
+                    step_sp.set_attr("step", step.step)
+                    step_sp.set_attr("active", len(step.active))
+                    step_sp.set_attr("live_tokens", step.live)
                     for name, n in counts.items():
                         step_sp.set_attr(name, n)
                     # the step's device outputs die here, inside the
                     # step's span and the fetch phase, not at this
                     # function's return: handing (B, V) float32 logits
                     # back to the runtime takes 1-2 ms on the chip's host
-                    del tokens, _logits
-                self._tokens[active] = toks[active]
-                self._positions[active] += 1
-                emitted = {s: [int(toks[s])] for s in active}
+                    step.outputs = None
+                emitted = {}
+                for slot in step.active:
+                    # a slot whose request left or changed while the
+                    # step ran was a scribble: its token is dropped
+                    if self._slot_req[slot] is step.reqs[slot]:
+                        self._tokens[slot] = toks[slot]
+                        emitted[slot] = [int(toks[slot])]
+                active = step.active
                 close("fetch")
             dt = sec["dispatch"] + sec["fetch"]
         # graftlint: disable=typed-errors — the catch must be broad
@@ -1318,10 +1523,12 @@ class GenerationPipeline:
                     and not isinstance(e, _TYPED_OUTCOMES)):
                 self._breaker.record_failure()
             # the step died mid-donation: the cache buffers are no
-            # longer trustworthy — rebuild the pages, resume the
-            # journaled sessions in place (tentpole 2; the in-graph
-            # seed makes the continued stream deterministic), and
-            # fail the rest (queued requests are untouched; the
+            # longer trustworthy — rebuild the pages (the steps in
+            # flight are lost with them and the step counter goes back
+            # to the oldest of them: no stream has seen their tokens),
+            # resume the journaled sessions in place (tentpole 2; the
+            # in-graph seed makes the continued stream deterministic),
+            # and fail the rest (queued requests are untouched; the
             # fresh state resets the page allocator and, in spec
             # mode, the draft cache with it)
             survivors = self._rebuild_after_fault(e)
@@ -1332,7 +1539,6 @@ class GenerationPipeline:
             self._publish_cache_bytes()
             close("publish")
             return
-        self._step += 1
         with _span("loop_sweep") as sp:
             sp.set_attr("finished", self._sweep_finished(emitted))
             sp.set_attr("emitted", sum(map(len, emitted.values())))
@@ -1483,6 +1689,7 @@ class GenerationPipeline:
             "active": self._n_active(),
             "queue_depth": self._queue.qsize(),
             "step": self._step,
+            "steps_ahead": self._steps_ahead,
             "max_len": self.engine.max_len,
             "prefill_buckets": list(self.engine.prefill_buckets),
             "sampler": {"kind": self.engine.sampler.kind,

@@ -151,7 +151,9 @@ def _settle(quiet=0.15, limit=10.0):
 def loop_run(monkeypatch):
     """Three staggered requests through a pipeline whose engine records
     the positions every decode call was given; yields (spans, positions
-    per decode call, counters before and after)."""
+    per decode call, counters before and after). Four slots, so one stays
+    free and every pass keeps the order dispatch, fetch, sweep: what a pass
+    looks like while every slot is occupied is ``tests/test_decode_ahead.py``'s."""
     reset_global_registry()
     eng = _engine()
     seen = []
@@ -162,7 +164,7 @@ def loop_run(monkeypatch):
         return real(cache, tokens, positions, step)
 
     monkeypatch.setattr(eng, "decode", decode)
-    with GenerationPipeline(eng, slots=3, max_new_tokens=40) as gp:
+    with GenerationPipeline(eng, slots=4, max_new_tokens=40) as gp:
         gp.generate(_prompt(5), max_new_tokens=3)        # compiles
         _settle()
         sink = reset_global_trace_sink()
