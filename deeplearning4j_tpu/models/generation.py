@@ -603,7 +603,11 @@ class DecodeEngine:
 
     def _tables(self, state: DecodeState):
         if state.tables_dev is None:
-            state.tables_dev = jnp.asarray(state.tables)
+            # a copy, as for a step's tokens and positions: on the CPU
+            # backend ``jnp.asarray`` may alias the host array, and the
+            # host's table changes (a join, a freed slot) while a step that
+            # was given this one is still in flight
+            state.tables_dev = jnp.asarray(state.tables.copy())
         return state.tables_dev
 
     # ---------------------------------------------------- quant numerics

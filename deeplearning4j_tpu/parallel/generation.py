@@ -72,6 +72,20 @@ step, not between two steps. With a free slot the loop keeps the order
 dispatch, fetch, sweep, because then an arrival can join at the very
 next boundary and a step already queued would stand between it and its
 prefill (``_iterate``, ``_runs_ahead``).
+
+The join, measured where it happens (PR 40): ``_start_request`` splits a
+join where the program hands over to the device, by consecutive clock
+reads: ``dispatch`` (pad, puts, the prefill program's call), ``insert``
+(page allocation, the insert program's call) and ``fetch`` (span
+``first_token_fetch`` inside ``prefill_insert``: the wait for the first
+token, which since PR 39 also waits for the decode step that was in flight
+when the join started). The request's ``prefill`` span carries the three
+as ``dispatch_us`` / ``insert_us`` / ``fetch_us`` beside ``bucket``,
+``inflight`` and ``step``; ``loop_admit`` carries ``free`` and ``queued``
+on a pass that joins; ``dl4j_decode_joins_total{bucket}`` and
+``dl4j_decode_join_seconds_total{phase}`` count the same without a trace,
+and ``snapshot()["joins"]`` and one INFO line at shutdown name the
+longest join and the part it was spent in.
 """
 from __future__ import annotations
 
@@ -87,6 +101,7 @@ import numpy as np
 from deeplearning4j_tpu.models.generation import (DECODE_FN, PREFILL_FN,
                                                   PROPOSE_FN, VERIFY_FN,
                                                   DecodeEngine)
+from deeplearning4j_tpu.observability import compile_watch as _cw
 from deeplearning4j_tpu.observability import cost_model as _cost
 from deeplearning4j_tpu.observability import global_registry, on_registry_reset
 from deeplearning4j_tpu.observability import span as _span
@@ -117,6 +132,13 @@ _log = logging.getLogger(__name__)
 #: free), publish (step metrics, cost model, breaker, flight recorder,
 #: journal, cache gauges). Their seconds sum to the loop's busy time.
 _LOOP_PHASES = ("admit", "reclaim", "dispatch", "fetch", "sweep", "publish")
+
+#: the parts of one join (``_start_request``), in order, split where the
+#: program hands over to the device: dispatch (pad, puts, the prefill
+#: program's call), insert (page allocation, the insert program's call, the
+#: draft's insert), fetch (the wait for the first token). Consecutive clock
+#: reads: they sum to the request's ``prefill`` span.
+_JOIN_PARTS = ("dispatch", "insert", "fetch")
 
 
 def _session_mod():
@@ -256,6 +278,21 @@ class _GenMetrics:
             "seconds the decode thread spent starting a joining request "
             "(prefill, insert, first-token fetch) while at least one "
             "other slot was active: every live stream waits that long")
+        self.joins = reg.counter(
+            "dl4j_decode_joins_total",
+            "joining requests started (prefill dispatched, cache entries "
+            "inserted, first token fetched), by the padded length the "
+            "prefill program ran at", label_names=("bucket",))
+        join = reg.counter(
+            "dl4j_decode_join_seconds_total",
+            "decode-thread seconds of the joins by part: dispatch (pad, "
+            "puts, the prefill program's call), insert (page allocation, "
+            "the insert program's call), fetch (the wait for the first "
+            "token: the device's prefill, and the decode step in flight "
+            "before it); the three sum to dl4j_decode_prefill_seconds' sum",
+            label_names=("phase",))
+        self.join_seconds = {ph: join.labels(phase=ph)
+                             for ph in _JOIN_PARTS}
         self.spec_accept = reg.gauge(
             "dl4j_spec_accept_ratio",
             "cumulative speculative-decode acceptance: accepted draft "
@@ -410,6 +447,15 @@ class GenerationPipeline:
         self._inflight: List[_Flight] = []
         self._steps_dispatched = 0
         self._steps_ahead = 0
+        # the joins' own books (``snapshot()["joins"]``, the stop line): the
+        # count, how many were started with a step in flight, the seconds of
+        # the three parts, and the longest join so far that traced no
+        # program (a new dict a record, so a snapshot never reads one
+        # half-written)
+        self._joins = 0
+        self._joins_behind = 0
+        self._join_s = dict.fromkeys(_JOIN_PARTS, 0.0)
+        self._join_longest: Optional[dict] = None
         if not engine.spec:
             # the few-byte program that hands a step's tokens to the next
             # one on the device is compiled here, not by the first full
@@ -933,8 +979,11 @@ class GenerationPipeline:
             record_span("slot_wait", req.t_enqueue_us, req.t_slot_us,
                         ctx=req.ctx, slot=slot)
         t_us = now_us()
-        # the live streams that get no token until this joiner is in
+        # the live streams that get no token until this joiner is in, and
+        # the steps on the chip its prefill queues behind
         stalled = self._n_active()
+        inflight = len(self._inflight)
+        traced0 = _cw.global_compile_watch().total
         # a resumed request re-prefills prompt + already-emitted tokens:
         # the cache rebuilds to exactly the state the lost slot held, and
         # the in-graph seeded sampler continues the identical stream
@@ -942,9 +991,11 @@ class GenerationPipeline:
         k_resumed = len(req.out)
         x_in = (np.concatenate([req.x, np.asarray(req.out, np.int32)])
                 if k_resumed else req.x)
+        n_in = int(x_in.size)
         try:
-            with _span("prefill_dispatch", slot=slot,
-                       prompt_tokens=int(x_in.size)):
+            bucket = self.engine.prefill_bucket(n_in)
+            with _span("prefill_dispatch", slot=slot, prompt_tokens=n_in,
+                       bucket=bucket, inflight=inflight):
                 first, _logits, kv, t = self.engine.prefill(
                     x_in[None], step=self._step)
         except Exception as e:
@@ -954,6 +1005,10 @@ class GenerationPipeline:
                 self._breaker.record_failure()
             self._fail_request(req, e)
             return False
+        # the join's three parts, split where the program hands over to the
+        # device: consecutive clock reads, so they leave no gap and sum to
+        # the ``prefill`` span
+        t_disp_us = now_us()
         try:
             with _span("prefill_insert", slot=slot):
                 self._cache = self.engine.insert_slot(self._cache, kv, slot)
@@ -964,13 +1019,28 @@ class GenerationPipeline:
                     self.engine.insert_draft_slot(self._cache, slot,
                                                   x_in[None],
                                                   step=self._step)
-                first_tok = int(np.asarray(first)[0])
+                t_ins_us = now_us()
+                # everything above returned as soon as the device had its
+                # work; this waits for it: the prefill program, and before
+                # it the decode step in flight, if there is one
+                with _span("first_token_fetch", slot=slot):
+                    first_tok = int(np.asarray(first)[0])
             end_us = now_us()
             dt = (end_us - t_us) * 1e-6
+            parts_us = (t_disp_us - t_us, t_ins_us - t_disp_us,
+                        end_us - t_ins_us)
             if req.ctx is not None:
                 record_span("prefill", t_us, end_us, ctx=req.ctx,
                             slot=slot, prompt_tokens=int(req.x.size),
-                            tokens=int(x_in.size), stalled_slots=stalled)
+                            tokens=n_in, stalled_slots=stalled,
+                            bucket=bucket, inflight=inflight,
+                            step=self._step, dispatch_us=parts_us[0],
+                            insert_us=parts_us[1], fetch_us=parts_us[2])
+            self._book_join(
+                obs, parts_us,
+                compiled=_cw.global_compile_watch().total != traced0,
+                slot=slot, bucket=bucket, tokens=n_in, inflight=inflight,
+                step=self._step)
             obs.prefill_latency.observe(dt)
             if stalled:
                 obs.prefill_stall.inc(dt)
@@ -1034,6 +1104,30 @@ class GenerationPipeline:
         self._positions[slot] = t
         return True
 
+    def _book_join(self, obs: "_GenMetrics", parts_us, compiled: bool,
+                   **what):
+        """One finished join into the counters and the pipeline's own books
+        (:meth:`snapshot`, the stop line): ``parts_us`` its dispatch, insert
+        and fetch microseconds, ``what`` its slot, bucket, tokens, the steps
+        in flight when it started and the loop's step counter. A join during
+        which a program was traced (a bucket's first, unless the deployment
+        warmed it) counts like any other but is not a candidate for the
+        longest: its seconds are a compile's or a cache load's, which
+        ``compile_watch`` reports, and it would hide every stall after it."""
+        obs.joins.labels(bucket=what["bucket"]).inc()
+        for phase, us in zip(_JOIN_PARTS, parts_us):
+            obs.join_seconds[phase].inc(us * 1e-6)
+            self._join_s[phase] += us * 1e-6
+        self._joins += 1
+        if what["inflight"]:
+            self._joins_behind += 1
+        ms = sum(parts_us) / 1e3
+        if not compiled and (self._join_longest is None
+                             or ms > self._join_longest["ms"]):
+            self._join_longest = dict(
+                what, ms=ms, **{ph + "_ms": us / 1e3
+                                for ph, us in zip(_JOIN_PARTS, parts_us)})
+
     def _maybe_preempt(self, pri: Optional[float] = None) -> bool:
         """Priority preemption at a step boundary (QoS posture): when
         the contending tier — the highest QUEUED tier by default, or an
@@ -1087,14 +1181,17 @@ class GenerationPipeline:
         moment reclamation or completions return enough pages.
         Never blocks: an idle pipeline waits for its next request in
         ``_decode_loop``, outside any iteration. Returns how many
-        requests it started."""
-        joined = 0
+        requests it started, the slots that were free when the first of
+        them was taken and the queue's depth after the last (both None
+        where nobody joined): whether several joiners ever meet at one
+        boundary, and whether more were waiting."""
+        joined, free0, queued = 0, None, None
         while not self._stop.is_set():
             free = [i for i, r in enumerate(self._slot_req) if r is None]
             if not free:
                 if self._qos and self._maybe_preempt():
                     continue       # a slot was freed — re-scan and join
-                return joined
+                break
             req, self._waiting = self._waiting, None
             if req is not None:
                 if req._claimed:
@@ -1107,7 +1204,7 @@ class GenerationPipeline:
             else:
                 req = self._take_request(timeout=0.0)
             if req is None:
-                return joined
+                break
             if (self.engine.paged
                     and self.engine.min_pages_for_prompt(
                         req.x.size + len(req.out))
@@ -1124,11 +1221,14 @@ class GenerationPipeline:
                     self._waiting = req
                     continue       # pages came back — retry this joiner
                 self._waiting = req
-                return joined
-            _GenMetrics.get().queue_depth.set(self._queue.qsize())
+                break
+            queued = self._queue.qsize()
+            _GenMetrics.get().queue_depth.set(queued)
+            if not joined:
+                free0 = len(free)
             self._start_request(req, free[0])
             joined += 1
-        return joined
+        return joined, free0, queued
 
     def _reclaim_victim_key(self, slot: int):
         """Reclamation victim ordering (max wins): shed sessions with
@@ -1297,6 +1397,7 @@ class GenerationPipeline:
         self._inflight.clear()
         _log.info("decode loop: %d of %d steps dispatched ahead",
                   self._steps_ahead, self._steps_dispatched)
+        _log.info("%s", self._joins_line())
         for slot, req in enumerate(self._slot_req):
             if req is not None:
                 self._fail_request(req, ShutdownError(
@@ -1306,6 +1407,23 @@ class GenerationPipeline:
             self._fail_request(self._waiting, ShutdownError(
                 "GenerationPipeline shut down"))
             self._waiting = None
+
+    def _joins_line(self) -> str:
+        """The joins of this pipeline's life in one line, for the log when
+        it stops: what a stall inside a join has to be hunted for
+        otherwise (which part, behind a step or not, which bucket)."""
+        j = self._join_s
+        line = (f"joins: {self._joins} in {sum(j.values()):.3f} s "
+                f"(dispatch {j['dispatch']:.3f}, insert {j['insert']:.3f}, "
+                f"fetch {j['fetch']:.3f}); {self._joins_behind} of "
+                f"{self._joins} behind a step in flight")
+        top = self._join_longest
+        if top is not None:
+            line += (f"; longest {top['ms']:.1f} ms: bucket {top['bucket']} "
+                     f"slot {top['slot']} inflight {top['inflight']} "
+                     f"dispatch/insert/fetch {top['dispatch_ms']:.1f}/"
+                     f"{top['insert_ms']:.1f}/{top['fetch_ms']:.1f}")
+        return line
 
     def _owed(self, slot: int) -> int:
         """Tokens ``slot``'s request still wants beyond the steps in
@@ -1423,7 +1541,11 @@ class GenerationPipeline:
             t_prev = now
 
         with _span("loop_admit") as sp:
-            sp.set_attr("joined", self._admit())
+            joined, free, queued = self._admit()
+            sp.set_attr("joined", joined)
+            if joined:
+                sp.set_attr("free", free)
+                sp.set_attr("queued", queued)
         active = [i for i, r in enumerate(self._slot_req)
                   if r is not None]
         obs.slots_in_use.set(len(active))
@@ -1690,6 +1812,9 @@ class GenerationPipeline:
             "queue_depth": self._queue.qsize(),
             "step": self._step,
             "steps_ahead": self._steps_ahead,
+            "joins": {"count": self._joins, "behind": self._joins_behind,
+                      **{ph + "_s": v for ph, v in self._join_s.items()},
+                      "longest": self._join_longest},
             "max_len": self.engine.max_len,
             "prefill_buckets": list(self.engine.prefill_buckets),
             "sampler": {"kind": self.engine.sampler.kind,

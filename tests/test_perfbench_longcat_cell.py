@@ -34,6 +34,10 @@ ACCEPTED = ["serve_tok_s", "setup_s", "slots_active_mean",
             "mla_roofline_pct.tput", "decode_touched_roofline_pct"]
 OWN = ["zero_pairs_pct.tput", "dense_ffn_dev_pct.tput",
        "dense_ffn_roofline_pct.tput"]
+#: what later PRs appended behind them for this cell (PR 40: the join)
+LATER = ["prefill_stall_pct.tput", "join_ms_per_ktok.tput",
+         "join_fetch_share_pct.tput", "join_max_ms.tput",
+         "joins_per_admit_mean.tput"]
 
 
 def test_benchmark_json_has_the_configuration_and_the_cell():
@@ -52,15 +56,21 @@ def test_benchmark_json_has_the_configuration_and_the_cell():
     assert all(1 <= len(x["why"]) <= 200 for x in (conf[0], row[0]))
     reported = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]
                 if CELL in m.get("workloads", [CELL])]
-    assert reported == ACCEPTED[:1] + ["setup_s"] + ACCEPTED[2:] + OWN
+    assert reported == ACCEPTED[:1] + ["setup_s"] + ACCEPTED[2:] + OWN \
+        + LATER
     # appended: the cell stands last in every list it joined, and its own
-    # three metrics last among the per-layer ones
+    # three metrics together behind every per-layer metric the benchmark
+    # had when it came, with only later PRs' behind them
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", []):
             assert m["workloads"][-1] == CELL and m["moves"] == "serve_tok_s" \
                 if "moves" in m else m["workloads"][-1] == CELL
-    assert [m["name"] for m in bench["per_layer"][-3:]] == OWN
-    assert [(m["source"], m["layer"]) for m in bench["per_layer"][-3:]] == [
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(OWN[0])
+    assert names[at:at + 3] == OWN
+    assert [n for n in names[at + 3:] if n in reported] == LATER
+    assert [(m["source"], m["layer"])
+            for m in bench["per_layer"][at:at + 3]] == [
         ("program_span", "engine"), ("device_trace", "model"),
         ("device_trace", "kernels")]
     # one four-chip cell in seven: inside the quarter the contract allows
