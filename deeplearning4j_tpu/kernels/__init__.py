@@ -11,7 +11,11 @@ path). Serving added a third, the routed experts' grouped feed-forward
 (``kernels/grouped_ffn.py``, imported from its module by the trace that takes
 it, not from here: both of an expert's products and the activation between
 them in one kernel that streams each touched expert's weights once, for the
-one row tile a decode step's rows are).
+one row tile a decode step's rows are), and a fourth, the paged latent
+attention of a decode step (``kernels/paged_latent_attention.py``, imported
+the same way: one query a slot against the slot's live pages of latent rows,
+fetched from the pool where they lie by the kernel's own asynchronous copies,
+eight pages a visit, with an online softmax; no gathered window).
 """
 from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
                                                         flash_attention_bthd)

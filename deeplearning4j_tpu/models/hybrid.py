@@ -453,6 +453,41 @@ def _slot_page(tables, positions, page_tokens: int, n_pages: int):
         n_pages - 1)
 
 
+def _on_tpu() -> bool:
+    """Whether the program is traced for the TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def latent_attention_backend(heads: int, row: int, out_width: int,
+                             page_tokens: int, pages: int,
+                             itemsize: int) -> Tuple[str, str]:
+    """(``paged-latent`` | ``gather``, why) for the absorbed latent attention
+    of a decode step: ``heads`` queries a slot against ``pages`` pages of
+    ``page_tokens`` rows ``row`` wide, of which the output reads
+    ``out_width``. The Pallas kernel (``kernels/paged_latent_attention.py``)
+    where the program is traced for the TPU, both widths are whole tiles of
+    128 lanes, a page is whole tiles of 8 rows and a visit's pages fit the
+    kernel's VMEM, which a page of thousands of rows does not; the gathered
+    window everywhere else. Consulted at trace time only
+    (``moe.expert_backend``'s manner); the kernel's module is loaded by the
+    first trace that may take it."""
+    if not _on_tpu():
+        return "gather", f"on {jax.default_backend()}"
+    if row % 128 or out_width % 128:
+        return "gather", (f"a row of {row}, read {out_width} wide, is not "
+                          "whole tiles of 128 lanes")
+    if page_tokens % 8:
+        return "gather", (f"a page of {page_tokens} rows is not whole tiles "
+                          "of 8 rows")
+    from deeplearning4j_tpu.kernels import paged_latent_attention as kernel
+    if not kernel.fits_vmem(heads, row, page_tokens, pages, itemsize):
+        n = kernel.visit_pages(page_tokens, row, itemsize, pages)
+        return "gather", (f"a visit of {n * page_tokens} rows of {row} is "
+                          "more than the kernel's VMEM")
+    return "paged-latent", (f"live pages of {page_tokens} rows of {row} read "
+                            "where they lie")
+
+
 class HybridLM:
     """See the module doc."""
 
@@ -490,6 +525,9 @@ class HybridLM:
                   for leaf in MIXERS[kind].leaves(c)]
         self.cache_leaves = sorted(leaves, key=lambda ln: not ln[0].paged)
         self._said: Dict[str, Any] = {}
+        #: (choice, why) that the last trace of a paged latent attention
+        #: took (:func:`latent_attention_backend`), None before any
+        self.attention_backend: Optional[Tuple[str, str]] = None
 
     # ------------------------------------------------------------ params
     def init_params(self, key) -> Dict:
@@ -789,7 +827,11 @@ class HybridLM:
 
     def _mla_decode(self, p, h, pool, tables, positions, page_tokens):
         """Absorbed form: h (B, d) against the slot's pages of latent rows.
-        The step's own row is written first, then read back with the rest."""
+        The step's own row is written first, then read back with the rest:
+        by the kernel that walks the slot's live pages where they lie
+        (``kernels/paged_latent_attention.py``) or, everywhere
+        :func:`latent_attention_backend` does not take it, over a gathered
+        view of every slot's whole window."""
         c = self.config
         B = h.shape[0]
         P = int(page_tokens)
@@ -806,21 +848,36 @@ class HybridLM:
         with jax.named_scope("kv_write"):
             page = _slot_page(tables, positions, P, pool.shape[0])
             pool = pool.at[page, positions % P].set(row)
-        with jax.named_scope("kv_gather"):
-            view = pool.at[tables].get(mode="promise_in_bounds").reshape(
-                B, S, c.latent_row)
-            view_c, view_r = view[..., :R], view[..., R:c.latent_dim]
-        with jax.named_scope("attn_core"), jax.named_scope("mla_attend"):
-            s = (jnp.einsum("bhc,bsc->bhs", q_c, view_c,
-                            preferred_element_type=jnp.float32)
-                 + jnp.einsum("bhr,bsr->bhs", q_r, view_r,
-                              preferred_element_type=jnp.float32)) * scale
-            live = jnp.arange(S)[None, :] <= positions[:, None]
-            s = jnp.where(live[:, None, :], s, -1e30)
-            pr = jax.nn.softmax(s, axis=-1).astype(c.dtype)
-            o_c = jnp.einsum("bhs,bsc->bhc", pr, view_c,
-                             preferred_element_type=jnp.float32
-                             ).astype(c.dtype)
+        self.attention_backend = latent_attention_backend(
+            c.mla_heads, c.latent_row, R, P, tables.shape[1],
+            jnp.dtype(c.dtype).itemsize)
+        self._say_once("attention backend", *self.attention_backend)
+        if self.attention_backend[0] == "paged-latent":
+            from deeplearning4j_tpu.kernels.paged_latent_attention import \
+                paged_latent_attention
+            with jax.named_scope("attn_core"), jax.named_scope("mla_attend"):
+                # against a whole cached row, whose padding is zeros
+                q = jnp.concatenate([q_c, q_r, jnp.zeros(
+                    (B, c.mla_heads, c.latent_row - c.latent_dim), c.dtype)],
+                    axis=-1)
+                o_c = paged_latent_attention(q, pool, tables, positions, R,
+                                             scale)
+        else:
+            with jax.named_scope("kv_gather"):
+                view = pool.at[tables].get(mode="promise_in_bounds").reshape(
+                    B, S, c.latent_row)
+                view_c, view_r = view[..., :R], view[..., R:c.latent_dim]
+            with jax.named_scope("attn_core"), jax.named_scope("mla_attend"):
+                s = (jnp.einsum("bhc,bsc->bhs", q_c, view_c,
+                                preferred_element_type=jnp.float32)
+                     + jnp.einsum("bhr,bsr->bhs", q_r, view_r,
+                                  preferred_element_type=jnp.float32)) * scale
+                live = jnp.arange(S)[None, :] <= positions[:, None]
+                s = jnp.where(live[:, None, :], s, -1e30)
+                pr = jax.nn.softmax(s, axis=-1).astype(c.dtype)
+                o_c = jnp.einsum("bhs,bsc->bhc", pr, view_c,
+                                 preferred_element_type=jnp.float32
+                                 ).astype(c.dtype)
         with jax.named_scope("attn_out"), jax.named_scope("mla_proj"):
             o = jnp.einsum("bhc,chv->bhv", o_c, wv,
                            preferred_element_type=jnp.float32).astype(c.dtype)
@@ -986,15 +1043,20 @@ class HybridLM:
             return _mm(o.reshape(B, -1), p["w_o"]).astype(c.dtype), pool
 
     # ------------------------------------------------------ full forward
+    def _say_once(self, subject, choice, why):
+        """``<subject>: <choice>: <reason>``, once a trace (every layer asks;
+        ``TransformerLM._say_once``'s spelling)."""
+        said = (jax.core.get_opaque_trace_state(), choice, why)
+        if said != self._said.get(subject):
+            self._said[subject] = said
+            logging.getLogger(__name__).info("%s: %s: %s", subject, choice,
+                                             why)
+
     def _say_layers(self):
         """``layer kinds: <a layer's parts, joined by +, a layer>: <the
         experts' form and the router>``, once a trace (as ``TransformerLM``
         says its layouts)."""
         c = self.config
-        said = jax.core.get_opaque_trace_state()
-        if said == self._said.get("layer kinds"):
-            return
-        self._said["layer kinds"] = said
         # a part that lands at the layer's end stands in brackets
         kinds = " ".join("+".join(
             p.kind if p.lands == "now" else f"[{p.kind}]" for p in s.parts)
@@ -1009,7 +1071,7 @@ class HybridLM:
             + (f": {e.router_width - e.identity} experts + {e.identity} "
                f"identity" if e.identity else "")
             + f", {e.held[1]} held from {e.held[0]}")
-        logging.getLogger(__name__).info("layer kinds: %s: %s", kinds, why)
+        self._say_once("layer kinds", kinds, why)
 
     def _layers(self, params, x, run):
         """The one walk over the description: every layer's parts in order,

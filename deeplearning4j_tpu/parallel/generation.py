@@ -319,15 +319,16 @@ class _Flight:
     ``reqs`` is the slot table as the step saw it: a slot whose request
     has left or changed by the fetch was a row nobody reads."""
 
-    __slots__ = ("step", "outputs", "active", "reqs", "live")
+    __slots__ = ("step", "outputs", "active", "reqs", "live", "pages")
 
     def __init__(self, step: int, outputs, active: List[int], reqs: list,
-                 live: int):
+                 live: int, pages: int):
         self.step = step
         self.outputs = outputs      # (tokens [+ counts], logits), on device
         self.active = active
         self.reqs = reqs
         self.live = live
+        self.pages = pages
 
 
 class _GenRequest(_Request):
@@ -1488,11 +1489,16 @@ class GenerationPipeline:
             tokens = self._tokens.copy()
         # the keys and values this step reads: every active slot's rows
         # up to and including the one it writes
-        live = int(self._positions[active].sum()) + len(active)
+        at = self._positions[active]
+        live = int(at.sum()) + len(active)
+        # and the pages those rows lie in: what an attention that walks a
+        # slot's live pages visits (0 for a cache that has no pages)
+        pt = self.engine.page_tokens
+        pages = int((at // pt + 1).sum()) if pt else 0
         nxt, logits, self._cache = self.engine.decode(
             self._cache, tokens, self._positions.copy(), self._step)
         flying.append(_Flight(self._step, (nxt, logits), active,
-                              list(self._slot_req), live))
+                              list(self._slot_req), live, pages))
         self._positions[[s for s in active if self._owed(s) > 0]] += 1
         self._step += 1
         self._steps_dispatched += 1
@@ -1620,6 +1626,7 @@ class GenerationPipeline:
                     step_sp.set_attr("step", step.step)
                     step_sp.set_attr("active", len(step.active))
                     step_sp.set_attr("live_tokens", step.live)
+                    step_sp.set_attr("attn_pages", step.pages)
                     for name, n in counts.items():
                         step_sp.set_attr(name, n)
                     # the step's device outputs die here, inside the
@@ -1751,6 +1758,9 @@ class GenerationPipeline:
         flight-recorder ``generation.json`` payload)."""
         slots = []
         tenants: dict = {}
+        # (choice, why) that the decode program's trace took, where the model
+        # chooses at trace time (``HybridLM.attention_backend``)
+        took = getattr(self.engine.model, "attention_backend", None)
         for i, req in enumerate(self._slot_req):
             if req is None:
                 slots.append({"slot": i, "state": "free"})
@@ -1817,6 +1827,7 @@ class GenerationPipeline:
                       "longest": self._join_longest},
             "max_len": self.engine.max_len,
             "prefill_buckets": list(self.engine.prefill_buckets),
+            "attention_backend": took and "%s: %s" % took,
             "sampler": {"kind": self.engine.sampler.kind,
                         "top_k": self.engine.sampler.top_k,
                         "temperature": self.engine.sampler.temperature},

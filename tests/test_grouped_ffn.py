@@ -354,3 +354,36 @@ def test_decode_rows_compile_for_the_v5e_at_published_widths(
     assert len(calls) == 1 and "ragged" not in text
     assert re.search(r'op_name="[^"]*/moe_experts/jit\(_grouped_ffn\)/'
                      r'pallas_call"', calls[0]), calls[0][-400:]
+
+
+@pytest.mark.parametrize("cell", ["longcat-rollout", "kimilinear-longgen",
+                                  "the largest page that fits"])
+def test_paged_latent_attention_compiles_for_the_v5e_at_published_shapes(
+        cell, one_chip):
+    """The decode step's other kernel (``kernels/paged_latent_attention.py``;
+    its own tests are ``tests/test_paged_latent_attention.py``, this file is
+    the one that loads the TPU's compiler): 64 slots of 64 heads and 32
+    pages a slot (``longcat-rollout``) and of 32 heads and 80 pages
+    (``kimilinear-longgen``), rows of 640, pages of 64, the output 512 wide,
+    bfloat16: Mosaic takes the kernel (its copies, its double buffer, its
+    loop) at eight pages a visit. And at the largest visit that
+    ``fits_vmem`` admits, a window of one page of thousands of rows: what
+    the chooser lets through, the chip's 16 MiB of scoped VMEM hold."""
+    from deeplearning4j_tpu.kernels import paged_latent_attention as pla
+    heads, pages, P = {"longcat-rollout": (64, 32, 64),
+                       "kimilinear-longgen": (32, 80, 64),
+                       "the largest page that fits": (64, 1, max(
+                           n for n in range(64, 8192, 64)
+                           if pla.fits_vmem(64, 640, n, 1, 2)))}[cell]
+    visit = pla.visit_pages(P, 640, 2, pages)
+    assert visit == (8 if P == 64 else 1) and (P == 64 or P > 2048)
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(lambda q, pool, tables, pos: pla._paged_latent_attention(
+        q, pool, tables, pos, 512, 192 ** -0.5, visit, False)).lower(
+        s(64, heads, 640), s(64 * pages + 1, P, 640),
+        s(64, pages, dtype=jnp.int32), s(64, dtype=jnp.int32)
+    ).compile().as_text()
+    assert len([line for line in text.splitlines()
+                if "tpu_custom_call" in line]) == 1
