@@ -15,7 +15,9 @@ one row tile a decode step's rows are), and a fourth, the paged latent
 attention of a decode step (``kernels/paged_latent_attention.py``, imported
 the same way: one query a slot against the slot's live pages of latent rows,
 fetched from the pool where they lie by the kernel's own asynchronous copies,
-eight pages a visit, with an online softmax; no gathered window).
+eight pages a visit, with an online softmax; no gathered window; the same
+walk with two products a key/value head serves grouped-query rows ``[k heads
+| v heads]``, ``paged_grouped_attention``).
 """
 from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
                                                         flash_attention_bthd)

@@ -26,10 +26,14 @@ window is live (PERF.md, PR 42). Here:
   float32 accumulator (H, R)), probabilities rounded to the rows' dtype
   before the second product, as the XLA spelling rounds them.
 
-What the two other paged attentions would change (``_gqa_decode``'s K/V rows,
-``TransformerLM``'s per-layer K and V pools) is the row's width and the
-product of one visit (:func:`_attend`); the page walk is the same.
-Interpret mode off the TPU (only there) so the tests run the same code.
+The same walk serves the grouped-query attention of a decode step
+(``HybridLM._gqa_decode``, :func:`paged_grouped_attention`): a cached row is
+``[k heads | v heads]``, so a visit makes two products a key/value head
+(:func:`_attend_grouped`) where the latent attention makes two in all; the
+queries of a key/value head are padded to whole sublane tiles. What
+``TransformerLM``'s per-layer K and V pools would change is again the row and
+the product of one visit. Interpret mode off the TPU (only there) so the
+tests run the same code.
 """
 from __future__ import annotations
 
@@ -56,26 +60,42 @@ VISIT_BYTES = 640 << 10
 VMEM_BYTES = 12 << 20
 
 
-def visit_pages(page_tokens: int, row: int, itemsize: int, pages: int) -> int:
-    """Pages a visit: as many as ``VISIT_BYTES`` hold, within the window, and
+#: a visit of the grouped-query walk: a 64-row page of [k | v] on 8 heads of
+#: 128 is 256 KB, so four of them, 1.3 us of HBM time a visit
+GROUPED_VISIT_BYTES = 1 << 20
+
+
+def visit_pages(page_tokens: int, row: int, itemsize: int, pages: int,
+                visit_bytes: int = VISIT_BYTES) -> int:
+    """Pages a visit: as many as ``visit_bytes`` hold, within the window, and
     one page where a page alone is more."""
-    return max(1, min(pages, VISIT_BYTES // (page_tokens * row * itemsize)))
+    return max(1, min(pages, visit_bytes // (page_tokens * row * itemsize)))
 
 
 def vmem_bytes(heads: int, row: int, page_tokens: int, pages: int,
-               itemsize: int = 2) -> int:
+               itemsize: int = 2, visit_bytes: int = VISIT_BYTES) -> int:
     """What a slot's walk holds: the two halves of the buffer, the half in
     use as the two products read it, and a visit's scores, their exponentials
     (float32) and the probabilities as the rows' type."""
-    n = visit_pages(page_tokens, row, itemsize, pages) * page_tokens
+    n = visit_pages(page_tokens, row, itemsize, pages,
+                    visit_bytes) * page_tokens
     return 3 * n * row * itemsize + heads * n * (4 + 4 + itemsize)
 
 
 def fits_vmem(heads: int, row: int, page_tokens: int, pages: int,
-              itemsize: int = 2) -> bool:
+              itemsize: int = 2, visit_bytes: int = VISIT_BYTES) -> bool:
     """Whether a visit fits: a visit is no less than a page, so a page of
     thousands of rows does not."""
-    return vmem_bytes(heads, row, page_tokens, pages, itemsize) <= VMEM_BYTES
+    return vmem_bytes(heads, row, page_tokens, pages, itemsize,
+                      visit_bytes) <= VMEM_BYTES
+
+
+def grouped_query_rows(per_group: int, itemsize: int) -> int:
+    """Rows the queries of one key/value head take in the kernel: their
+    count rounded up to whole sublane tiles of the rows' type (8 rows of 4
+    bytes, 16 of 2), so that a head's slice of the scores is whole tiles."""
+    tile = 8 * max(1, 4 // itemsize)
+    return -(-per_group // tile) * tile
 
 
 def _attend(q, rows, live, scale, carry, out_width):
@@ -94,11 +114,37 @@ def _attend(q, rows, live, scale, carry, out_width):
     return m_new, l, acc
 
 
+def _attend_grouped(q, rows, live, scale, carry, groups):
+    """One visit of the grouped-query walk: q (groups * n_q, hd), the
+    queries of key/value head g in rows ``g n_q ..``; ``rows`` (n, 2 groups
+    hd), ``[k heads | v heads]``; ``live`` (1, n) -> the new (maximum, sum
+    (groups * n_q, 1), accumulator (groups * n_q, hd)). Two products a
+    key/value head, each on that head's lanes of the one copy of the page."""
+    m, l, acc = carry
+    hd, n_q = q.shape[1], q.shape[0] // groups
+    s = jnp.concatenate([lax.dot_general(
+        q[g * n_q:(g + 1) * n_q], rows[:, g * hd:(g + 1) * hd], _NT,
+        preferred_element_type=jnp.float32) for g in range(groups)], axis=0)
+    s = jnp.where(live, scale * s, _NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+    p = p.astype(rows.dtype)
+    acc = alpha * acc + jnp.concatenate([jnp.dot(
+        p[g * n_q:(g + 1) * n_q],
+        rows[:, (groups + g) * hd:(groups + g + 1) * hd],
+        preferred_element_type=jnp.float32) for g in range(groups)], axis=0)
+    return m_new, l, acc
+
+
 def _kernel(tables_ref, pos_ref, q_ref, pool_ref, o_ref, buf, sem, side0_ref,
-            *, page_tokens, visit, pages, scale):
+            *, page_tokens, visit, pages, attend):
     """Grid cell = one slot. ``buf`` (2, visit * P, row) is the double
     buffer, ``sem`` one DMA semaphore a half, ``side0_ref`` the half this
-    slot's first visit was fetched into (by the slot before it)."""
+    slot's first visit was fetched into (by the slot before it).
+    ``attend(q, rows, live, carry)`` is one visit's part of the online
+    softmax."""
     P, G = page_tokens, visit
     b, n_slots = pl.program_id(0), pl.num_programs(0)
     out_width = o_ref.shape[-1]
@@ -149,7 +195,7 @@ def _kernel(tables_ref, pos_ref, q_ref, pool_ref, o_ref, buf, sem, side0_ref,
 
         fetch(b, v, side, False)
         at = v * (G * P) + lax.broadcasted_iota(jnp.int32, (1, G * P), 1)
-        return _attend(q, buf[side], at <= pos, scale, carry, out_width)
+        return attend(q, buf[side], at <= pos, carry)
 
     H = q.shape[0]
     _m, l, acc = lax.fori_loop(0, visits, one, (
@@ -160,12 +206,11 @@ def _kernel(tables_ref, pos_ref, q_ref, pool_ref, o_ref, buf, sem, side0_ref,
     side0_ref[0] = (side0 + visits) % 2
 
 
-# jitted, so that a program's latent layers share ONE traced and lowered kernel
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _paged_latent_attention(q, pool, tables, positions, out_width, scale,
-                            visit, interpret):
-    B, H, row = q.shape
-    _n, P, _row = pool.shape
+def _walk(q, pool, tables, positions, out_width, attend, visit, interpret):
+    """The page walk for q (B, H, width) against ``pool`` (pages, P, row):
+    (B, H, out_width)."""
+    B, H, width = q.shape
+    _n, P, row = pool.shape
     pages = tables.shape[1]
 
     def slot(b, *_scalars):
@@ -173,11 +218,11 @@ def _paged_latent_attention(q, pool, tables, positions, out_width, scale,
 
     return pl.pallas_call(
         functools.partial(_kernel, page_tokens=P, visit=visit, pages=pages,
-                          scale=scale),
+                          attend=attend),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
-            in_specs=[pl.BlockSpec((None, H, row), slot),
+            in_specs=[pl.BlockSpec((None, H, width), slot),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, H, out_width), slot),
             scratch_shapes=[pltpu.VMEM((2, visit * P, row), pool.dtype),
@@ -190,6 +235,27 @@ def _paged_latent_attention(q, pool, tables, positions, out_width, scale,
         interpret=interpret,
     )(tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32), q,
       pool)
+
+
+# jitted, so that a program's latent layers share ONE traced and lowered kernel
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _paged_latent_attention(q, pool, tables, positions, out_width, scale,
+                            visit, interpret):
+    def attend(q, rows, live, carry):
+        return _attend(q, rows, live, scale, carry, out_width)
+
+    return _walk(q, pool, tables, positions, out_width, attend, visit,
+                 interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _paged_grouped_attention(q, pool, tables, positions, groups, scale,
+                             visit, interpret):
+    def attend(q, rows, live, carry):
+        return _attend_grouped(q, rows, live, scale, carry, groups)
+
+    return _walk(q, pool, tables, positions, q.shape[-1], attend, visit,
+                 interpret)
 
 
 def paged_latent_attention(q, pool, tables, positions, out_width: int,
@@ -215,3 +281,26 @@ def paged_latent_attention(q, pool, tables, positions, out_width: int,
     return _paged_latent_attention(q, pool, tables, positions, out_width,
                                    float(scale), visit,
                                    jax.default_backend() != "tpu")
+
+
+def paged_grouped_attention(q, pool, tables, positions, scale: float):
+    """q (B, G, K, hd): K query heads on each of G key/value heads; ``pool``
+    (pages, P, 2 G hd), a row ``[k heads | v heads]``; ``tables`` and
+    ``positions`` as :func:`paged_latent_attention` takes them -> (B, G, K,
+    hd) in q's dtype: ``softmax(scale * q . k) @ v`` a head over the slot's
+    live rows, read where they lie, each once."""
+    B, G, K, hd = q.shape
+    if pool.ndim != 3 or pool.shape[2] != 2 * G * hd \
+            or pool.dtype != q.dtype or tables.shape[0] != B \
+            or positions.shape != (B,):
+        raise ValueError(f"q {q.shape} {q.dtype}, pool {pool.shape} "
+                         f"{pool.dtype}, tables {tables.shape} and positions "
+                         f"{positions.shape} are not one paged layer")
+    n_q = grouped_query_rows(K, pool.dtype.itemsize)
+    visit = visit_pages(pool.shape[1], pool.shape[2], pool.dtype.itemsize,
+                        tables.shape[1], GROUPED_VISIT_BYTES)
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, n_q - K), (0, 0)))
+    o = _paged_grouped_attention(
+        qp.reshape(B, G * n_q, hd), pool, tables, positions, G, float(scale),
+        visit, jax.default_backend() != "tpu")
+    return o.reshape(B, G, n_q, hd)[:, :, :K]

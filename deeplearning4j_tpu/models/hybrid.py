@@ -5,7 +5,8 @@ short convolution; ``mla``: latent attention, its rope dimensions rotated
 where the configuration gives a ``rope_theta`` and carried unrotated where
 not, its queries behind a bottleneck where it gives a ``q_lora_rank``;
 ``mamba2``: a state-space layer with a scalar decay a head; ``gqa``: softmax
-attention with grouped key/value heads and no positions) and its feed-forward
+attention with grouped key/value heads over every earlier position; ``swa``:
+the same attention over the last ``swa_window`` positions) and its feed-forward
 (``dense`` SwiGLU, or ``moe``: routed experts of which this chip holds a
 share, ``parallel/moe.py::routed_experts_ffn``). Either may be absent: a layer
 is then ONE pre-norm residual part, a mixer alone or a feed-forward alone,
@@ -14,9 +15,14 @@ with one norm. A layer that is more than that lists its ``parts``
 parameters, the norm it reads through (or the rows the part before it read)
 and where its result lands - at once, or after the layer's last part (a
 shortcut around what lies between: the double layer whose expert layer reads
-the first feed-forward's rows and is added behind the second). RMSNorm, no
-position table, untied head. Parameters are held in ``param_dtype`` and
-computed with as they are: nothing is cast per call.
+the first feed-forward's rows and is added behind the second). The two
+grouped-query kinds share their key/value heads and head size and have, each
+of its own, a head count, a rotation (:class:`Rope`: none, plain, or YaRN's
+scaled frequencies; on all of a head or on its leading dimensions; an
+amplitude factor) and a head-wise sigmoid gate on the attention's output;
+keys are rotated once, at their absolute position, before they are cached.
+RMSNorm, no position table, untied head. Parameters are held in
+``param_dtype`` and computed with as they are: nothing is cast per call.
 
 Three spellings of the same mathematics:
 
@@ -37,22 +43,28 @@ block of methods under "cache protocol" below; ``TransformerLM`` implements
 the same block. **Mixer kinds own their cache leaves**: ``MIXERS`` holds, for
 every kind, the leaves a layer of it keeps - paged rows (one pool a layer,
 pages shared through the engine's allocator and tables: ``latent``, ``kv``)
-or a fixed state a slot (``kda_s``, ``kda_conv``, ``ssm_s``, ``ssm_conv``),
-shape and dtype - and its full and one-step functions. The protocol's methods
-walk the layers over that table and name no leaf; each layer's array is a
-leaf of the cache of its own, so that a step rewrites it in place.
+or a fixed state a slot (``kda_s``, ``kda_conv``, ``ssm_s``, ``ssm_conv``,
+and ``swa_kv``: a window layer's last ``swa_window`` rows, a ring written at
+``position % swa_window``, so that what it holds a slot does not grow with
+the slot's context), shape and dtype - and its full and one-step functions.
+The protocol's methods walk the layers over that table and name no leaf; each
+layer's array is a leaf of the cache of its own, so that a step rewrites it
+in place.
 
 Named scopes: the outer names are the fixed vocabulary of
 ``models/transformer.py`` (``embed``, ``ln``, ``attn_qkv``, ``attn_core``,
 ``attn_out``, ``mlp``, ``head``, ``kv_write``, ``kv_gather``); inside them
 ``kda_proj``, ``kda_conv``, ``kda_state``, ``kda_out``, ``mla_proj`` (and
 in it ``mla_rope``, the rotation), ``mla_attend``, ``ssm_proj``,
-``ssm_conv``, ``ssm_state``, ``ssm_out``, ``gqa_proj``, ``gqa_attend``,
+``ssm_conv``, ``ssm_state``, ``ssm_out``, ``gqa_proj`` (and in it
+``gqa_rope``, the rotation, and ``attn_gate``, the output gate's scalars),
+``gqa_attend``, ``swa_proj`` (with ``swa_rope`` and ``attn_gate``),
+``swa_attend``, ``swa_write`` (the ring's row, under ``kv_write``),
 ``ffn_dense`` (a dense feed-forward) and, from the expert layer,
 ``moe_route``, ``moe_experts``, ``moe_shared``, ``moe_combine`` (and in it
 ``moe_zero``, the identity experts' weighted copy), ``moe_latent``. One log
-line a trace, ``layer kinds: ...``, names the layers' parts, the experts'
-form and the router.
+line a trace, ``layer kinds: ...``, names the layers' parts, each attention
+kind's heads, rotation, gate and window, the experts' form and the router.
 """
 from __future__ import annotations
 
@@ -63,6 +75,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.parallel.moe import (EXPERT_FORMS,
@@ -128,6 +141,61 @@ class LayerSpec:
             raise ValueError(f"unknown layer {self}")
 
 
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """The rotary positions of one kind of grouped-query attention: the
+    leading ``dims`` dimensions of every query and key head (None: all of
+    them) are rotated in adjacent pairs (2i, 2i + 1) by ``position *
+    inv_freq[i]`` and multiplied by ``amplitude``; the rest of a head is
+    carried as it is. ``inv_freq[i] = theta^(-2i / dims)``, or, where YaRN's
+    four numbers are given (all or none), that frequency blended with
+    itself over ``factor`` along YaRN's ramp between the dimensions that
+    turn ``beta_fast`` and ``beta_slow`` times in ``original`` positions.
+    Static: the same frequencies at every position."""
+
+    theta: float
+    dims: Optional[int] = None
+    amplitude: float = 1.0
+    factor: Optional[float] = None
+    original: Optional[int] = None
+    beta_fast: Optional[float] = None
+    beta_slow: Optional[float] = None
+
+    def __post_init__(self):
+        yarn = (self.factor, self.original, self.beta_fast, self.beta_slow)
+        if any(v is not None for v in yarn) and None in yarn:
+            raise ValueError("a YaRN rotation needs its four numbers: "
+                             f"factor, original, beta_fast, beta_slow {yarn}")
+        if self.dims is not None and (self.dims <= 0 or self.dims % 2):
+            raise ValueError(f"{self.dims} dimensions cannot rotate in pairs")
+
+    def ramp(self, d: int):
+        """(low, high): the pairs between which YaRN's ramp rises 0 -> 1."""
+        def corr(turns):
+            return d * math.log(self.original / (2 * math.pi * turns)) \
+                / (2 * math.log(self.theta))
+        return (max(math.floor(corr(self.beta_fast)), 0),
+                min(math.ceil(corr(self.beta_slow)), d - 1))
+
+    def inv_freq(self, d: int) -> np.ndarray:
+        """float32 (d / 2,): the angle a position of pair i."""
+        i = np.arange(d // 2, dtype=np.float64)
+        f = self.theta ** (-2.0 * i / d)
+        if self.factor is not None:
+            low, high = self.ramp(d)
+            ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+            f = f * (1.0 - ramp) + f / self.factor * ramp
+        return f.astype(np.float32)
+
+    def say(self, head_dim: int) -> str:
+        d = self.dims or head_dim
+        return ("%s theta %g on %d of %d" % (
+            "plain" if self.factor is None else
+            "yarn x%g from %d" % (self.factor, self.original),
+            self.theta, d, head_dim)
+            + ("" if self.amplitude == 1.0 else " x%.4f" % self.amplitude))
+
+
 @dataclasses.dataclass
 class HybridConfig:
     vocab_size: int
@@ -165,6 +233,14 @@ class HybridConfig:
     gqa_heads: int = 32
     gqa_kv_heads: int = 2           # query head i on kv head i // (Hq / Hkv)
     gqa_head_dim: int = 128
+    gqa_rope: Optional[Rope] = None       # None: no positions
+    gqa_gated: bool = False         # a sigmoid gate a head on the output
+    #: the window kind (``swa``): on the same key/value heads and head size,
+    #: its own head count, rotation and gate, and the positions it reads
+    swa_heads: int = 32
+    swa_rope: Optional[Rope] = None
+    swa_gated: bool = False
+    swa_window: Optional[int] = None
     dense_ff: int = 9216
     expert_ff: int = 1024
     shared_ff: Optional[int] = None       # None: expert_ff
@@ -183,6 +259,15 @@ class HybridConfig:
         if self.ssm_heads % self.ssm_groups \
                 or self.gqa_heads % self.gqa_kv_heads:
             raise ValueError("heads must divide into their groups")
+        if any(p.kind == "swa" for s in self.layers for p in s.parts):
+            if not self.swa_window or self.swa_window < 1:
+                raise ValueError("a window layer needs `swa_window`")
+            if self.swa_heads % self.gqa_kv_heads:
+                raise ValueError("heads must divide into their groups")
+        for rope in (self.gqa_rope, self.swa_rope):
+            if rope is not None and (rope.dims or 0) > self.gqa_head_dim:
+                raise ValueError(f"{rope.dims} rotated dimensions are more "
+                                 f"than a head's {self.gqa_head_dim}")
 
     @property
     def n_layers(self) -> int:
@@ -214,6 +299,12 @@ class HybridConfig:
     def gqa_kv_row(self) -> int:
         """A cached row of a grouped-query layer: [k heads | v heads]."""
         return 2 * self.gqa_kv_heads * self.gqa_head_dim
+
+    def attention(self, kind: str):
+        """(heads, rotation, gated, window) of a grouped-query kind."""
+        if kind == "gqa":
+            return self.gqa_heads, self.gqa_rope, self.gqa_gated, None
+        return self.swa_heads, self.swa_rope, self.swa_gated, self.swa_window
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,6 +357,26 @@ def _rope(x, positions, theta: float):
     other = jnp.where(lane % 2 == 0, -jnp.roll(x, -1, axis=-1),
                       jnp.roll(x, 1, axis=-1))
     return x * jnp.cos(ang) + other * jnp.sin(ang)
+
+
+def _rotate(x, positions, rope: Rope):
+    """x (..., hd) float32 at ``positions`` (broadcast against x's leading
+    axes) -> float32: ``rope``'s leading dimensions of the last axis turned
+    in adjacent pairs (:func:`_rope`'s lanes) by its own frequencies, times
+    its amplitude; the rest of the axis as it is."""
+    hd = x.shape[-1]
+    r = rope.dims or hd
+    lane = np.arange(r)
+    ang = positions[..., None].astype(jnp.float32) \
+        * jnp.asarray(rope.inv_freq(r)[lane // 2])
+    head, rest = x[..., :r].astype(jnp.float32), x[..., r:]
+    other = jnp.where(lane % 2 == 0, -jnp.roll(head, -1, axis=-1),
+                      jnp.roll(head, 1, axis=-1))
+    head = head * jnp.cos(ang) + other * jnp.sin(ang)
+    if rope.amplitude != 1.0:
+        head = head * rope.amplitude
+    return head if r == hd else jnp.concatenate(
+        [head, rest.astype(jnp.float32)], axis=-1)
 
 
 # ------------------------------------------- the short causal convolution
@@ -453,9 +564,26 @@ def _slot_page(tables, positions, page_tokens: int, n_pages: int):
         n_pages - 1)
 
 
+def _ring_live(window: int, positions):
+    """(B, window): the rows of a slot's ring that hold a position of its
+    context once the row of ``positions`` is written. Row j holds the latest
+    position p <= positions with p % window == j: every row once the slot is
+    as old as the window, rows 0 .. positions of a younger one."""
+    return jnp.arange(window)[None, :] <= positions[:, None]
+
+
 def _on_tpu() -> bool:
     """Whether the program is traced for the TPU."""
     return jax.default_backend() == "tpu"
+
+
+#: the inner scopes of the two grouped-query kinds, ``<kind>_<what>``
+_ATTN_SCOPES = ("proj", "rope", "attend")
+
+
+def _scope(kind: str, what: str) -> str:
+    assert what in _ATTN_SCOPES, what
+    return f"{kind}_{what}"
 
 
 def latent_attention_backend(heads: int, row: int, out_width: int,
@@ -486,6 +614,49 @@ def latent_attention_backend(heads: int, row: int, out_width: int,
                           "more than the kernel's VMEM")
     return "paged-latent", (f"live pages of {page_tokens} rows of {row} read "
                             "where they lie")
+
+
+#: the gathered view of every slot's whole window that a grouped-query layer
+#: of a decode step may still make; a larger one is never made on the TPU
+#: (at 64 slots of 7,168 rows of 4 KB it is 1.9 GB a layer a step, which the
+#: chip has not got beside a deployment's cache; PERF.md, PR 43)
+GATHER_VIEW_BYTES = 1 << 30
+
+
+def grouped_attention_backend(slots: int, heads: int, kv_heads: int,
+                              head_dim: int, page_tokens: int, pages: int,
+                              itemsize: int) -> Tuple[str, str]:
+    """(``paged-grouped`` | ``gather``, why) for the grouped-query attention
+    of a decode step: ``heads`` queries a slot on ``kv_heads`` key/value
+    heads against ``pages`` pages of ``page_tokens`` rows ``[k heads | v
+    heads]``. The Pallas kernel that walks a slot's live pages where the
+    program is traced for the TPU, a head is whole tiles of 128 lanes, a
+    page whole tiles of 8 rows, a visit fits the kernel's VMEM, and the
+    gathered view of all the windows would be more than
+    ``GATHER_VIEW_BYTES``; the gathered window everywhere else. Consulted at
+    trace time only, as :func:`latent_attention_backend` is."""
+    if not _on_tpu():
+        return "gather", f"on {jax.default_backend()}"
+    row = 2 * kv_heads * head_dim
+    if head_dim % 128:
+        return "gather", (f"a head of {head_dim} is not whole tiles of 128 "
+                          "lanes")
+    if page_tokens % 8:
+        return "gather", (f"a page of {page_tokens} rows is not whole tiles "
+                          "of 8 rows")
+    view = slots * pages * page_tokens * row * itemsize
+    if view <= GATHER_VIEW_BYTES:
+        return "gather", (f"the view of every slot's window is "
+                          f"{view / 2**20:.0f} MiB")
+    from deeplearning4j_tpu.kernels import paged_latent_attention as kernel
+    rows_q = kernel.grouped_query_rows(heads // kv_heads, itemsize) * kv_heads
+    visit = kernel.GROUPED_VISIT_BYTES
+    if not kernel.fits_vmem(rows_q, row, page_tokens, pages, itemsize, visit):
+        n = kernel.visit_pages(page_tokens, row, itemsize, pages, visit)
+        return "gather", (f"a visit of {n * page_tokens} rows of {row} is "
+                          "more than the kernel's VMEM")
+    return "paged-grouped", (f"live pages of {page_tokens} rows of {row} "
+                             "read where they lie")
 
 
 class HybridLM:
@@ -525,9 +696,12 @@ class HybridLM:
                   for leaf in MIXERS[kind].leaves(c)]
         self.cache_leaves = sorted(leaves, key=lambda ln: not ln[0].paged)
         self._said: Dict[str, Any] = {}
-        #: (choice, why) that the last trace of a paged latent attention
-        #: took (:func:`latent_attention_backend`), None before any
+        #: (choice, why) that the last trace of an attention over pages took
+        #: (:func:`latent_attention_backend`,
+        #: :func:`grouped_attention_backend`), None before any
         self.attention_backend: Optional[Tuple[str, str]] = None
+        #: positions a window layer reads and keeps a slot, None without one
+        self.cache_window = c.swa_window if "swa" in seen else None
 
     # ------------------------------------------------------------ params
     def init_params(self, key) -> Dict:
@@ -619,10 +793,12 @@ class HybridLM:
             "dt_bias": jnp.full((H,), -2.0, jnp.float32),
             "norm": ones(di), "w_out": w((di, d), resid)}
 
-    def _init_gqa(self, w, ones, resid):
+    def _init_gqa(self, w, ones, resid, kind="gqa"):
         c = self.config
-        d, hq = c.d_model, c.gqa_heads * c.gqa_head_dim
+        heads, _rope, gated, _window = c.attention(kind)
+        d, hq = c.d_model, heads * c.gqa_head_dim
         return {"w_q": w((d, hq)), "w_kv": w((d, c.gqa_kv_row)),  # [k | v]
+                **({"w_gate": w((d, heads))} if gated else {}),
                 "w_o": w((hq, d), resid)}
 
     # ------------------------------------------------------------ pieces
@@ -965,16 +1141,42 @@ class HybridLM:
             return self._ssm_out(p, y, x, z), s, tail
 
     # ------------------------------------------- grouped-query attention
-    def _gqa_project(self, p, h):
-        """h (..., d) -> q (..., Hkv, Hq / Hkv, hd) and the row the cache
-        keeps, [k heads | v heads] (..., 2 Hkv hd). No positions."""
+    # ``kind``: ``gqa``, over every earlier position, its rows in pages; or
+    # ``swa``, over the last ``swa_window``, its rows in a ring a slot.
+    def _gqa_project(self, p, h, kind="gqa", positions=None):
+        """h (..., d) at ``positions`` (broadcast against h's leading axes;
+        None: whole sequences, 0 .. T - 1 along the axis before the last)
+        -> q (..., Hkv, Hq / Hkv, hd), the row the cache keeps, [k heads |
+        v heads] (..., 2 Hkv hd), and the output gate's scalars (..., Hq)
+        float32, or None. Where the kind rotates, q and the row's keys are
+        rotated here, in float32 before they are rounded: the keys once, at
+        their own position, so a cached row never needs it again."""
         c = self.config
-        g = c.gqa_kv_heads
-        with jax.named_scope("gqa_proj"):
-            q = _mm(h, p["w_q"]).astype(c.dtype).reshape(
-                *h.shape[:-1], g, c.gqa_heads // g, c.gqa_head_dim)
-            row = _mm(h, p["w_kv"]).astype(c.dtype)
-        return q, row
+        heads, rope, gated, _window = c.attention(kind)
+        g, hd = c.gqa_kv_heads, c.gqa_head_dim
+        with jax.named_scope(_scope(kind, "proj")):
+            if rope is None:
+                q = _mm(h, p["w_q"]).astype(c.dtype).reshape(
+                    *h.shape[:-1], g, heads // g, hd)
+                row = _mm(h, p["w_kv"]).astype(c.dtype)
+            else:
+                if positions is None:
+                    positions = jnp.arange(h.shape[-2])
+                q = _mm(h, p["w_q"]).reshape(*h.shape[:-1], g, heads // g, hd)
+                kv = _mm(h, p["w_kv"])
+                k = kv[..., :g * hd].reshape(*h.shape[:-1], g, hd)
+                with jax.named_scope(_scope(kind, "rope")):
+                    q = _rotate(q, positions[..., None, None],
+                                rope).astype(c.dtype)
+                    k = _rotate(k, positions[..., None], rope)
+                row = jnp.concatenate(
+                    [k.reshape(*h.shape[:-1], g * hd), kv[..., g * hd:]],
+                    axis=-1).astype(c.dtype)
+            gate = None
+            if gated:
+                with jax.named_scope("attn_gate"):
+                    gate = jax.nn.sigmoid(_mm(h, p["w_gate"]))
+        return q, row, gate
 
     def _gqa_kv(self, rows):
         """(..., 2 Hkv hd) -> k, v (..., Hkv, hd)."""
@@ -983,64 +1185,147 @@ class HybridLM:
         shape = (*rows.shape[:-1], c.gqa_kv_heads, c.gqa_head_dim)
         return rows[..., :half].reshape(shape), rows[..., half:].reshape(shape)
 
-    def _gqa_full(self, p, h):
+    def _gqa_out(self, p, o, gate, kind):
+        """o (..., Hq hd) in ``dtype``: every head times its gate's scalar,
+        where the kind is gated, then the output projection."""
+        c = self.config
+        with jax.named_scope("attn_out"), jax.named_scope(
+                _scope(kind, "proj")):
+            if gate is not None:
+                with jax.named_scope("attn_gate"):
+                    o = (o.reshape(*gate.shape, c.gqa_head_dim)
+                         * gate[..., None]).astype(c.dtype).reshape(o.shape)
+            return _mm(o, p["w_o"]).astype(c.dtype)
+
+    def _gqa_full(self, p, h, kind="gqa"):
         """Causal softmax attention over (B, T, d) in blocks of queries.
+        A block scores every key (``gqa``) or the ``block + window`` keys it
+        can see (``swa``: a query at t reads keys t - window < j <= t).
         Returns (y, the rows of K and V (B, T, 2 Hkv hd))."""
         c = self.config
         B, T, _ = h.shape
         scale = c.gqa_head_dim ** -0.5
+        window = c.attention(kind)[3]
         with jax.named_scope("attn_qkv"):
-            q, row = self._gqa_project(p, h)
+            q, row, gate = self._gqa_project(p, h, kind)
             k, v = self._gqa_kv(row)
-        with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+        with jax.named_scope("attn_core"), jax.named_scope(
+                _scope(kind, "attend")):
             bq = min(_QUERY_BLOCK, T)
             pad = -T % bq
             nb = (T + pad) // bq
             qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
             qb = qb.reshape(B, nb, bq, *q.shape[2:]).swapaxes(0, 1)
+            if window:
+                # keys i0 - window .. i0 + bq - 1 of a block that starts at
+                # i0: ``window`` rows of zeros in front, ``pad`` behind
+                span = ((0, 0), (window, pad), (0, 0), (0, 0))
+                k, v = jnp.pad(k, span), jnp.pad(v, span)
 
             def one(args):
                 q_b, i0 = args
-                s = jnp.einsum("bqgkd,btgd->bgkqt", q_b, k,
+                k_b, v_b = k, v
+                if window:
+                    k_b, v_b = (lax.dynamic_slice_in_dim(a, i0, bq + window,
+                                                         axis=1)
+                                for a in (k, v))
+                s = jnp.einsum("bqgkd,btgd->bgkqt", q_b, k_b,
                                preferred_element_type=jnp.float32) * scale
-                ok = (i0 + jnp.arange(bq))[:, None] >= jnp.arange(T)[None, :]
+                at = (i0 + jnp.arange(bq))[:, None]
+                if window:
+                    j = (i0 - window + jnp.arange(bq + window))[None, :]
+                    ok = (j <= at) & (j > at - window) & (j >= 0)
+                else:
+                    ok = at >= jnp.arange(T)[None, :]
                 pr = jax.nn.softmax(jnp.where(ok, s, -1e30),
                                     axis=-1).astype(c.dtype)
-                return jnp.einsum("bgkqt,btgd->bqgkd", pr, v,
+                return jnp.einsum("bgkqt,btgd->bqgkd", pr, v_b,
                                   preferred_element_type=jnp.float32
                                   ).astype(c.dtype)
 
             o = lax.map(one, (qb, jnp.arange(nb) * bq))
             o = o.swapaxes(0, 1).reshape(B, T + pad, -1)[:, :T]
-        with jax.named_scope("attn_out"), jax.named_scope("gqa_proj"):
-            return _mm(o, p["w_o"]).astype(c.dtype), row
+        return self._gqa_out(p, o, gate, kind), row
+
+    def _swa_full(self, p, h, last_idx):
+        """The window kind over (B, T, d). Returns (y, the ring as it stands
+        at the prompt's TRUE last token (B, window, 2 Hkv hd): the row of
+        position t at ``t % window`` for the last ``window`` positions up to
+        ``last_idx``, zeros where the prompt is shorter)."""
+        W = self.config.swa_window
+        y, row = self._gqa_full(p, h, "swa")
+        with jax.named_scope("kv_write"), jax.named_scope("swa_write"):
+            j = jnp.arange(W)
+            at = last_idx - (last_idx - j) % W
+            ring = jnp.where((at >= 0)[None, :, None],
+                             jnp.take(row, jnp.maximum(at, 0), axis=1), 0)
+        return y, ring
 
     def _gqa_decode(self, p, h, pool, tables, positions, page_tokens):
         """h (B, d) against the slot's pages of K and V rows. The step's own
-        row is written first, then read back with the rest."""
+        row is written first, then read back with the rest: by the kernel
+        that walks the slot's live pages where they lie
+        (``kernels/paged_latent_attention.py::paged_grouped_attention``) or,
+        everywhere :func:`grouped_attention_backend` does not take it, over
+        a gathered view of every slot's whole window."""
         c = self.config
         B = h.shape[0]
         P = int(page_tokens)
         S = tables.shape[1] * P
+        scale = c.gqa_head_dim ** -0.5
         with jax.named_scope("attn_qkv"):
-            q, row = self._gqa_project(p, h)
+            q, row, gate = self._gqa_project(p, h, "gqa", positions)
         with jax.named_scope("kv_write"):
             page = _slot_page(tables, positions, P, pool.shape[0])
             pool = pool.at[page, positions % P].set(row)
-        with jax.named_scope("kv_gather"):
-            k, v = self._gqa_kv(pool.at[tables].get(
-                mode="promise_in_bounds").reshape(B, S, c.gqa_kv_row))
-        with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+        self.attention_backend = grouped_attention_backend(
+            B, c.gqa_heads, c.gqa_kv_heads, c.gqa_head_dim, P,
+            tables.shape[1], jnp.dtype(c.dtype).itemsize)
+        self._say_once("attention backend", *self.attention_backend)
+        if self.attention_backend[0] == "paged-grouped":
+            from deeplearning4j_tpu.kernels.paged_latent_attention import \
+                paged_grouped_attention
+            with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+                o = paged_grouped_attention(q, pool, tables, positions, scale)
+        else:
+            with jax.named_scope("kv_gather"):
+                k, v = self._gqa_kv(pool.at[tables].get(
+                    mode="promise_in_bounds").reshape(B, S, c.gqa_kv_row))
+            with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+                s = jnp.einsum("bgkd,bsgd->bgks", q, k,
+                               preferred_element_type=jnp.float32) * scale
+                live = jnp.arange(S)[None, :] <= positions[:, None]
+                pr = jax.nn.softmax(jnp.where(live[:, None, None, :], s,
+                                              -1e30), axis=-1).astype(c.dtype)
+                o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
+                               preferred_element_type=jnp.float32
+                               ).astype(c.dtype)
+        return self._gqa_out(p, o.reshape(B, -1), gate, "gqa"), pool
+
+    def _swa_decode(self, p, h, ring, positions):
+        """h (B, d) against the slot's ring of K and V rows (B, window,
+        2 Hkv hd). The step's own row goes to ``position % window``, over
+        the row that has just left the window; the ring is then read whole,
+        under a mask for a slot younger than the window. Every key carries
+        its own position's rotation, so the ring's order does not matter."""
+        c = self.config
+        W = c.swa_window
+        with jax.named_scope("attn_qkv"):
+            q, row, gate = self._gqa_project(p, h, "swa", positions)
+        with jax.named_scope("kv_write"), jax.named_scope("swa_write"):
+            ring = ring.at[jnp.arange(h.shape[0]), positions % W].set(row)
+        with jax.named_scope("attn_core"), jax.named_scope("swa_attend"):
+            k, v = self._gqa_kv(ring)
             s = jnp.einsum("bgkd,bsgd->bgks", q, k,
                            preferred_element_type=jnp.float32) \
                 * c.gqa_head_dim ** -0.5
-            live = jnp.arange(S)[None, :] <= positions[:, None]
+            live = _ring_live(W, positions)
             pr = jax.nn.softmax(jnp.where(live[:, None, None, :], s, -1e30),
                                 axis=-1).astype(c.dtype)
             o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
                            preferred_element_type=jnp.float32).astype(c.dtype)
-        with jax.named_scope("attn_out"), jax.named_scope("gqa_proj"):
-            return _mm(o.reshape(B, -1), p["w_o"]).astype(c.dtype), pool
+        return self._gqa_out(p, o.reshape(h.shape[0], -1), gate,
+                             "swa"), ring
 
     # ------------------------------------------------------ full forward
     def _say_once(self, subject, choice, why):
@@ -1071,6 +1356,14 @@ class HybridLM:
             + (f": {e.router_width - e.identity} experts + {e.identity} "
                f"identity" if e.identity else "")
             + f", {e.held[1]} held from {e.held[0]}")
+        present = {p.kind for s in c.layers for p in s.parts}
+        for kind in ("gqa", "swa"):
+            heads, rope, gated, window = c.attention(kind)
+            if kind in present and (rope or gated or window):
+                why += (f"; {kind} {heads} heads on {c.gqa_kv_heads}, "
+                        + (rope.say(c.gqa_head_dim) if rope else "unrotated")
+                        + (", gated" if gated else "")
+                        + (f", window {window}" if window else ""))
         self._say_once("layer kinds", kinds, why)
 
     def _layers(self, params, x, run):
@@ -1256,4 +1549,11 @@ MIXERS: Dict[str, MixerKind] = {
         lambda m, p, h, held, tables, pos, pt: m._gqa_decode(
             p, h, *held, tables, pos, pt),
         HybridLM._init_gqa),
+    "swa": MixerKind(
+        lambda c: (CacheLeaf("swa_kv", False, (c.swa_window, c.gqa_kv_row),
+                             c.dtype),),
+        lambda m, p, h, valid, last: m._swa_full(p, h, last),
+        lambda m, p, h, held, tables, pos, pt: m._swa_decode(p, h, *held,
+                                                             pos),
+        lambda m, w, ones, resid: m._init_gqa(w, ones, resid, "swa")),
 }

@@ -319,16 +319,19 @@ class _Flight:
     ``reqs`` is the slot table as the step saw it: a slot whose request
     has left or changed by the fetch was a row nobody reads."""
 
-    __slots__ = ("step", "outputs", "active", "reqs", "live", "pages")
+    __slots__ = ("step", "outputs", "active", "reqs", "live", "pages",
+                 "window_rows", "cache_bytes")
 
     def __init__(self, step: int, outputs, active: List[int], reqs: list,
-                 live: int, pages: int):
+                 live: int, pages: int, window_rows: int, cache_bytes: int):
         self.step = step
         self.outputs = outputs      # (tokens [+ counts], logits), on device
         self.active = active
         self.reqs = reqs
         self.live = live
         self.pages = pages
+        self.window_rows = window_rows
+        self.cache_bytes = cache_bytes
 
 
 class _GenRequest(_Request):
@@ -1495,10 +1498,18 @@ class GenerationPipeline:
         # slot's live pages visits (0 for a cache that has no pages)
         pt = self.engine.page_tokens
         pages = int((at // pt + 1).sum()) if pt else 0
+        # the rows a window layer reads, where the model has one: every
+        # active slot's last ``cache_window`` rows and no more
+        window = getattr(self.engine.model, "cache_window", None)
+        window_rows = int(np.minimum(at + 1, window).sum()) if window else 0
+        # what the step's cache holds: the pages handed out, in every layer
+        # that keeps pages, and every occupied slot's fixed state
+        cache_bytes = self.engine.resident_cache_bytes(self._cache)
         nxt, logits, self._cache = self.engine.decode(
             self._cache, tokens, self._positions.copy(), self._step)
         flying.append(_Flight(self._step, (nxt, logits), active,
-                              list(self._slot_req), live, pages))
+                              list(self._slot_req), live, pages,
+                              window_rows, cache_bytes))
         self._positions[[s for s in active if self._owed(s) > 0]] += 1
         self._step += 1
         self._steps_dispatched += 1
@@ -1627,6 +1638,8 @@ class GenerationPipeline:
                     step_sp.set_attr("active", len(step.active))
                     step_sp.set_attr("live_tokens", step.live)
                     step_sp.set_attr("attn_pages", step.pages)
+                    step_sp.set_attr("window_rows", step.window_rows)
+                    step_sp.set_attr("cache_bytes", step.cache_bytes)
                     for name, n in counts.items():
                         step_sp.set_attr(name, n)
                     # the step's device outputs die here, inside the

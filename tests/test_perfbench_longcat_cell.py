@@ -43,14 +43,14 @@ LATER = ["prefill_stall_pct.tput", "join_ms_per_ktok.tput",
 def test_benchmark_json_has_the_configuration_and_the_cell():
     bench = harness.benchmark()
     conf = [c for c in bench["configs"] if c["name"] == CONFIG]
-    assert len(conf) == 1 and bench["configs"][-1] is conf[0]
+    assert len(conf) == 1 and bench["configs"][4] is conf[0]
     assert conf[0]["file"] == f"perfbench/configs/{CONFIG}.json"
     assert conf[0]["reduced"] == ["num_layers", "n_routed_experts",
                                   "vocab_size"]
     assert conf[0]["source"].startswith(
         "https://huggingface.co/meituan-longcat/LongCat-Flash-Omni/")
     row = [w for w in bench["workloads"] if w["name"] == CELL]
-    assert row == [bench["workloads"][-1]] == [{
+    assert row == [bench["workloads"][6]] == [{
         "name": CELL, "config": CONFIG, "traffic": MIX, "chips": 1,
         "why": row[0]["why"]}]
     assert all(1 <= len(x["why"]) <= 200 for x in (conf[0], row[0]))
@@ -58,22 +58,28 @@ def test_benchmark_json_has_the_configuration_and_the_cell():
                 if CELL in m.get("workloads", [CELL])]
     assert reported == ACCEPTED[:1] + ["setup_s"] + ACCEPTED[2:] + OWN \
         + LATER
-    # appended: the cell stands last in every list it joined, and its own
+    # appended: the cell stood last in every list it joined (only later
+    # PRs' cells stand behind it: PR 43's ``laguna-codegen``), and its own
     # three metrics together behind every per-layer metric the benchmark
     # had when it came, with only later PRs' behind them
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL and m["moves"] == "serve_tok_s" \
-                if "moves" in m else m["workloads"][-1] == CELL
+            behind = m["workloads"][m["workloads"].index(CELL) + 1:]
+            assert behind in ([], ["laguna-codegen"])
+            assert m.get("moves", "serve_tok_s") == "serve_tok_s"
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(OWN[0])
     assert names[at:at + 3] == OWN
     assert [n for n in names[at + 3:] if n in reported] == LATER
+    assert names[at + 3 + len(LATER) + 1:] == [     # + join_hold_dev_pct.*
+        "join_hold_dev_pct.lat", "attn_window_dev_pct.tput",
+        "attn_full_dev_pct.tput", "attn_window_roofline_pct.tput",
+        "attn_full_roofline_pct.tput", "cache_bytes_per_live_token.tput"]
     assert [(m["source"], m["layer"])
             for m in bench["per_layer"][at:at + 3]] == [
         ("program_span", "engine"), ("device_trace", "model"),
         ("device_trace", "kernels")]
-    # one four-chip cell in seven: inside the quarter the contract allows
+    # one four-chip cell in eight: inside the quarter the contract allows
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

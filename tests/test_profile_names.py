@@ -408,23 +408,75 @@ def test_transformer_uses_the_vocabulary_and_nothing_else():
 
 def test_hybrid_and_the_expert_layer_use_the_vocabulary_and_inner_names():
     """``models/hybrid.py`` and ``parallel/moe.py`` name nothing outside the
-    fixed vocabulary and the inner names the benchmark's three readers know
+    fixed vocabulary and the inner names the benchmark's four readers know
     (``_inner.INNER``: PR 27's; ``_nemotron.NAMES``: the state-space and
     grouped-query layers and the experts' latent pair; ``_longcat.NAMES``:
-    the dense feed-forwards, the rotation and the identity experts' copy),
-    and use every one of the inner names."""
-    from perfbench.layer_metrics import _inner, _longcat, _nemotron
-    used = set()
+    the dense feed-forwards, the rotation and the identity experts' copy;
+    ``_laguna.NAMES``: the two grouped-query kinds' projection, rotation and
+    attend, the window kind's ring write and the output gate), and use every
+    one of the inner names. The two grouped-query kinds share their code, so
+    their ``<kind>_<what>`` names come from ``hybrid._scope``."""
+    from deeplearning4j_tpu.models import hybrid
+    from perfbench.layer_metrics import _inner, _laguna, _longcat, _nemotron
+    used = {hybrid._scope(kind, what) for kind in ("gqa", "swa")
+            for what in hybrid._ATTN_SCOPES}
     for rel in ("models/hybrid.py", "parallel/moe.py"):
         with open(os.path.join(ROOT, "deeplearning4j_tpu", rel)) as f:
             used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    inner = _inner.INNER | _nemotron.NAMES | _longcat.NAMES
+    inner = _inner.INNER | _nemotron.NAMES | _longcat.NAMES | _laguna.NAMES
     assert used <= _named.SCOPES | inner
     assert used >= inner
     assert _longcat.NAMES == {"ffn_dense", "mla_rope", "moe_zero"}
     assert _nemotron.NAMES == {
         "ssm_proj", "ssm_conv", "ssm_state", "ssm_out", "gqa_proj",
         "gqa_attend", "moe_latent"}
+    assert _laguna.NAMES == {
+        "gqa_proj", "gqa_rope", "gqa_attend", "swa_proj", "swa_rope",
+        "swa_attend", "swa_write", "attn_gate"}
+
+
+def test_laguna_decode_program_names_the_new_scopes_inside_the_vocabulary():
+    """The compiled decode step of a model with both grouped-query kinds,
+    rotated and gated: every operation under one of the new inner names also
+    sits under the vocabulary's scope for that part of the block (the ring's
+    write under ``kv_write``, so that ``kv_move`` counts it), and each
+    resolves to its kind."""
+    import json
+    from perfbench import harness
+    from perfbench.layer_metrics import _laguna
+    mod = harness.load_module("models", "laguna.py")
+    cfg = harness.load_json("configs", "laguna-xs2-33b-a3b-stage5.json")
+    cfg.update(cfg["rehearsal"])
+    model = mod.build_model(cfg)
+    shapes = mod.weight_shapes(cfg)
+    eng = DecodeEngine(model, shapes, max_len=cfg["n_positions"],
+                       prefill_buckets=[16], page_tokens=8)
+    cache = jax.eval_shape(lambda: model.new_paged_cache(4, 9, 8))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)     # noqa: E731
+    text = eng._decode_paged_jit.lower(
+        shapes, cache, i32(4, 16), i32(4), i32(4), i32()).compile().as_text()
+    outer = {"gqa_proj": {"attn_qkv", "attn_out"}, "gqa_rope": {"attn_qkv"},
+             "gqa_attend": {"attn_core"},
+             "swa_proj": {"attn_qkv", "attn_out"}, "swa_rope": {"attn_qkv"},
+             "swa_attend": {"attn_core"}, "swa_write": {"kv_write"},
+             "attn_gate": {"attn_qkv", "attn_out"}}
+    seen, kinds = {}, {}
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        kind, inner = _laguna.names_of(name)
+        if inner:
+            seen.setdefault(inner, set()).add(_named.scope_of(name))
+            kinds.setdefault(inner, set()).add(kind)
+    assert {k: v for k, v in seen.items() if k != "attn_gate"} == {
+        k: v for k, v in outer.items() if k != "attn_gate"}, json.dumps(
+        {k: sorted(map(str, v)) for k, v in seen.items()})
+    # the compiler may fuse a gate's few operations into its neighbours
+    assert seen.get("attn_gate", set()) <= outer["attn_gate"]
+    assert all(kinds[n] == {n[:3]} for n in outer if n != "attn_gate")
+    assert kinds.get("attn_gate", set()) <= {"gqa", "swa"}
+    found = {_named.scope_of(n)
+             for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert {"kv_write", "kv_gather", "mlp", "head", "embed", "ln"} <= found
+    assert found - {None} <= _named.SCOPES
 
 
 def test_hybrid_decode_program_names_the_new_scopes_inside_the_vocabulary():
