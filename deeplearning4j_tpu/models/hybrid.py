@@ -4,9 +4,12 @@ Each layer names its mixer (``kda``: gated delta-rule linear attention with a
 short convolution; ``mla``: latent attention, its rope dimensions rotated
 where the configuration gives a ``rope_theta`` and carried unrotated where
 not, its queries behind a bottleneck where it gives a ``q_lora_rank``;
-``mamba2``: a state-space layer with a scalar decay a head; ``gqa``: softmax
-attention with grouped key/value heads over every earlier position; ``swa``:
-the same attention over the last ``swa_window`` positions) and its feed-forward
+``mamba2``: a state-space layer with a scalar decay a head; ``mamba1``: one
+with a decay a channel and state dimension; ``gqa``: softmax attention with
+grouped key/value heads over every earlier position; ``swa``: the same
+attention over the last ``swa_window`` positions; ``xattn``: queries alone
+over the rows of an earlier ``gqa`` layer; ``gmu``: a gate on an earlier
+``mamba1`` layer's output of the same pass) and its feed-forward
 (``dense`` SwiGLU, or ``moe``: routed experts of which this chip holds a
 share, ``parallel/moe.py::routed_experts_ffn``). Either may be absent: a layer
 is then ONE pre-norm residual part, a mixer alone or a feed-forward alone,
@@ -21,8 +24,14 @@ of its own, a head count, a rotation (:class:`Rope`: none, plain, or YaRN's
 scaled frequencies; on all of a head or on its leading dimensions; an
 amplitude factor) and a head-wise sigmoid gate on the attention's output;
 keys are rotated once, at their absolute position, before they are cached.
-RMSNorm, no position table, untied head. Parameters are held in
-``param_dtype`` and computed with as they are: nothing is cast per call.
+They may be differential (heads in pairs, two softmax maps a pair over
+a value twice as wide, their difference normalised: computed as grouped-query
+attention on padded pairs, ``HybridConfig.attention_shape``) and may carry
+biases on their projections, both kinds alike. RMSNorm or LayerNorm
+(``norm``), no position table, an untied head or the embedding's transpose
+(``tie_embeddings``).
+Parameters are held in ``param_dtype`` and computed with as they are: nothing
+is cast per call.
 
 Three spellings of the same mathematics:
 
@@ -34,7 +43,8 @@ Three spellings of the same mathematics:
   of the prompt's last token and what the cache needs: the rows of every
   position for a paged layer and, for a recurrent layer, the state and the
   convolution's 3-row tail at the prompt's TRUE last token (rows beyond it
-  are identity updates).
+  are identity updates). Where the configuration names ``last_row_from``,
+  the layers from there on are run on that token's row alone.
 - :meth:`decode_paged` - one token a slot: the recurrent step on the slot's
   state, attention over the slot's pages.
 
@@ -49,22 +59,32 @@ and ``swa_kv``: a window layer's last ``swa_window`` rows, a ring written at
 the slot's context), shape and dtype - and its full and one-step functions.
 The protocol's methods walk the layers over that table and name no leaf; each
 layer's array is a leaf of the cache of its own, so that a step rewrites it
-in place.
+in place. **A kind may own nothing and read what an earlier part hands on**
+(``MixerKind.reads``; the part's ``source`` names the earlier part's
+``tag``): ``pages``, that part's paged rows - in a step its pool, the step's
+row already written, in a full pass the rows of every position - or ``side``,
+that part's side output of the same pass (``MixerKind.gives_side``). Such a
+layer adds no leaf: the engine allocates one pool for the layer that owns the
+rows and ``page_bytes`` counts it once, whatever reads it (``page_readers``).
 
 Named scopes: the outer names are the fixed vocabulary of
 ``models/transformer.py`` (``embed``, ``ln``, ``attn_qkv``, ``attn_core``,
 ``attn_out``, ``mlp``, ``head``, ``kv_write``, ``kv_gather``); inside them
 ``kda_proj``, ``kda_conv``, ``kda_state``, ``kda_out``, ``mla_proj`` (and
 in it ``mla_rope``, the rotation), ``mla_attend``, ``ssm_proj``,
-``ssm_conv``, ``ssm_state``, ``ssm_out``, ``gqa_proj`` (and in it
-``gqa_rope``, the rotation, and ``attn_gate``, the output gate's scalars),
-``gqa_attend``, ``swa_proj`` (with ``swa_rope`` and ``attn_gate``),
-``swa_attend``, ``swa_write`` (the ring's row, under ``kv_write``),
+``ssm_conv``, ``ssm_state``, ``ssm_out`` (either state-space kind's),
+``gqa_proj`` (and in it ``gqa_rope``, the rotation, and ``attn_gate``, the
+output gate's scalars), ``gqa_attend``, ``swa_proj`` (with ``swa_rope`` and
+``attn_gate``), ``swa_attend``, ``swa_write`` (the ring's row, under
+``kv_write``), ``xattn_proj``, ``xattn_attend``, ``attn_diff`` (the
+differential subtraction and its sub-norm, inside a kind's ``*_attend``),
+``gmu`` (the memory unit's two products and its gate),
 ``ffn_dense`` (a dense feed-forward) and, from the expert layer,
 ``moe_route``, ``moe_experts``, ``moe_shared``, ``moe_combine`` (and in it
 ``moe_zero``, the identity experts' weighted copy), ``moe_latent``. One log
-line a trace, ``layer kinds: ...``, names the layers' parts, each attention
-kind's heads, rotation, gate and window, the experts' form and the router.
+line a trace, ``layer kinds: ...``, names the layers' parts (what a part hands
+on behind ``=``, what it reads behind ``<``), each attention kind's heads,
+rotation, gate, form and window, the experts' form and the router.
 """
 from __future__ import annotations
 
@@ -90,6 +110,8 @@ _SUB = 16
 #: queries a block of the expanded attentions (their scores are
 #: heads x block x T float32)
 _QUERY_BLOCK = 512
+#: rows a step of the Mamba-1 prefill's scan
+_M1_CHUNK = 16
 
 
 _FFNS = ("dense", "moe")
@@ -102,12 +124,17 @@ class Part:
     ``norm``: the key of its RMSNorm gain there, or None where it reads the
     rows the part before it read (normalised once, by that part's gain).
     ``lands``: ``now``, its result is added to the stream at once, or
-    ``end``, behind the layer's last part."""
+    ``end``, behind the layer's last part. ``tag``: the name under which
+    LATER parts may read what this one hands on (the side output of its
+    kind, where the kind gives one; else its paged rows). ``source``: the
+    ``tag`` of the earlier part that a kind which ``reads`` takes it from."""
 
     kind: str
     name: str
     norm: Optional[str]
     lands: str = "now"
+    tag: Optional[str] = None
+    source: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +146,8 @@ class LayerSpec:
     mixer: Optional[str] = None     # a key of ``MIXERS``, or None
     ffn: Optional[str] = None       # "dense" | "moe" | None
     parts: Tuple[Part, ...] = ()
+    tag: Optional[str] = None       # the mixer's ``Part.tag``
+    source: Optional[str] = None    # the mixer's ``Part.source``
 
     def __post_init__(self):
         if not self.parts:
@@ -127,14 +156,17 @@ class LayerSpec:
                     or (self.mixer is None and self.ffn is None)):
                 raise ValueError(f"unknown layer {self}")
             object.__setattr__(self, "parts", tuple(
-                Part(kind, name, norm) for kind, name, norm in (
-                    (self.mixer, "mixer", "ln1"), (self.ffn, "ffn", "ln2"))
+                Part(kind, name, norm, "now", *given)
+                for kind, name, norm, given in (
+                    (self.mixer, "mixer", "ln1", (self.tag, self.source)),
+                    (self.ffn, "ffn", "ln2", ()))
                 if kind is not None))
             return
         parts = tuple(self.parts)
         object.__setattr__(self, "parts", parts)
         names = [p.name for p in parts] + [p.norm for p in parts if p.norm]
         if (self.mixer is not None or self.ffn is not None
+                or self.tag is not None or self.source is not None
                 or any(p.kind not in (*MIXERS, *_FFNS)
                        or p.lands not in ("now", "end") for p in parts)
                 or parts[0].norm is None or len(set(names)) != len(names)):
@@ -204,6 +236,15 @@ class HybridConfig:
     max_len: int                    # longest sequence a cache slot holds
     experts: Optional[RoutedExpertsConfig] = None
     rms_eps: float = 1e-5
+    #: every norm of the stream: ``rms`` (a gain) or ``layer`` (LayerNorm:
+    #: the mean taken off, a gain ``g`` and a bias ``b``), eps ``rms_eps``
+    norm: str = "rms"
+    #: the head is the embedding, transposed: the parameters hold no ``head``
+    tie_embeddings: bool = False
+    #: the first layer that a prefill runs on the prompt's LAST row alone
+    #: (None: every layer over every row): it and the layers behind it keep
+    #: no cache and hand nothing on, so no other row of theirs is ever read
+    last_row_from: Optional[int] = None
     dtype: Any = jnp.bfloat16       # activations
     param_dtype: Any = jnp.bfloat16
     kda_heads: int = 32
@@ -230,6 +271,11 @@ class HybridConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    #: ``mamba1``: a decay a CHANNEL and state dimension, exp(dt[c] A[c, n])
+    m1_inner: int = 5120
+    m1_state: int = 16
+    m1_conv: int = 4
+    m1_dt_rank: int = 160
     gqa_heads: int = 32
     gqa_kv_heads: int = 2           # query head i on kv head i // (Hq / Hkv)
     gqa_head_dim: int = 128
@@ -241,6 +287,11 @@ class HybridConfig:
     swa_rope: Optional[Rope] = None
     swa_gated: bool = False
     swa_window: Optional[int] = None
+    #: differential attention (heads in pairs, two softmax maps a pair over
+    #: a value twice as wide, their difference normalised) and biases on
+    #: the projections, in every grouped-query kind alike
+    differential: bool = False
+    attn_bias: bool = False
     dense_ff: int = 9216
     expert_ff: int = 1024
     shared_ff: Optional[int] = None       # None: expert_ff
@@ -268,6 +319,52 @@ class HybridConfig:
             if rope is not None and (rope.dims or 0) > self.gqa_head_dim:
                 raise ValueError(f"{rope.dims} rotated dimensions are more "
                                  f"than a head's {self.gqa_head_dim}")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        kinds = {p.kind for s in self.layers for p in s.parts}
+        for kind in ("gqa", "swa"):
+            heads, _rope, gated, _window = self.attention(kind)
+            if self.differential and kind in kinds and (
+                    gated or self.gqa_kv_heads % 2
+                    or heads % self.gqa_kv_heads):
+                raise ValueError("differential attention pairs the heads "
+                                 "and the key/value heads, ungated")
+            if _rope is not None and (self.differential or self.attn_bias or (
+                    kind == "gqa" and "xattn" in kinds)):
+                raise ValueError("a rotation beside differential attention, "
+                                 "projection biases or a query-only layer "
+                                 "is not written")
+        self._check_sources()
+
+    def _check_sources(self):
+        """What a part reads was handed on by an earlier part whose kind
+        gives it; the layers a prefill runs on the last row keep nothing."""
+        gave: Dict[str, str] = {}
+        for i, spec in enumerate(self.layers):
+            for p in spec.parts:
+                kind = MIXERS.get(p.kind)
+                reads = kind.reads if kind else None
+                if (reads is None) != (p.source is None):
+                    raise ValueError(f"layer {i}: {p.kind} reads {reads}, "
+                                     f"its source is {p.source!r}")
+                if reads and gave.get(p.source) != reads:
+                    raise ValueError(
+                        f"layer {i}: no earlier part hands on {reads} as "
+                        f"{p.source!r}")
+                if p.tag is not None:
+                    given = None if kind is None else (
+                        "side" if kind.gives_side else
+                        "pages" if kind.keeps_pages(self) else None)
+                    if given is None or p.tag in gave:
+                        raise ValueError(f"layer {i}: {p.kind} hands on "
+                                         f"nothing as {p.tag!r}")
+                    gave[p.tag] = given
+                if self.last_row_from is not None \
+                        and i >= self.last_row_from and (
+                            p.tag or (kind and kind.leaves(self))):
+                    raise ValueError(
+                        f"layer {i} keeps a cache or hands something on: a "
+                        "prefill cannot run it on the last row alone")
 
     @property
     def n_layers(self) -> int:
@@ -300,11 +397,31 @@ class HybridConfig:
         """A cached row of a grouped-query layer: [k heads | v heads]."""
         return 2 * self.gqa_kv_heads * self.gqa_head_dim
 
+    @property
+    def m1_proj(self) -> int:
+        """What a Mamba-1 layer projects its convolved rows to: the time
+        step's bottleneck, B and C."""
+        return self.m1_dt_rank + 2 * self.m1_state
+
     def attention(self, kind: str):
-        """(heads, rotation, gated, window) of a grouped-query kind."""
-        if kind == "gqa":
+        """(heads, rotation, gated, window) of a grouped-query kind;
+        ``xattn`` is the full kind's."""
+        if kind in ("gqa", "xattn"):
             return self.gqa_heads, self.gqa_rope, self.gqa_gated, None
         return self.swa_heads, self.swa_rope, self.swa_gated, self.swa_window
+
+    def attention_shape(self, kind: str):
+        """(key/value heads, query rows on each, head width) as a kind's
+        products see them. Differential attention is grouped-query attention
+        on PAIRS: the cached row ``[k heads | v heads]`` read as half as
+        many heads twice as wide, a pair's two queries padded with zeros to
+        that width (the first in the low half, the second in the high), so
+        that ``q1' . [k1 | k2] = q1 . k1`` and the value is ``[v1 | v2]``."""
+        heads, g, hd = self.attention(kind)[0], self.gqa_kv_heads, \
+            self.gqa_head_dim
+        if self.differential:
+            return g // 2, heads // (g // 2), 2 * hd
+        return g, heads // g, hd
 
 
 @dataclasses.dataclass(frozen=True)
@@ -323,21 +440,41 @@ class CacheLeaf:
 @dataclasses.dataclass(frozen=True)
 class MixerKind:
     """What the model and the cache protocol need of a kind of mixer.
-    ``full(model, p, h, valid, last_idx) -> (y, *entries)`` over (B, T, d),
-    the entries in the order of ``leaves``; ``decode(model, p, h, held,
-    tables, positions, page_tokens) -> (y, *held)`` for one token a slot;
-    ``init(model, w, ones, resid) -> params``."""
+    ``full(model, p, h, valid, last_idx, src, row) -> (y, *entries)`` over
+    (B, T, d), the entries in the order of ``leaves``; ``decode(model, p, h,
+    held, tables, positions, page_tokens, src) -> (y, *held)`` for one token
+    a slot; ``init(model, w, ones, resid, layer) -> params``. ``reads``:
+    None, or what a layer of the kind takes from the earlier part its
+    ``Part.source`` names and owns nothing of - ``pages``: that part's paged
+    rows (``src``: the rows of every position in a full pass, its pool,
+    already written, in a step) - ``side``: that part's side output of the
+    same pass. ``gives_side``: ``full`` and ``decode`` return a side output
+    behind everything else, rows as wide as the kind says, which the walk
+    keeps where the part has a ``tag``. ``row``: None, or the one position
+    h's single row stands at (a prefill's layers on the last row)."""
 
     leaves: Callable
     full: Callable
     decode: Callable
     init: Callable
+    reads: Optional[str] = None
+    gives_side: bool = False
+
+    def keeps_pages(self, config) -> bool:
+        return any(leaf.paged for leaf in self.leaves(config))
 
 
 def _rms(x, g, eps):
     x32 = x.astype(jnp.float32)
     return x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps) \
         * g.astype(jnp.float32)
+
+
+def _layer_norm(x, g, b, eps):
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, -1, keepdims=True)
+    return x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps) \
+        * g.astype(jnp.float32) + b.astype(jnp.float32)
 
 
 def _mm(x, w):
@@ -553,6 +690,49 @@ def ssd_step(s, x, dt, log_a, b, c):
     return s.reshape(B, H, P, N), y.reshape(B, H, P)
 
 
+# --------------------------------------------------------------- Mamba-1
+def mamba1_step(s, x, dt, a, b, c):
+    """One row of the recurrence with a decay a channel and state dimension,
+    for every slot: s (B, N, C) float32 (the channels minor: 5,120 of them
+    fill whole tiles of 128 lanes, 16 state dimensions would fill an eighth
+    of one); x, dt (B, C); a (N, C) < 0; b, c (B, N).
+    ``S <- exp(dt a) S + dt x b^T``, ``y = c S``; every factor in (0, 1]."""
+    s = jnp.exp(dt[:, None, :] * a) * s \
+        + (dt * x)[:, None, :] * b[:, :, None]
+    return s, jnp.sum(s * c[:, :, None], axis=1)
+
+
+def mamba1_chunked(x, dt, a, b, c, s0, chunk: int):
+    """That recurrence over whole sequences: x, dt (B, T, C); a (N, C);
+    b, c (B, T, N); s0 (B, N, C); all float32, T a multiple of ``chunk``.
+    Returns (y (B, T, C), the state after the last row). The decay differs
+    in every channel AND state dimension, so a chunk is no matrix product
+    (``ssd_chunked``'s is): the rows of a chunk are taken one after another
+    inside ONE step of a scan over the chunks, :func:`mamba1_step` spelled
+    ``chunk`` times, so that the compiler sees a chain of element-wise
+    updates of one (N, C) state and not T / chunk x chunk tiny programs.
+    Every factor is exp(dt a) in (0, 1]: nothing is divided by a running
+    product, so no decay can overflow or underflow the result."""
+    B, T, C = x.shape
+    n = T // chunk
+
+    def chunks(z):          # (B, T, X) -> (n, B, chunk, X)
+        return z.reshape(B, n, chunk, -1).swapaxes(0, 1)
+
+    def step(s, xs):
+        x_n, dt_n, b_n, c_n = xs
+        ys = []
+        for i in range(chunk):
+            s, y = mamba1_step(s, x_n[:, i], dt_n[:, i], a, b_n[:, i],
+                               c_n[:, i])
+            ys.append(y)
+        return s, jnp.stack(ys, axis=1)
+
+    s_end, y = lax.scan(step, s0, (chunks(x), chunks(dt), chunks(b),
+                                   chunks(c)))
+    return y.swapaxes(0, 1).reshape(B, T, C), s_end
+
+
 def _slot_page(tables, positions, page_tokens: int, n_pages: int):
     """The page each slot's row at ``positions`` goes to. A position past
     the last logical page (a retired slot) goes to the trash page, the
@@ -584,6 +764,16 @@ _ATTN_SCOPES = ("proj", "rope", "attend")
 def _scope(kind: str, what: str) -> str:
     assert what in _ATTN_SCOPES, what
     return f"{kind}_{what}"
+
+
+def _pair_queries(q, groups: int):
+    """q (..., H, hd), heads 2j and 2j + 1 a pair -> (..., groups, 2 H /
+    groups, 2 hd): ``[q1 | 0]`` and ``[0 | q2]`` of every pair, the pairs
+    of a key/value pair side by side."""
+    *lead, H, hd = q.shape
+    q = q.reshape(*lead, H // 2, 2, 1, hd) \
+        * jnp.eye(2, dtype=q.dtype)[:, :, None]
+    return q.reshape(*lead, groups, H // groups, 2 * hd)
 
 
 def latent_attention_backend(heads: int, row: int, out_width: int,
@@ -695,6 +885,12 @@ class HybridLM:
         leaves = [(leaf, n) for kind, n in seen.items() if kind in MIXERS
                   for leaf in MIXERS[kind].leaves(c)]
         self.cache_leaves = sorted(leaves, key=lambda ln: not ln[0].paged)
+        #: parts that read paged rows a step: those that own a paged leaf
+        #: and those that read another's
+        self.page_readers = sum(
+            1 for s in c.layers for p in s.parts if p.kind in MIXERS
+            and (MIXERS[p.kind].reads == "pages"
+                 or MIXERS[p.kind].keeps_pages(c)))
         self._said: Dict[str, Any] = {}
         #: (choice, why) that the last trace of an attention over pages took
         #: (:func:`latent_attention_backend`,
@@ -719,6 +915,10 @@ class HybridLM:
         def ones(n):
             return jnp.ones((n,), c.param_dtype)
 
+        def norm():
+            return ones(d) if c.norm == "rms" else {
+                "g": ones(d), "b": jnp.zeros((d,), c.param_dtype)}
+
         def ffn(width, lead=(), d_in=d, form="swiglu"):
             return {EXPERT_FORMS[form]: w(lead + (
                 d_in, (2 if form == "swiglu" else 1) * width)),
@@ -738,22 +938,23 @@ class HybridLM:
             return p
 
         blocks = []
-        for spec in c.layers:
+        for layer, spec in enumerate(c.layers):
             blk = {}
             for part in spec.parts:
                 if part.norm is not None:
-                    blk[part.norm] = ones(d)
+                    blk[part.norm] = norm()
                 if part.kind in MIXERS:
-                    blk[part.name] = MIXERS[part.kind].init(self, w, ones,
-                                                            resid)
+                    blk[part.name] = MIXERS[part.kind].init(
+                        self, w, ones, resid, layer)
                 else:
                     blk[part.name] = ffn(c.dense_ff) \
                         if part.kind == "dense" else experts()
             blocks.append(blk)
-        return {"tok_emb": w((c.vocab_size, d)), "head": w((d, c.vocab_size)),
-                "ln_f": ones(d), "blocks": blocks}
+        return {"tok_emb": w((c.vocab_size, d)),
+                **({} if c.tie_embeddings else {"head": w((d, c.vocab_size))}),
+                "ln_f": norm(), "blocks": blocks}
 
-    def _init_kda(self, w, ones, resid):
+    def _init_kda(self, w, ones, resid, _layer=None):
         c = self.config
         H, K, d, r = c.kda_heads, c.kda_head_dim, c.d_model, c.kda_gate_rank
         return {
@@ -767,7 +968,7 @@ class HybridLM:
                                                      c.param_dtype),
             "o_norm": ones(K), "w_o": w((H * K, d), resid)}
 
-    def _init_mla(self, w, ones, resid):
+    def _init_mla(self, w, ones, resid, _layer=None):
         c = self.config
         hm, d, rq = c.mla_heads, c.d_model, c.q_lora_rank
         hq = hm * (c.qk_nope_dim + c.qk_rope_dim)
@@ -781,7 +982,7 @@ class HybridLM:
                         hm * (c.qk_nope_dim + c.v_head_dim))),
             "w_o": w((hm * c.v_head_dim, d), resid)}
 
-    def _init_mamba2(self, w, ones, resid):
+    def _init_mamba2(self, w, ones, resid, _layer=None):
         c = self.config
         H, d, di = c.ssm_heads, c.d_model, c.ssm_inner
         return {
@@ -793,19 +994,60 @@ class HybridLM:
             "dt_bias": jnp.full((H,), -2.0, jnp.float32),
             "norm": ones(di), "w_out": w((di, d), resid)}
 
-    def _init_gqa(self, w, ones, resid, kind="gqa"):
+    def _init_gqa(self, w, ones, resid, layer=0, kind="gqa"):
         c = self.config
         heads, _rope, gated, _window = c.attention(kind)
-        d, hq = c.d_model, heads * c.gqa_head_dim
-        return {"w_q": w((d, hq)), "w_kv": w((d, c.gqa_kv_row)),  # [k | v]
+        differential, bias = c.differential, c.attn_bias
+        d, hd, hq = c.d_model, c.gqa_head_dim, heads * c.gqa_head_dim
+
+        def zeros(n):
+            return jnp.zeros((n,), c.param_dtype)
+
+        return {"w_q": w((d, hq)),
+                **({} if kind == "xattn" else
+                   {"w_kv": w((d, c.gqa_kv_row))}),           # [k | v]
+                **({"b_q": zeros(hq), "b_o": zeros(d)} if bias else {}),
+                **({"b_kv": zeros(c.gqa_kv_row)}
+                   if bias and kind != "xattn" else {}),
                 **({"w_gate": w((d, heads))} if gated else {}),
+                # a pair's weight on its second map: exp(lq1 . lk1) -
+                # exp(lq2 . lk2) + lambda_init, the last set by the layer's
+                # depth and never trained
+                **({**{n: w((hd,), 0.1).astype(jnp.float32) for n in (
+                    "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")},
+                    "lambda_init": jnp.float32(
+                        0.8 - 0.6 * math.exp(-0.3 * layer)),
+                    "sub_norm": ones(2 * hd)} if differential else {}),
                 "w_o": w((hq, d), resid)}
+
+    def _init_mamba1(self, w, ones, resid, _layer=None):
+        c = self.config
+        d, C, N, r = c.d_model, c.m1_inner, c.m1_state, c.m1_dt_rank
+        return {
+            "w_in": w((d, 2 * C)),                       # [x | z]
+            "conv": w((c.m1_conv, C), 0.3),
+            "b_conv": jnp.zeros((C,), c.param_dtype),
+            "w_x": w((C, c.m1_proj)),                    # [dt's r | B | C]
+            "w_dt": w((r, C), r ** -0.5),
+            "b_dt": jnp.full((C,), -2.0, jnp.float32),
+            # (N, C): as the state lies, the channels minor
+            "a_log": jnp.log(jnp.broadcast_to(
+                jnp.arange(1, N + 1, dtype=jnp.float32)[:, None], (N, C))),
+            "d_skip": jnp.ones((C,), jnp.float32),
+            "w_out": w((C, d), resid)}
+
+    def _init_gmu(self, w, ones, resid, _layer=None):
+        c = self.config
+        return {"w_in": w((c.d_model, c.m1_inner)),
+                "w_out": w((c.m1_inner, c.d_model), resid)}
 
     # ------------------------------------------------------------ pieces
     # The residual stream and the norms' outputs are float32 (a few MB);
     # what a matmul takes is cast to ``dtype`` where it is taken.
     def _ln(self, g, x):
         with jax.named_scope("ln"):
+            if self.config.norm == "layer":
+                return _layer_norm(x, g["g"], g["b"], self.config.rms_eps)
             return _rms(x, g, self.config.rms_eps)
 
     def _embed(self, params, tokens):
@@ -816,6 +1058,9 @@ class HybridLM:
     def _head(self, params, x):
         x = self._ln(params["ln_f"], x).astype(self.config.dtype)
         with jax.named_scope("head"):
+            if self.config.tie_embeddings:
+                return jnp.einsum("...d,vd->...v", x, params["tok_emb"],
+                                  preferred_element_type=jnp.float32)
             return _mm(x, params["head"])
 
     def _ffn(self, p, kind, h32, token_mask):
@@ -1140,9 +1385,91 @@ class HybridLM:
         with jax.named_scope("attn_out"):
             return self._ssm_out(p, y, x, z), s, tail
 
+    # ---------------------------------------------------------- Mamba-1
+    def _m1_project(self, p, h):
+        """h (..., d) -> the rows the convolution takes (..., C) and the
+        gate's z (..., C) float32."""
+        c = self.config
+        with jax.named_scope("ssm_proj"):
+            xz = _mm(h, p["w_in"])
+        return xz[..., :c.m1_inner].astype(c.dtype), xz[..., c.m1_inner:]
+
+    def _m1_dtbc(self, p, x):
+        """x (..., C) float32, the convolved rows through their SiLU -> the
+        time step (..., C) > 0 through its bottleneck, B and C (..., N)."""
+        c = self.config
+        r, N = c.m1_dt_rank, c.m1_state
+        with jax.named_scope("ssm_proj"):
+            rbc = _mm(x.astype(c.dtype), p["w_x"])
+            dt = jax.nn.softplus(_mm(rbc[..., :r].astype(c.dtype), p["w_dt"])
+                                 + p["b_dt"])
+        return dt, rbc[..., r:r + N], rbc[..., r + N:]
+
+    def _m1_out(self, p, y, z):
+        c = self.config
+        with jax.named_scope("ssm_out"):
+            return _mm((y * jax.nn.silu(z)).astype(c.dtype),
+                       p["w_out"]).astype(c.dtype)
+
+    def _m1_full(self, p, h, valid, last_idx):
+        """h (B, T, d); rows where ``valid`` is False are identity updates
+        (dt = 0). Returns (y, state at the last valid row (B, N, C), the 3
+        rows the convolution would need before the next, and the scan's
+        output BEFORE the gate, skip included, (B, T, C) float32: what a
+        later layer's memory unit reads)."""
+        c = self.config
+        B, T, _ = h.shape
+        with jax.named_scope("attn_qkv"):
+            pre, z = self._m1_project(p, h)
+            with jax.named_scope("ssm_conv"):
+                x = jax.nn.silu(_conv_full(pre, p["conv"])
+                                + p["b_conv"].astype(jnp.float32))
+                tail = _conv_tail(pre, c.m1_conv, last_idx, c.dtype)
+            dt, b, cm = self._m1_dtbc(p, x)
+        with jax.named_scope("attn_core"), jax.named_scope("ssm_state"):
+            dt = jnp.where(valid[None, :, None], dt, 0.0)
+            pad = -T % _M1_CHUNK
+            xs = [x, dt, b, cm]
+            if pad:
+                xs = [jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in xs]
+            s0 = jnp.zeros((B, c.m1_state, c.m1_inner), jnp.float32)
+            y, s = mamba1_chunked(xs[0], xs[1], -jnp.exp(p["a_log"]),
+                                  xs[2], xs[3], s0, _M1_CHUNK)
+            y = y[:, :T] + p["d_skip"] * x
+        with jax.named_scope("attn_out"):
+            return self._m1_out(p, y, z), s, tail, y
+
+    def _m1_decode(self, p, h, s, tail):
+        """h (B, d), s (B, N, C), tail (B, 3, C)."""
+        with jax.named_scope("attn_qkv"):
+            pre, z = self._m1_project(p, h)
+            with jax.named_scope("ssm_conv"):
+                conved, rows = _conv_step(tail, pre, p["conv"])
+                x = jax.nn.silu(conved + p["b_conv"].astype(jnp.float32))
+                tail = rows[:, 1:]
+            dt, b, cm = self._m1_dtbc(p, x)
+        with jax.named_scope("attn_core"), jax.named_scope("ssm_state"):
+            s, y = mamba1_step(s, x, dt, -jnp.exp(p["a_log"]), b, cm)
+            y = y + p["d_skip"] * x
+        with jax.named_scope("attn_out"):
+            return self._m1_out(p, y, z), s, tail, y
+
+    def _gmu(self, p, h, memory):
+        """The gated memory unit: h (..., d) gates the rows ``memory``
+        (..., C) of the same positions, a state-space layer's output before
+        its own gate: ``(silu(h W_in) * memory) W_out``. No state."""
+        c = self.config
+        with jax.named_scope("attn_qkv"), jax.named_scope("gmu"):
+            g = jax.nn.silu(_mm(h, p["w_in"]))
+        with jax.named_scope("attn_core"), jax.named_scope("gmu"):
+            y = (g * memory.astype(jnp.float32)).astype(c.dtype)
+        with jax.named_scope("attn_out"), jax.named_scope("gmu"):
+            return _mm(y, p["w_out"]).astype(c.dtype)
+
     # ------------------------------------------- grouped-query attention
-    # ``kind``: ``gqa``, over every earlier position, its rows in pages; or
-    # ``swa``, over the last ``swa_window``, its rows in a ring a slot.
+    # ``kind``: ``gqa``, over every earlier position, its rows in pages;
+    # ``swa``, over the last ``swa_window``, its rows in a ring a slot; or
+    # ``xattn``, queries alone over the rows of the ``gqa`` layer it reads.
     def _gqa_project(self, p, h, kind="gqa", positions=None):
         """h (..., d) at ``positions`` (broadcast against h's leading axes;
         None: whole sequences, 0 .. T - 1 along the axis before the last)
@@ -1150,16 +1477,26 @@ class HybridLM:
         v heads] (..., 2 Hkv hd), and the output gate's scalars (..., Hq)
         float32, or None. Where the kind rotates, q and the row's keys are
         rotated here, in float32 before they are rounded: the keys once, at
-        their own position, so a cached row never needs it again."""
+        their own position, so a cached row never needs it again. The
+        query-only kind (``xattn``) has no row: None. A differential kind's
+        q is in pairs, ``HybridConfig.attention_shape``'s."""
         c = self.config
         heads, rope, gated, _window = c.attention(kind)
+        differential, bias = c.differential, c.attn_bias
         g, hd = c.gqa_kv_heads, c.gqa_head_dim
         with jax.named_scope(_scope(kind, "proj")):
             if rope is None:
-                q = _mm(h, p["w_q"]).astype(c.dtype).reshape(
-                    *h.shape[:-1], g, heads // g, hd)
-                row = _mm(h, p["w_kv"]).astype(c.dtype)
-            else:
+                q, row = _mm(h, p["w_q"]), None
+                if bias:
+                    q = q + p["b_q"].astype(jnp.float32)
+                q = q.astype(c.dtype).reshape(*h.shape[:-1], g, heads // g,
+                                              hd)
+                if kind != "xattn":
+                    row = _mm(h, p["w_kv"])
+                    if bias:
+                        row = row + p["b_kv"].astype(jnp.float32)
+                    row = row.astype(c.dtype)
+            else:       # never beside a bias or a query-only layer
                 if positions is None:
                     positions = jnp.arange(h.shape[-2])
                 q = _mm(h, p["w_q"]).reshape(*h.shape[:-1], g, heads // g, hd)
@@ -1172,18 +1509,39 @@ class HybridLM:
                 row = jnp.concatenate(
                     [k.reshape(*h.shape[:-1], g * hd), kv[..., g * hd:]],
                     axis=-1).astype(c.dtype)
+            if differential:
+                q = _pair_queries(q.reshape(*h.shape[:-1], heads, hd),
+                                  c.attention_shape(kind)[0])
             gate = None
             if gated:
                 with jax.named_scope("attn_gate"):
                     gate = jax.nn.sigmoid(_mm(h, p["w_gate"]))
         return q, row, gate
 
-    def _gqa_kv(self, rows):
-        """(..., 2 Hkv hd) -> k, v (..., Hkv, hd)."""
+    def _gqa_kv(self, rows, kind="gqa"):
+        """(..., 2 Hkv hd) -> k, v (..., Hkv, hd), or, for a differential
+        kind, (..., Hkv / 2, 2 hd): its pairs of heads."""
         c = self.config
         half = c.gqa_kv_row // 2
-        shape = (*rows.shape[:-1], c.gqa_kv_heads, c.gqa_head_dim)
+        g, _k, hd = c.attention_shape(kind)
+        shape = (*rows.shape[:-1], g, hd)
         return rows[..., :half].reshape(shape), rows[..., half:].reshape(shape)
+
+    def _attn_diff(self, p, o):
+        """o (..., G, K, 2 hd), the two maps of every pair over the pair's
+        value, a1 and a2 side by side -> (..., Hq hd) in ``dtype``:
+        ``rms(a1 - lambda a2) (1 - lambda_init)`` a pair, through the
+        128-wide sub-norm's gain."""
+        c = self.config
+        with jax.named_scope("attn_diff"):
+            a = o.astype(jnp.float32).reshape(*o.shape[:-3], -1, 2,
+                                              o.shape[-1])
+            lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
+                   - jnp.exp(jnp.sum(p["lambda_q2"] * p["lambda_k2"]))
+                   + p["lambda_init"])
+            y = _rms(a[..., 0, :] - lam * a[..., 1, :], p["sub_norm"],
+                     c.rms_eps) * (1.0 - p["lambda_init"])
+            return y.reshape(*o.shape[:-3], -1).astype(c.dtype)
 
     def _gqa_out(self, p, o, gate, kind):
         """o (..., Hq hd) in ``dtype``: every head times its gate's scalar,
@@ -1195,20 +1553,27 @@ class HybridLM:
                 with jax.named_scope("attn_gate"):
                     o = (o.reshape(*gate.shape, c.gqa_head_dim)
                          * gate[..., None]).astype(c.dtype).reshape(o.shape)
+            if c.attn_bias:
+                return (_mm(o, p["w_o"])
+                        + p["b_o"].astype(jnp.float32)).astype(c.dtype)
             return _mm(o, p["w_o"]).astype(c.dtype)
 
-    def _gqa_full(self, p, h, kind="gqa"):
+    def _gqa_full(self, p, h, kind="gqa", src=None, row_at=None):
         """Causal softmax attention over (B, T, d) in blocks of queries.
         A block scores every key (``gqa``) or the ``block + window`` keys it
         can see (``swa``: a query at t reads keys t - window < j <= t).
-        Returns (y, the rows of K and V (B, T, 2 Hkv hd))."""
+        Returns (y, the rows of K and V (B, T, 2 Hkv hd)). The query-only
+        kind scores the rows ``src`` of the layer it reads and returns None
+        for its own; ``row_at``: None, or the position of h's ONE row."""
         c = self.config
         B, T, _ = h.shape
         scale = c.gqa_head_dim ** -0.5
         window = c.attention(kind)[3]
+        starts = None if row_at is None else jnp.reshape(row_at, (1,))
         with jax.named_scope("attn_qkv"):
-            q, row, gate = self._gqa_project(p, h, kind)
-            k, v = self._gqa_kv(row)
+            q, row, gate = self._gqa_project(p, h, kind, starts)
+            k, v = self._gqa_kv(row if src is None else src, kind)
+        keys = k.shape[1]
         with jax.named_scope("attn_core"), jax.named_scope(
                 _scope(kind, "attend")):
             bq = min(_QUERY_BLOCK, T)
@@ -1236,15 +1601,18 @@ class HybridLM:
                     j = (i0 - window + jnp.arange(bq + window))[None, :]
                     ok = (j <= at) & (j > at - window) & (j >= 0)
                 else:
-                    ok = at >= jnp.arange(T)[None, :]
+                    ok = at >= jnp.arange(keys)[None, :]
                 pr = jax.nn.softmax(jnp.where(ok, s, -1e30),
                                     axis=-1).astype(c.dtype)
                 return jnp.einsum("bgkqt,btgd->bqgkd", pr, v_b,
                                   preferred_element_type=jnp.float32
                                   ).astype(c.dtype)
 
-            o = lax.map(one, (qb, jnp.arange(nb) * bq))
+            o = lax.map(one, (qb, jnp.arange(nb) * bq if starts is None
+                              else starts))
             o = o.swapaxes(0, 1).reshape(B, T + pad, -1)[:, :T]
+            if c.differential:
+                o = self._attn_diff(p, o.reshape(B, T, *q.shape[2:]))
         return self._gqa_out(p, o, gate, kind), row
 
     def _swa_full(self, p, h, last_idx):
@@ -1261,37 +1629,47 @@ class HybridLM:
                              jnp.take(row, jnp.maximum(at, 0), axis=1), 0)
         return y, ring
 
-    def _gqa_decode(self, p, h, pool, tables, positions, page_tokens):
+    def _gqa_decode(self, p, h, pool, tables, positions, page_tokens,
+                    kind="gqa"):
         """h (B, d) against the slot's pages of K and V rows. The step's own
         row is written first, then read back with the rest: by the kernel
         that walks the slot's live pages where they lie
         (``kernels/paged_latent_attention.py::paged_grouped_attention``) or,
         everywhere :func:`grouped_attention_backend` does not take it, over
-        a gathered view of every slot's whole window."""
+        a gathered view of every slot's whole window. The query-only kind
+        writes nothing: ``pool`` is the layer's it reads, the step's row
+        already in it."""
         c = self.config
         B = h.shape[0]
         P = int(page_tokens)
         S = tables.shape[1] * P
         scale = c.gqa_head_dim ** -0.5
+        g, k_rows, hd = c.attention_shape(kind)
         with jax.named_scope("attn_qkv"):
-            q, row, gate = self._gqa_project(p, h, "gqa", positions)
-        with jax.named_scope("kv_write"):
-            page = _slot_page(tables, positions, P, pool.shape[0])
-            pool = pool.at[page, positions % P].set(row)
+            q, row, gate = self._gqa_project(p, h, kind, positions)
+        if row is not None:
+            with jax.named_scope("kv_write"):
+                page = _slot_page(tables, positions, P, pool.shape[0])
+                pool = pool.at[page, positions % P].set(row)
         self.attention_backend = grouped_attention_backend(
-            B, c.gqa_heads, c.gqa_kv_heads, c.gqa_head_dim, P,
-            tables.shape[1], jnp.dtype(c.dtype).itemsize)
+            B, g * k_rows, g, hd, P, tables.shape[1],
+            jnp.dtype(c.dtype).itemsize)
         self._say_once("attention backend", *self.attention_backend)
         if self.attention_backend[0] == "paged-grouped":
             from deeplearning4j_tpu.kernels.paged_latent_attention import \
                 paged_grouped_attention
-            with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    _scope(kind, "attend")):
                 o = paged_grouped_attention(q, pool, tables, positions, scale)
+                if c.differential:
+                    o = self._attn_diff(p, o)
         else:
             with jax.named_scope("kv_gather"):
                 k, v = self._gqa_kv(pool.at[tables].get(
-                    mode="promise_in_bounds").reshape(B, S, c.gqa_kv_row))
-            with jax.named_scope("attn_core"), jax.named_scope("gqa_attend"):
+                    mode="promise_in_bounds").reshape(B, S, c.gqa_kv_row),
+                    kind)
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    _scope(kind, "attend")):
                 s = jnp.einsum("bgkd,bsgd->bgks", q, k,
                                preferred_element_type=jnp.float32) * scale
                 live = jnp.arange(S)[None, :] <= positions[:, None]
@@ -1300,7 +1678,9 @@ class HybridLM:
                 o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
                                preferred_element_type=jnp.float32
                                ).astype(c.dtype)
-        return self._gqa_out(p, o.reshape(B, -1), gate, "gqa"), pool
+                if c.differential:
+                    o = self._attn_diff(p, o)
+        return self._gqa_out(p, o.reshape(B, -1), gate, kind), pool
 
     def _swa_decode(self, p, h, ring, positions):
         """h (B, d) against the slot's ring of K and V rows (B, window,
@@ -1315,7 +1695,7 @@ class HybridLM:
         with jax.named_scope("kv_write"), jax.named_scope("swa_write"):
             ring = ring.at[jnp.arange(h.shape[0]), positions % W].set(row)
         with jax.named_scope("attn_core"), jax.named_scope("swa_attend"):
-            k, v = self._gqa_kv(ring)
+            k, v = self._gqa_kv(ring, "swa")
             s = jnp.einsum("bgkd,bsgd->bgks", q, k,
                            preferred_element_type=jnp.float32) \
                 * c.gqa_head_dim ** -0.5
@@ -1324,6 +1704,8 @@ class HybridLM:
                                 axis=-1).astype(c.dtype)
             o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
                            preferred_element_type=jnp.float32).astype(c.dtype)
+            if c.differential:
+                o = self._attn_diff(p, o)
         return self._gqa_out(p, o.reshape(h.shape[0], -1), gate,
                              "swa"), ring
 
@@ -1342,10 +1724,14 @@ class HybridLM:
         experts' form and the router>``, once a trace (as ``TransformerLM``
         says its layouts)."""
         c = self.config
-        # a part that lands at the layer's end stands in brackets
-        kinds = " ".join("+".join(
-            p.kind if p.lands == "now" else f"[{p.kind}]" for p in s.parts)
-            for s in c.layers)
+        # a part that lands at the layer's end stands in brackets; what a
+        # part hands on stands behind ``=``, what it reads behind ``<``
+        def say(p):
+            name = p.kind + (f"={p.tag}" if p.tag else "") \
+                + (f"<{p.source}" if p.source else "")
+            return name if p.lands == "now" else f"[{name}]"
+
+        kinds = " ".join("+".join(say(p) for p in s.parts) for s in c.layers)
         e = c.experts
         why = "no routed experts" if not self.moe_layers else (
             f"experts {e.form}"
@@ -1359,21 +1745,39 @@ class HybridLM:
         present = {p.kind for s in c.layers for p in s.parts}
         for kind in ("gqa", "swa"):
             heads, rope, gated, window = c.attention(kind)
-            if kind in present and (rope or gated or window):
+            differential, bias = c.differential, c.attn_bias
+            if kind in present and (rope or gated or window or differential
+                                    or bias):
                 why += (f"; {kind} {heads} heads on {c.gqa_kv_heads}, "
                         + (rope.say(c.gqa_head_dim) if rope else "unrotated")
                         + (", gated" if gated else "")
+                        + (", differential in pairs of {} wide".format(
+                            2 * c.gqa_head_dim) if differential else "")
+                        + (", biased" if bias else "")
                         + (f", window {window}" if window else ""))
+        if "xattn" in present:
+            why += (f"; xattn: queries alone, {self.page_readers} parts "
+                    "read the pages")
+        if "mamba1" in present:
+            why += (f"; mamba1 {c.m1_inner} channels x {c.m1_state}, dt "
+                    f"through {c.m1_dt_rank}, {_M1_CHUNK} rows a scan step")
+        if c.norm != "rms" or c.tie_embeddings:
+            why += f"; {c.norm} norm" + (", tied head" if c.tie_embeddings
+                                         else "")
+        if c.last_row_from is not None:
+            why += (f"; a prefill runs the layers from {c.last_row_from} on "
+                    "the last row")
         self._say_once("layer kinds", kinds, why)
 
-    def _layers(self, params, x, run):
-        """The one walk over the description: every layer's parts in order,
-        ``run(part, rank, p, h32) -> y`` for each (``rank``: a mixer's among
-        the mixers of its kind, ``p`` its parameters, ``h32`` the normalised
-        rows it reads, float32), each result added to the stream where the
-        part says it lands."""
-        for blk, spec, ranks in zip(params["blocks"], self.config.layers,
-                                    self._rank):
+    def _layers(self, params, x, run, start=0, stop=None):
+        """The one walk over the description: every layer's parts in order
+        (layers ``start`` to ``stop``), ``run(part, rank, p, h32) -> y`` for
+        each (``rank``: a mixer's among the mixers of its kind, ``p`` its
+        parameters, ``h32`` the normalised rows it reads, float32), each
+        result added to the stream where the part says it lands."""
+        for blk, spec, ranks in zip(params["blocks"][start:stop],
+                                    self.config.layers[start:stop],
+                                    self._rank[start:stop]):
             late = []
             for part, rank in zip(spec.parts, ranks):
                 if part.norm is not None:
@@ -1387,26 +1791,57 @@ class HybridLM:
                 x = x + y
         return x
 
-    def _trunk(self, params, tokens, last_idx):
+    def _handed_on(self, kind, held):
+        """What a part with a ``tag`` hands to later parts, from what its
+        kind's function returned behind y: its side output (the last), else
+        the array of its paged leaf."""
+        if kind.gives_side:
+            return held[-1]
+        return next(a for leaf, a in zip(kind.leaves(self.config), held)
+                    if leaf.paged)
+
+    def _trunk(self, params, tokens, last_idx, last_row=False):
         """tokens (B, T) -> (x (B, T, d) before the final norm, cache
-        entries). Rows after ``last_idx`` are padding."""
+        entries). Rows after ``last_idx`` are padding. ``last_row``: the
+        layers from ``last_row_from`` on are run on the row at ``last_idx``
+        alone, and x is that row, (B, 1, d) - where the configuration names
+        such a layer; everywhere else, and in :meth:`apply`, every layer
+        over every row."""
         c = self.config
         self._say_layers()
         T = tokens.shape[1]
         valid = jnp.arange(T) <= last_idx
         entries = {leaf.name: [] for leaf, _n in self.cache_leaves}
+        given: Dict[str, Any] = {}
+        sides = set()   # the tags of ``given`` that are side outputs
+        row = None      # the position of the ONE row the layers now see
 
         def run(part, _rank, p, h32):
             if part.kind not in MIXERS:
-                return self._ffn(p, part.kind, h32,
-                                 jnp.broadcast_to(valid, tokens.shape))[0]
+                return self._ffn(p, part.kind, h32, None if row is not None
+                                 else jnp.broadcast_to(valid, tokens.shape)
+                                 )[0]
             kind = MIXERS[part.kind]
-            y, *new = kind.full(self, p, h32.astype(c.dtype), valid, last_idx)
+            y, *new = kind.full(self, p, h32.astype(c.dtype), valid, last_idx,
+                                given.get(part.source), row)
             for leaf, entry in zip(kind.leaves(c), new):
                 entries[leaf.name].append(entry)
+            if part.tag is not None:
+                given[part.tag] = self._handed_on(kind, new)
+                if kind.gives_side:
+                    sides.add(part.tag)
             return y
 
-        return self._layers(params, self._embed(params, tokens), run), entries
+        x = self._embed(params, tokens)
+        if not last_row or c.last_row_from is None:
+            return self._layers(params, x, run), entries
+        x = self._layers(params, x, run, stop=c.last_row_from)
+        # a side output is a row a position: the last row's; paged rows are
+        # read whole, by the one query that stands at ``last_idx``
+        x, row = lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1), last_idx
+        given = {tag: lax.dynamic_slice_in_dim(a, last_idx, 1, axis=1)
+                 if tag in sides else a for tag, a in given.items()}
+        return self._layers(params, x, run, start=c.last_row_from), entries
 
     def apply(self, params, tokens):
         """tokens (B, T) int32 -> logits (B, T, V) float32."""
@@ -1417,9 +1852,18 @@ class HybridLM:
     def prefill_cache(self, params, tokens, last_idx):
         """tokens (B, T_bucket), the prompt's last token at ``last_idx`` ->
         (logits of that token (B, 1, V), entries for :meth:`insert_paged`)."""
-        x, entries = self._trunk(params, tokens, last_idx)
-        last = lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
-        return self._head(params, last), entries
+        if self.config.last_row_from is None:
+            x, entries = self._trunk(params, tokens, last_idx)
+            x = lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
+        else:
+            x, entries = self._trunk(params, tokens, last_idx, last_row=True)
+        return self._head(params, x), entries
+
+    def prefill_tail_rows(self, bucket: int) -> int:
+        """Rows a prefill of ``bucket`` positions computes in its LAST
+        layer: 1 where the configuration runs its upper layers on the last
+        row alone, else the bucket."""
+        return 1 if self.config.last_row_from is not None else int(bucket)
 
     def entries_tokens(self, entries) -> int:
         """Positions a prefill's entries cover: the bucket's length where a
@@ -1484,6 +1928,7 @@ class HybridLM:
         occupied = tables[:, 0] != (pools[0].shape[0] - 1) if pools else None
         x = self._embed(params, tokens)
         out = {leaf.name: [] for leaf, _n in self.cache_leaves}
+        given: Dict[str, Any] = {}
         stats = jnp.zeros((len(self.step_stats),), jnp.int32)
 
         def run(part, rank, p, h32):
@@ -1498,9 +1943,11 @@ class HybridLM:
             y, *held = kind.decode(
                 self, p, h32.astype(c.dtype),
                 [arrays[n][rank] for n in names], tables, positions,
-                page_tokens)
+                page_tokens, given.get(part.source))
             for n, a in zip(names, held):
                 out[n].append(a)
+            if part.tag is not None:
+                given[part.tag] = self._handed_on(kind, held)
             return y
 
         x = self._layers(params, x, run)
@@ -1529,31 +1976,57 @@ def _ssm_leaves(c: HybridConfig):
 MIXERS: Dict[str, MixerKind] = {
     "kda": MixerKind(
         _kda_leaves,
-        lambda m, p, h, valid, last: m._kda_full(p, h, valid, last),
-        lambda m, p, h, held, tables, pos, pt: m._kda_decode(p, h, *held),
+        lambda m, p, h, valid, last, src, row: m._kda_full(p, h, valid, last),
+        lambda m, p, h, held, tables, pos, pt, src: m._kda_decode(p, h,
+                                                                  *held),
         HybridLM._init_kda),
     "mla": MixerKind(
         lambda c: (CacheLeaf("latent", True, (c.latent_row,), c.dtype),),
-        lambda m, p, h, valid, last: m._mla_full(p, h),
-        lambda m, p, h, held, tables, pos, pt: m._mla_decode(
+        lambda m, p, h, valid, last, src, row: m._mla_full(p, h),
+        lambda m, p, h, held, tables, pos, pt, src: m._mla_decode(
             p, h, *held, tables, pos, pt),
         HybridLM._init_mla),
     "mamba2": MixerKind(
         _ssm_leaves,
-        lambda m, p, h, valid, last: m._ssm_full(p, h, valid, last),
-        lambda m, p, h, held, tables, pos, pt: m._ssm_decode(p, h, *held),
+        lambda m, p, h, valid, last, src, row: m._ssm_full(p, h, valid, last),
+        lambda m, p, h, held, tables, pos, pt, src: m._ssm_decode(p, h,
+                                                                  *held),
         HybridLM._init_mamba2),
+    "mamba1": MixerKind(
+        lambda c: (CacheLeaf("m1_s", False, (c.m1_state, c.m1_inner),
+                             jnp.float32),
+                   CacheLeaf("m1_conv", False, (c.m1_conv - 1, c.m1_inner),
+                             c.dtype)),
+        lambda m, p, h, valid, last, src, row: m._m1_full(p, h, valid, last),
+        lambda m, p, h, held, tables, pos, pt, src: m._m1_decode(p, h,
+                                                                 *held),
+        HybridLM._init_mamba1, gives_side=True),
+    "gmu": MixerKind(
+        lambda c: (),
+        lambda m, p, h, valid, last, src, row: (m._gmu(p, h, src),),
+        lambda m, p, h, held, tables, pos, pt, src: (m._gmu(p, h, src),),
+        HybridLM._init_gmu, reads="side"),
     "gqa": MixerKind(
         lambda c: (CacheLeaf("kv", True, (c.gqa_kv_row,), c.dtype),),
-        lambda m, p, h, valid, last: m._gqa_full(p, h),
-        lambda m, p, h, held, tables, pos, pt: m._gqa_decode(
+        lambda m, p, h, valid, last, src, row: m._gqa_full(p, h),
+        lambda m, p, h, held, tables, pos, pt, src: m._gqa_decode(
             p, h, *held, tables, pos, pt),
         HybridLM._init_gqa),
     "swa": MixerKind(
         lambda c: (CacheLeaf("swa_kv", False, (c.swa_window, c.gqa_kv_row),
                              c.dtype),),
-        lambda m, p, h, valid, last: m._swa_full(p, h, last),
-        lambda m, p, h, held, tables, pos, pt: m._swa_decode(p, h, *held,
-                                                             pos),
-        lambda m, w, ones, resid: m._init_gqa(w, ones, resid, "swa")),
+        lambda m, p, h, valid, last, src, row: m._swa_full(p, h, last),
+        lambda m, p, h, held, tables, pos, pt, src: m._swa_decode(
+            p, h, *held, pos),
+        lambda m, w, ones, resid, layer: m._init_gqa(w, ones, resid, layer,
+                                                     "swa")),
+    "xattn": MixerKind(
+        lambda c: (),
+        lambda m, p, h, valid, last, src, row: m._gqa_full(
+            p, h, "xattn", src, row)[:1],
+        lambda m, p, h, held, tables, pos, pt, src: m._gqa_decode(
+            p, h, src, tables, pos, pt, "xattn")[:1],
+        lambda m, w, ones, resid, layer: m._init_gqa(w, ones, resid, layer,
+                                                     "xattn"),
+        reads="pages"),
 }

@@ -808,6 +808,13 @@ class TransformerLM:
     def max_positions(self) -> int:
         return self.config.max_len   # the learned position table
 
+    @property
+    def page_readers(self) -> int:
+        return self.config.n_layers  # every layer reads its own pages
+
+    def prefill_tail_rows(self, bucket: int) -> int:
+        return int(bucket)           # every layer runs on every row
+
     def prefill_cache(self, params, tokens, last_idx):
         """Every position's logits and k/v; keys and values of the padding
         beyond ``last_idx`` are masked by position until overwritten."""

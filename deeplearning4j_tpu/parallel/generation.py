@@ -440,6 +440,9 @@ class GenerationPipeline:
         # constants of this deployment, for the gauges every step publishes
         self._page_bytes = engine.page_bytes()
         self._slot_state_bytes = self.slots * engine.slot_state_bytes()
+        # layers that read the paged rows a step (a layer may read another
+        # layer's pages: rows read = live tokens x this, whatever is held)
+        self._page_readers = engine.model.page_readers
         # a popped request the pool couldn't back yet — retried at every
         # step boundary (pages free there) before the queue is touched
         self._waiting: Optional[_GenRequest] = None
@@ -1039,7 +1042,9 @@ class GenerationPipeline:
                             tokens=n_in, stalled_slots=stalled,
                             bucket=bucket, inflight=inflight,
                             step=self._step, dispatch_us=parts_us[0],
-                            insert_us=parts_us[1], fetch_us=parts_us[2])
+                            insert_us=parts_us[1], fetch_us=parts_us[2],
+                            tail_rows=self.engine.model.prefill_tail_rows(
+                                bucket))
             self._book_join(
                 obs, parts_us,
                 compiled=_cw.global_compile_watch().total != traced0,
@@ -1640,6 +1645,7 @@ class GenerationPipeline:
                     step_sp.set_attr("attn_pages", step.pages)
                     step_sp.set_attr("window_rows", step.window_rows)
                     step_sp.set_attr("cache_bytes", step.cache_bytes)
+                    step_sp.set_attr("page_readers", self._page_readers)
                     for name, n in counts.items():
                         step_sp.set_attr(name, n)
                     # the step's device outputs die here, inside the

@@ -114,7 +114,10 @@ def test_every_mixer_kind_declares_its_leaves(family):
              for k, kind in hybrid.MIXERS.items()}
     assert names == {"kda": ["kda_s", "kda_conv"], "mla": ["latent"],
                      "mamba2": ["ssm_s", "ssm_conv"], "gqa": ["kv"],
-                     "swa": ["swa_kv"]}       # the window kind's ring, PR 43
+                     "swa": ["swa_kv"],       # the window kind's ring, PR 43
+                     # PR 46: a per-channel scan, and two kinds that own
+                     # nothing and read another layer's rows
+                     "mamba1": ["m1_s", "m1_conv"], "gmu": [], "xattn": []}
     # this model keeps what its kinds own, the paged leaf first
     assert [(leaf.name, leaf.paged, n) for leaf, n in model.cache_leaves] \
         == [("kv", True, 1), ("ssm_s", False, 5), ("ssm_conv", False, 5)]

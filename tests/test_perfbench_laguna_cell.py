@@ -76,7 +76,10 @@ def test_benchmark_json_has_the_configuration_and_the_cell():
         ("device_trace", "kernels", "higher", "%"),
         ("device_trace", "kernels", "higher", "%"),
         ("program_span", "engine", "lower", "bytes")]
-    assert all(m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
+    # one cell when they came; only later PRs' cells stand behind it (PR
+    # 46's ``phi4flash-reasoning`` reports all five)
+    assert all(m["workloads"] in ([CELL], [CELL, "phi4flash-reasoning"])
+               and m["moves"] == "serve_tok_s"
                for m in bench["per_layer"][at:at + 5])
     # one four-chip cell in eight: inside the quarter the contract allows
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1

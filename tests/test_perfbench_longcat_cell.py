@@ -65,7 +65,8 @@ def test_benchmark_json_has_the_configuration_and_the_cell():
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", []):
             behind = m["workloads"][m["workloads"].index(CELL) + 1:]
-            assert behind in ([], ["laguna-codegen"])
+            assert behind in ([], ["laguna-codegen"], ["phi4flash-reasoning"],
+                              ["laguna-codegen", "phi4flash-reasoning"])
             assert m.get("moves", "serve_tok_s") == "serve_tok_s"
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(OWN[0])
@@ -74,7 +75,10 @@ def test_benchmark_json_has_the_configuration_and_the_cell():
     assert names[at + 3 + len(LATER) + 1:] == [     # + join_hold_dev_pct.*
         "join_hold_dev_pct.lat", "attn_window_dev_pct.tput",
         "attn_full_dev_pct.tput", "attn_window_roofline_pct.tput",
-        "attn_full_roofline_pct.tput", "cache_bytes_per_live_token.tput"]
+        "attn_full_roofline_pct.tput", "cache_bytes_per_live_token.tput",
+        # PR 46: the query-only attention and the memory units
+        "attn_cross_dev_pct.tput", "attn_cross_roofline_pct.tput",
+        "gmu_dev_pct.tput"]
     assert [(m["source"], m["layer"])
             for m in bench["per_layer"][at:at + 3]] == [
         ("program_span", "engine"), ("device_trace", "model"),

@@ -417,15 +417,23 @@ def test_hybrid_and_the_expert_layer_use_the_vocabulary_and_inner_names():
     one of the inner names. The two grouped-query kinds share their code, so
     their ``<kind>_<what>`` names come from ``hybrid._scope``."""
     from deeplearning4j_tpu.models import hybrid
-    from perfbench.layer_metrics import _inner, _laguna, _longcat, _nemotron
+    from perfbench.layer_metrics import (_inner, _laguna, _longcat,
+                                         _nemotron, _phi4flash)
     used = {hybrid._scope(kind, what) for kind in ("gqa", "swa")
             for what in hybrid._ATTN_SCOPES}
+    # the query-only kind (PR 46) shares that code and never rotates
+    used |= {hybrid._scope("xattn", what) for what in ("proj", "attend")}
     for rel in ("models/hybrid.py", "parallel/moe.py"):
         with open(os.path.join(ROOT, "deeplearning4j_tpu", rel)) as f:
             used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    inner = _inner.INNER | _nemotron.NAMES | _longcat.NAMES | _laguna.NAMES
+    # PR 46's reader: the query-only attention and the memory unit, and the
+    # differential subtraction, nested in a kind's attend and read with it
+    inner = _inner.INNER | _nemotron.NAMES | _longcat.NAMES | _laguna.NAMES \
+        | _phi4flash.NAMES | _phi4flash.NESTED
     assert used <= _named.SCOPES | inner
     assert used >= inner
+    assert _phi4flash.NAMES == {"xattn_proj", "xattn_attend", "gmu"}
+    assert _phi4flash.NESTED == {"attn_diff"}
     assert _longcat.NAMES == {"ffn_dense", "mla_rope", "moe_zero"}
     assert _nemotron.NAMES == {
         "ssm_proj", "ssm_conv", "ssm_state", "ssm_out", "gqa_proj",
