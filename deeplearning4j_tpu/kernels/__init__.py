@@ -17,7 +17,8 @@ the same way: one query a slot against the slot's live pages of latent rows,
 fetched from the pool where they lie by the kernel's own asynchronous copies,
 eight pages a visit, with an online softmax; no gathered window; the same
 walk with two products a key/value head serves grouped-query rows ``[k heads
-| v heads]``, ``paged_grouped_attention``).
+| v heads]``, ``paged_grouped_attention``: a full layer's pages and, taken
+as a slot's own pages in order, a window layer's ring).
 """
 from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
                                                         flash_attention_bthd)

@@ -56,7 +56,10 @@ pages shared through the engine's allocator and tables: ``latent``, ``kv``)
 or a fixed state a slot (``kda_s``, ``kda_conv``, ``ssm_s``, ``ssm_conv``,
 and ``swa_kv``: a window layer's last ``swa_window`` rows, a ring written at
 ``position % swa_window``, so that what it holds a slot does not grow with
-the slot's context), shape and dtype - and its full and one-step functions.
+the slot's context; a decode step traced for the TPU reads it where it lies
+through the page walk the paged layers take, the ring being the slot's own
+pages in order, :func:`ring_attention_backend`), shape and dtype - and its
+full and one-step functions.
 The protocol's methods walk the layers over that table and name no leaf; each
 layer's array is a leaf of the cache of its own, so that a step rewrites it
 in place. **A kind may own nothing and read what an earlier part hands on**
@@ -84,7 +87,9 @@ differential subtraction and its sub-norm, inside a kind's ``*_attend``),
 ``moe_zero``, the identity experts' weighted copy), ``moe_latent``. One log
 line a trace, ``layer kinds: ...``, names the layers' parts (what a part hands
 on behind ``=``, what it reads behind ``<``), each attention kind's heads,
-rotation, gate, form and window, the experts' form and the router.
+rotation, gate, form and window, the experts' form and the router; and one
+a kind that attends over a cache in a decode step, ``attention backend:
+<kind>: <choice>: <why>``, which ``attention_backend`` keeps by kind.
 """
 from __future__ import annotations
 
@@ -813,6 +818,21 @@ def latent_attention_backend(heads: int, row: int, out_width: int,
 GATHER_VIEW_BYTES = 1 << 30
 
 
+def _grouped_visit_too_large(heads: int, kv_heads: int, row: int,
+                             page_tokens: int, pages: int,
+                             itemsize: int) -> Optional[str]:
+    """Why a visit of the grouped-query page walk does not fit the kernel's
+    VMEM, None where it does; loads the kernel's module."""
+    from deeplearning4j_tpu.kernels import paged_latent_attention as kernel
+    rows_q = kernel.grouped_query_rows(heads // kv_heads, itemsize) * kv_heads
+    visit = kernel.GROUPED_VISIT_BYTES
+    if kernel.fits_vmem(rows_q, row, page_tokens, pages, itemsize, visit):
+        return None
+    n = kernel.visit_pages(page_tokens, row, itemsize, pages, visit)
+    return (f"a visit of {n * page_tokens} rows of {row} is more than the "
+            "kernel's VMEM")
+
+
 def grouped_attention_backend(slots: int, heads: int, kv_heads: int,
                               head_dim: int, page_tokens: int, pages: int,
                               itemsize: int) -> Tuple[str, str]:
@@ -838,15 +858,53 @@ def grouped_attention_backend(slots: int, heads: int, kv_heads: int,
     if view <= GATHER_VIEW_BYTES:
         return "gather", (f"the view of every slot's window is "
                           f"{view / 2**20:.0f} MiB")
-    from deeplearning4j_tpu.kernels import paged_latent_attention as kernel
-    rows_q = kernel.grouped_query_rows(heads // kv_heads, itemsize) * kv_heads
-    visit = kernel.GROUPED_VISIT_BYTES
-    if not kernel.fits_vmem(rows_q, row, page_tokens, pages, itemsize, visit):
-        n = kernel.visit_pages(page_tokens, row, itemsize, pages, visit)
-        return "gather", (f"a visit of {n * page_tokens} rows of {row} is "
-                          "more than the kernel's VMEM")
+    too_large = _grouped_visit_too_large(heads, kv_heads, row, page_tokens,
+                                         pages, itemsize)
+    if too_large:
+        return "gather", too_large
     return "paged-grouped", (f"live pages of {page_tokens} rows of {row} "
                              "read where they lie")
+
+
+#: rows of a ring that the page walk takes as one page: pages of 64, 128 and
+#: 256 rows read alike on the chip (PERF.md section 6, PR 47), and a slot
+#: younger than the window fetches whole pages
+RING_PAGE_ROWS = 64
+
+
+def _ring_page(window: int) -> int:
+    """Rows a page of a ring of ``window`` rows: a shorter ring is one."""
+    return min(RING_PAGE_ROWS, window)
+
+
+def ring_attention_backend(heads: int, kv_heads: int, head_dim: int,
+                           window: int, itemsize: int) -> Tuple[str, str]:
+    """(``paged-grouped`` | ``xla``, why) for the window kind's attention of
+    a decode step: ``heads`` queries a slot on ``kv_heads`` key/value heads
+    against the slot's ring of ``window`` rows ``[k heads | v heads]``. The
+    ring of every slot, reshaped, IS a pool whose slot b owns pages ``b n ..
+    (b + 1) n - 1`` of ``RING_PAGE_ROWS`` rows in order, so the kernel that
+    walks a full layer's pages reads it where it lies, where the program is
+    traced for the TPU, a head is whole tiles of 128 lanes, the window whole
+    pages of whole 8-row tiles and a visit fits the kernel's VMEM; the two
+    einsums everywhere else. Nothing is gathered in either spelling, so no
+    view's size is asked. Consulted at trace time only, as
+    :func:`latent_attention_backend` is."""
+    if not _on_tpu():
+        return "xla", f"on {jax.default_backend()}"
+    row = 2 * kv_heads * head_dim
+    if head_dim % 128:
+        return "xla", f"a head of {head_dim} is not whole tiles of 128 lanes"
+    page = _ring_page(window)
+    if page % 8 or window % page:
+        return "xla", (f"a ring of {window} rows is not whole pages of "
+                       f"{page} rows in whole tiles of 8")
+    too_large = _grouped_visit_too_large(heads, kv_heads, row, page,
+                                         window // page, itemsize)
+    if too_large:
+        return "xla", too_large
+    return "paged-grouped", (f"live pages of {page} rows of {row} of a ring "
+                             f"of {window} read where they lie")
 
 
 class HybridLM:
@@ -892,10 +950,11 @@ class HybridLM:
             and (MIXERS[p.kind].reads == "pages"
                  or MIXERS[p.kind].keeps_pages(c)))
         self._said: Dict[str, Any] = {}
-        #: (choice, why) that the last trace of an attention over pages took
-        #: (:func:`latent_attention_backend`,
-        #: :func:`grouped_attention_backend`), None before any
-        self.attention_backend: Optional[Tuple[str, str]] = None
+        #: kind -> (choice, why) that the last trace of that kind's attention
+        #: of a decode step took (:func:`latent_attention_backend`,
+        #: :func:`grouped_attention_backend`,
+        #: :func:`ring_attention_backend`), empty before any
+        self.attention_backend: Dict[str, Tuple[str, str]] = {}
         #: positions a window layer reads and keeps a slot, None without one
         self.cache_window = c.swa_window if "swa" in seen else None
 
@@ -1269,11 +1328,9 @@ class HybridLM:
         with jax.named_scope("kv_write"):
             page = _slot_page(tables, positions, P, pool.shape[0])
             pool = pool.at[page, positions % P].set(row)
-        self.attention_backend = latent_attention_backend(
-            c.mla_heads, c.latent_row, R, P, tables.shape[1],
-            jnp.dtype(c.dtype).itemsize)
-        self._say_once("attention backend", *self.attention_backend)
-        if self.attention_backend[0] == "paged-latent":
+        if self._took("mla", latent_attention_backend(
+                c.mla_heads, c.latent_row, R, P, tables.shape[1],
+                jnp.dtype(c.dtype).itemsize)) == "paged-latent":
             from deeplearning4j_tpu.kernels.paged_latent_attention import \
                 paged_latent_attention
             with jax.named_scope("attn_core"), jax.named_scope("mla_attend"):
@@ -1651,11 +1708,9 @@ class HybridLM:
             with jax.named_scope("kv_write"):
                 page = _slot_page(tables, positions, P, pool.shape[0])
                 pool = pool.at[page, positions % P].set(row)
-        self.attention_backend = grouped_attention_backend(
-            B, g * k_rows, g, hd, P, tables.shape[1],
-            jnp.dtype(c.dtype).itemsize)
-        self._say_once("attention backend", *self.attention_backend)
-        if self.attention_backend[0] == "paged-grouped":
+        if self._took(kind, grouped_attention_backend(
+                B, g * k_rows, g, hd, P, tables.shape[1],
+                jnp.dtype(c.dtype).itemsize)) == "paged-grouped":
             from deeplearning4j_tpu.kernels.paged_latent_attention import \
                 paged_grouped_attention
             with jax.named_scope("attn_core"), jax.named_scope(
@@ -1686,28 +1741,46 @@ class HybridLM:
         """h (B, d) against the slot's ring of K and V rows (B, window,
         2 Hkv hd). The step's own row goes to ``position % window``, over
         the row that has just left the window; the ring is then read whole,
-        under a mask for a slot younger than the window. Every key carries
-        its own position's rotation, so the ring's order does not matter."""
+        under a mask for a slot younger than the window: by the kernel that
+        walks a full layer's pages, the ring taken as the slot's own pages
+        in order, which clamps a position to the window and visits and
+        masks what :func:`_ring_live` keeps
+        (``kernels/paged_latent_attention.py::paged_grouped_attention``),
+        or, everywhere :func:`ring_attention_backend` does not take it, by
+        two einsums. Every key carries its own position's rotation, so the
+        ring's order does not matter."""
         c = self.config
-        W = c.swa_window
+        B, W = h.shape[0], c.swa_window
+        scale = c.gqa_head_dim ** -0.5
+        g, k_rows, hd = c.attention_shape("swa")
         with jax.named_scope("attn_qkv"):
             q, row, gate = self._gqa_project(p, h, "swa", positions)
         with jax.named_scope("kv_write"), jax.named_scope("swa_write"):
-            ring = ring.at[jnp.arange(h.shape[0]), positions % W].set(row)
+            ring = ring.at[jnp.arange(B), positions % W].set(row)
+        took = self._took("swa", ring_attention_backend(
+            g * k_rows, g, hd, W, jnp.dtype(c.dtype).itemsize))
         with jax.named_scope("attn_core"), jax.named_scope("swa_attend"):
-            k, v = self._gqa_kv(ring, "swa")
-            s = jnp.einsum("bgkd,bsgd->bgks", q, k,
-                           preferred_element_type=jnp.float32) \
-                * c.gqa_head_dim ** -0.5
-            live = _ring_live(W, positions)
-            pr = jax.nn.softmax(jnp.where(live[:, None, None, :], s, -1e30),
-                                axis=-1).astype(c.dtype)
-            o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
-                           preferred_element_type=jnp.float32).astype(c.dtype)
+            if took == "paged-grouped":
+                from deeplearning4j_tpu.kernels.paged_latent_attention \
+                    import paged_grouped_attention
+                P = _ring_page(W)           # slot b: pages b W / P ..
+                o = paged_grouped_attention(
+                    q, ring.reshape(B * W // P, P, c.gqa_kv_row),
+                    jnp.arange(B * W // P, dtype=jnp.int32).reshape(B, -1),
+                    positions, scale)
+            else:
+                k, v = self._gqa_kv(ring, "swa")
+                s = jnp.einsum("bgkd,bsgd->bgks", q, k,
+                               preferred_element_type=jnp.float32) * scale
+                live = _ring_live(W, positions)
+                pr = jax.nn.softmax(jnp.where(live[:, None, None, :], s,
+                                              -1e30), axis=-1).astype(c.dtype)
+                o = jnp.einsum("bgks,bsgd->bgkd", pr, v,
+                               preferred_element_type=jnp.float32
+                               ).astype(c.dtype)
             if c.differential:
                 o = self._attn_diff(p, o)
-        return self._gqa_out(p, o.reshape(h.shape[0], -1), gate,
-                             "swa"), ring
+        return self._gqa_out(p, o.reshape(B, -1), gate, "swa"), ring
 
     # ------------------------------------------------------ full forward
     def _say_once(self, subject, choice, why):
@@ -1718,6 +1791,14 @@ class HybridLM:
             self._said[subject] = said
             logging.getLogger(__name__).info("%s: %s: %s", subject, choice,
                                              why)
+
+    def _took(self, kind, took):
+        """Keeps and says what a trace of ``kind``'s attention of a decode
+        step took, ``attention backend: <kind>: <choice>: <why>``: its
+        choice."""
+        self.attention_backend[kind] = took
+        self._say_once(f"attention backend: {kind}", *took)
+        return took[0]
 
     def _say_layers(self):
         """``layer kinds: <a layer's parts, joined by +, a layer>: <the

@@ -1777,8 +1777,8 @@ class GenerationPipeline:
         flight-recorder ``generation.json`` payload)."""
         slots = []
         tenants: dict = {}
-        # (choice, why) that the decode program's trace took, where the model
-        # chooses at trace time (``HybridLM.attention_backend``)
+        # kind -> (choice, why) that the decode program's trace took, where
+        # the model chooses at trace time (``HybridLM.attention_backend``)
         took = getattr(self.engine.model, "attention_backend", None)
         for i, req in enumerate(self._slot_req):
             if req is None:
@@ -1846,7 +1846,9 @@ class GenerationPipeline:
                       "longest": self._join_longest},
             "max_len": self.engine.max_len,
             "prefill_buckets": list(self.engine.prefill_buckets),
-            "attention_backend": took and "%s: %s" % took,
+            "attention_backend": "; ".join(
+                "%s: %s: %s" % (kind, *said)
+                for kind, said in took.items()) if took else None,
             "sampler": {"kind": self.engine.sampler.kind,
                         "top_k": self.engine.sampler.top_k,
                         "temperature": self.engine.sampler.temperature},

@@ -387,3 +387,60 @@ def test_paged_latent_attention_compiles_for_the_v5e_at_published_shapes(
     ).compile().as_text()
     assert len([line for line in text.splitlines()
                 if "tpu_custom_call" in line]) == 1
+
+
+@pytest.mark.parametrize("cell", ["laguna-codegen", "phi4flash-reasoning"])
+def test_the_ring_walk_compiles_for_the_v5e_at_published_widths(
+        cell, one_chip, monkeypatch):
+    """A window layer's decode step (``HybridLM._swa_decode``) of the two
+    cells that have one, 64 slots over rings of 512 rows in bfloat16, traced
+    for the TPU and compiled for a described v5e: 64 heads on 8 key/value
+    heads of 128, a row of 2,048 (``laguna-codegen``), and 40 heads of 64 on
+    20 as four query rows on each of 10 pairs of 128, a row of 2,560
+    (``phi4flash-reasoning``). Mosaic takes the page walk over the ring
+    (pages of 64 rows, 256 and 192 rows a visit), the ring is written and
+    read in place (the reshape to pages is no copy: nothing temporary of a
+    ring's size), and the compiled call's ``op_name`` lies under
+    ``attn_core/swa_attend``, where the window kind's readers look."""
+    from deeplearning4j_tpu.models import hybrid
+    family, name, row, visit = {
+        "laguna-codegen": ("laguna", "laguna-xs2-33b-a3b-stage5.json", 2048,
+                           4),
+        "phi4flash-reasoning": ("phi4flash",
+                                "phi-4-mini-flash-reasoning.json", 2560, 3)
+    }[cell]
+    from deeplearning4j_tpu.kernels import paged_latent_attention as pla
+    adapter = harness.load_module("models", family + ".py")
+    cfg = harness.load_json("configs", name)
+    model = adapter.build_model(cfg)
+    c = model.config
+    assert (c.swa_window, c.gqa_kv_row) == (512, row)
+    assert pla.visit_pages(hybrid.RING_PAGE_ROWS, row, 2, 8,
+                           pla.GROUPED_VISIT_BYTES) == visit
+    layer = [i for i, spec in enumerate(c.layers)
+             if any(part.kind == "swa" for part in spec.parts)][0]
+    block = adapter.weight_shapes(cfg)["blocks"][layer]
+    mixer = [v for v in block.values()
+             if isinstance(v, dict) and "w_kv" in v][0]
+
+    def s(*shape, dtype=c.dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    p = jax.tree.map(lambda a: s(*a.shape, dtype=a.dtype), mixer)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(model._swa_decode, donate_argnums=(2,)).lower(
+        p, s(SLOTS, c.d_model), s(SLOTS, 512, row),
+        s(SLOTS, dtype=jnp.int32)).compile()
+    assert model.attention_backend == {"swa": (
+        "paged-grouped", f"live pages of 64 rows of {row} of a ring of 512 "
+        "read where they lie")}
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*/attn_core/swa_attend/'
+                     r'jit\(_paged_grouped_attention\)/pallas_call"',
+                     calls[0]), calls[0][-400:]
+    ring = SLOTS * 512 * row * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == ring
+    assert mem.temp_size_in_bytes < ring // 8
+

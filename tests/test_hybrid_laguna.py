@@ -318,17 +318,44 @@ def _gap(runs, params, cfg):
     return worst
 
 
+@pytest.mark.parametrize("rings", ["xla", "paged-grouped"])
 def test_prefill_then_decode_through_pages_and_rings_is_the_full_forward(
-        family):
+        family, rings, monkeypatch, caplog):
     """Every step's LOGITS of every occupied slot against the reference's
     full forward over prompt + served tokens (``PLAN``): contexts several
     windows deep, page boundaries, a bucket's padding, joins and leaves, and
-    slots of different ages in one step."""
+    slots of different ages in one step. In both spellings of the window
+    layers' attend: the two einsums (what the CPU gets) and the page walk
+    over a slot's ring (interpret mode here; the kernel reads heads in whole
+    tiles of 128 lanes, so the same stage with heads of 128, and its window
+    of 8 rows as one page)."""
     cfg, model, params = family
-    runs, state = _serve(_engine(model, params, cfg), cfg, PLAN, 40)
+    if rings == "paged-grouped":
+        cfg = _cfg(head_dim=128)
+        params = LM.make_weights(cfg, 3)
+        monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
+    model = LM.build_model(cfg)         # nothing traced, nothing said
+    with caplog.at_level(logging.INFO, logger=hybrid.__name__):
+        runs, state = _serve(_engine(model, params, cfg), cfg, PLAN, 40)
     assert _gap(runs, params, cfg) < TOL
+    assert model.attention_backend["swa"][0] == rings
+    assert model.attention_backend["gqa"][0] == "gather"
+    # one line a kind a trace of the decode program, three window layers
+    # and two full ones or not
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("attention backend: ")]
+    swa = "attention backend: swa: " + {
+        "xla": "xla: on cpu",
+        "paged-grouped": "paged-grouped: live pages of 8 rows of 512 of a "
+                         "ring of 8 read where they lie"}[rings]
+    gqa = "attention backend: gqa: gather: " + (
+        "on cpu" if rings == "xla" else "the view of every slot's window is "
+        "1 MiB")
+    assert 1 <= said.count(swa) == said.count(gqa) <= 2
+    assert set(said) == {swa, gqa}
     # what a window layer holds a slot is the window, whatever the context
-    assert [a.shape for a in state.arrays["swa_kv"]] == [(4, 8, 128)] * 3
+    row = 4 * cfg["head_dim"]
+    assert [a.shape for a in state.arrays["swa_kv"]] == [(4, 8, row)] * 3
     assert len(state.arrays["kv"]) == 2
 
 
@@ -559,13 +586,13 @@ def test_kernel_equals_the_gathered_window(positions, visit, monkeypatch):
     h, pool, tables, pos, trash = _step(positions)
     want, pool0 = model._gqa_decode(p, h, pool.at[trash].set(0), tables, pos,
                                     8)
-    assert model.attention_backend == ("gather", "on cpu")
+    assert model.attention_backend == {"gqa": ("gather", "on cpu")}
     monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
     monkeypatch.setattr(hybrid, "GATHER_VIEW_BYTES", 0)
     monkeypatch.setattr(pla, "GROUPED_VISIT_BYTES", visit * 8 * 512 * 4)
     got, pool1 = model._gqa_decode(p, h, pool.at[trash].set(jnp.nan), tables,
                                    pos, 8)
-    assert model.attention_backend[0] == "paged-grouped"
+    assert model.attention_backend["gqa"][0] == "paged-grouped"
     assert not bool(jnp.isnan(got).any())
     assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * max(
         1.0, float(jnp.max(jnp.abs(want))))
@@ -617,6 +644,132 @@ def test_refusals_of_the_grouped_entry():
                 (q, pool, t[:1], pos), (q, pool, t, pos[:1])):
         with pytest.raises(ValueError, match="not one paged layer"):
             pla.paged_grouped_attention(*bad, 1.0)
+
+
+# ------------------------------------------ the window kind's page walk
+def _window(dtype=jnp.float32, window=24):
+    """One gated, rotated window layer of 6 heads on 2 key/value heads of
+    128 over a ring of ``window`` rows."""
+    cfg = HybridConfig(
+        vocab_size=64, d_model=64, layers=(LayerSpec("swa", "dense"),),
+        max_len=256, gqa_kv_heads=2, gqa_head_dim=128, swa_heads=6,
+        swa_window=window, swa_rope=Rope(theta=1e4, dims=128),
+        swa_gated=True, dense_ff=64, dtype=dtype, param_dtype=dtype)
+    model = HybridLM(cfg)
+    p = model.init_params(jax.random.key(6))["blocks"][0]["mixer"]
+    return model, jax.tree.map(lambda a: (10 * a).astype(dtype), p)
+
+
+def _ring_step(model, positions, dtype=jnp.float32):
+    c = model.config
+    ks = jax.random.split(jax.random.key(0), 2)
+    ring = jax.random.normal(
+        ks[0], (len(positions), c.swa_window, c.gqa_kv_row)).astype(dtype)
+    return (jax.random.normal(ks[1], (len(positions), 64)).astype(dtype),
+            ring, jnp.asarray(positions, jnp.int32))
+
+
+#: a window of 24 rows in pages of 8: slots younger than the window (one
+#: inside its first page, one at a page's last row, one at a page's first),
+#: exactly as old (position 23 fills the last row), one row past it (24
+#: overwrites row 0) and several turns past it
+AGES = (0, 7, 8, 23, 24, 25, 100, 191)
+
+
+@pytest.mark.parametrize("visit", [1, 2, 3])
+def test_the_walk_over_a_ring_equals_the_two_einsums(visit, monkeypatch):
+    """float32: the window layer's step through the page walk is the XLA
+    spelling's to summation noise, slots of every age in ONE batch; a page
+    of a young slot's ring beyond its position (NaN here) is never fetched;
+    the ring comes back with the step's row at ``position % window`` and
+    nothing else changed."""
+    model, p = _window()
+    h, ring, pos = _ring_step(model, AGES)
+    want, ring0 = model._swa_decode(p, h, ring, pos)
+    assert model.attention_backend == {"swa": ("xla", "on cpu")}
+    monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
+    monkeypatch.setattr(hybrid, "RING_PAGE_ROWS", 8)
+    monkeypatch.setattr(pla, "GROUPED_VISIT_BYTES", visit * 8 * 512 * 4)
+    beyond = (np.arange(24)[None, :] // 8
+              > np.minimum(np.asarray(AGES), 23)[:, None] // 8)
+    assert beyond.sum(axis=1).tolist() == [16, 16, 8, 0, 0, 0, 0, 0]
+    dead = jnp.where(beyond[:, :, None], jnp.nan, ring)
+    got, ring1 = model._swa_decode(p, h, dead, pos)
+    assert model.attention_backend == {"swa": (
+        "paged-grouped",
+        "live pages of 8 rows of 512 of a ring of 24 read where they lie")}
+    assert not bool(jnp.isnan(got).any())
+    assert float(jnp.max(jnp.abs(want))) > 1.0
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(
+        jnp.max(jnp.abs(want)))
+    # the ring: the step's row where it belongs, every other row as it came
+    at = np.asarray(AGES) % 24
+    ring0, ring1 = np.asarray(ring0), np.asarray(ring1)
+    np.testing.assert_array_equal(ring1[~beyond], ring0[~beyond])
+    assert np.isnan(ring1[beyond]).all()
+    kept = np.ones((len(AGES), 24), bool)
+    kept[np.arange(len(AGES)), at] = False
+    np.testing.assert_array_equal(ring0[kept], np.asarray(ring)[kept])
+    assert np.abs(ring0[~kept] - np.asarray(ring)[~kept]).max() > 0.5
+
+
+def test_the_walk_over_a_ring_in_bfloat16_is_the_einsums_in_bfloat16(
+        monkeypatch):
+    """16 query rows a key/value head (a bfloat16 tile) where 3 are real;
+    one page of 16 rows a visit."""
+    model, p = _window(jnp.bfloat16, window=32)
+    h, ring, pos = _ring_step(model, (5, 31, 32, 33, 500), jnp.bfloat16)
+    want, ring0 = model._swa_decode(p, h, ring, pos)
+    monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
+    monkeypatch.setattr(hybrid, "RING_PAGE_ROWS", 16)
+    monkeypatch.setattr(pla, "GROUPED_VISIT_BYTES", 16 * 512 * 2)
+    got, ring1 = model._swa_decode(p, h, ring, pos)
+    assert model.attention_backend["swa"][0] == "paged-grouped"
+    np.testing.assert_array_equal(np.asarray(ring1, np.float32),
+                                  np.asarray(ring0, np.float32))
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                - want.astype(jnp.float32))))
+    # one bfloat16 rounding of outputs of order 1
+    assert err < 2e-2 * max(1.0, float(jnp.max(jnp.abs(want))))
+
+
+def test_the_rings_choice_and_its_reasons(monkeypatch):
+    """Decided from what a trace can see, the reason in the line. The two
+    cells' window layers (64 slots a step): ``laguna-codegen``'s 64 heads on
+    8 key/value heads of 128 and ``phi4flash-reasoning``'s 40 heads of 64 on
+    20, which the products see as 4 query rows on each of 10 pairs of 128
+    (``HybridConfig.attention_shape``); rings of 512 rows, bfloat16."""
+    laguna, phi = (64, 8, 128, 512, 2), (40, 10, 128, 512, 2)
+    for args in (laguna, phi):
+        assert hybrid.ring_attention_backend(*args) == ("xla", "on cpu")
+    monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
+    assert hybrid.ring_attention_backend(*laguna) == (
+        "paged-grouped",
+        "live pages of 64 rows of 2048 of a ring of 512 read where they lie")
+    assert hybrid.ring_attention_backend(*phi) == (
+        "paged-grouped",
+        "live pages of 64 rows of 2560 of a ring of 512 read where they lie")
+    # what reaches the chooser is what the configurations publish
+    for name, adapter, args in (
+            (FILE, LM, laguna),
+            ("phi-4-mini-flash-reasoning.json",
+             harness.load_module("models", "phi4flash.py"), phi)):
+        c = adapter.build_model(_load(name, rehearsal=False)).config
+        g, k, hd = c.attention_shape("swa")
+        assert (g * k, g, hd, c.swa_window,
+                jnp.dtype(c.dtype).itemsize) == args
+    why = lambda *a: hybrid.ring_attention_backend(*a)[1]   # noqa: E731
+    # 40 heads of 64 that are NOT pairs: a head is half a tile
+    assert why(40, 20, 64, 512, 2) == ("a head of 64 is not whole tiles of "
+                                       "128 lanes")
+    assert "whole pages of 64 rows" in why(64, 8, 128, 500, 2)
+    assert "whole pages of 12 rows" in why(64, 8, 128, 12, 2)
+    # a window of 8 rows is one page of its own
+    assert hybrid.ring_attention_backend(6, 2, 128, 8, 4)[0] \
+        == "paged-grouped"
+    monkeypatch.setattr(hybrid, "RING_PAGE_ROWS", 4096)
+    assert why(64, 8, 128, 8192, 2) == (
+        "a visit of 4096 rows of 2048 is more than the kernel's VMEM")
 
 
 # ---------------------------- the fourth configuration's layer, bit for bit

@@ -20,6 +20,7 @@ import hashlib
 import json
 import logging
 import os
+import re
 import sys
 
 import jax
@@ -594,9 +595,11 @@ def _sha(text: bytes):
     return hashlib.sha256(text).hexdigest()
 
 
-@pytest.mark.parametrize("program", ["apply", "decode", "prefill", "insert"])
-def test_the_other_families_programs_are_the_parents(other_family, program):
-    cfg, adapter, model, want = other_family
+def _lowered(cfg, adapter, model, program):
+    """The text, lowered for the TPU, of one program of ``model`` over the
+    adapter's weight shapes: the full forward over (2, 40) tokens, a
+    32-token prefill bucket's, or the decode step's or the insert's over 4
+    slots and 21 pages of 8 rows."""
     shapes = adapter.weight_shapes(cfg)
     slots, pages, pt = 4, 21, 8
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)     # noqa: E731
@@ -618,9 +621,15 @@ def test_the_other_families_programs_are_the_parents(other_family, program):
                 lambda p, t: model.prefill_cache(p, t, 3)[1], shapes,
                 i32(1, 32))
             traced = eng._insert_paged_jit.trace(cache, ent, i32(4), i32())
-    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    return traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("program", ["apply", "decode", "prefill", "insert"])
+def test_the_other_families_programs_are_the_parents(other_family, program):
+    cfg, adapter, model, want = other_family
+    text = _lowered(cfg, adapter, model, program)
     assert _sha(text.encode()) == want[program]
-    assert len(eng.model.step_stats) == 4       # no fifth count rides along
+    assert len(model.step_stats) == 4           # no fifth count rides along
 
 
 def test_the_other_families_logits_are_the_parents(other_family, request):
@@ -643,3 +652,80 @@ def test_the_other_families_logits_are_the_parents(other_family, request):
                                       "hybrid_parent_logits.npz"))
         fam = request.node.callspec.params["other_family"]
         assert np.abs(got[:, -1] - stored[fam]).max() < 0.02
+
+# ------------------- the families without a window kind, letter for letter
+#: family -> (its file, what lets the latent attention's kernel in where the
+#: trace is for the TPU: a latent rank of whole 128-lane tiles)
+WINDOWLESS = {
+    "kimi_linear": ("kimi-linear-48b-a3b-ep2share.json",
+                    {"kv_lora_rank": 128}),
+    "nemotron_h": ("nemotron-3-super-120b-a12b-ep4share.json", {}),
+    "longcat_flash": (FILE, {"kv_lora_rank": 128}),
+}
+#: what the parent commit (d2735bf, PR 46) lowers the decode program and a
+#: prefill bucket's of the three configurations WITHOUT a window layer to,
+#: at their rehearsal sizes in their own bfloat16, by the digest of the text
+#: lowered for the TPU: as this process's backend names itself (``cpu``: the
+#: gathered window), and with ``jax.default_backend`` patched to ``tpu`` and
+#: ``WINDOWLESS``'s widths (the second and fifth families' decode programs
+#: then hold ``paged_latent_attention``'s kernel; the fourth's grouped-query
+#: layer keeps the gather). PR 47 gave the window kind's rings the page walk
+#: and every kind a line of its own in ``attention_backend``; these
+#: programs' text, and with it their compile-cache keys and the three
+#: cells' ``setup_s``, did not move. A kernel's serialised body carries its
+#: source file's PATH, which differs from checkout to checkout, so a
+#: custom call's ``backend_config`` is set aside before the digest
+#: (``kernels/paged_latent_attention.py`` itself is the parent's file).
+PARENT_47 = {
+    ("kimi_linear", "decode", "cpu"):
+    "d2bbb869e760c4f33f8dbd4fc286fd25ccbcfa4084801c84c2adc8cac5501587",
+    ("kimi_linear", "prefill", "cpu"):
+    "aa6175ec581566d8e637ffa1ef5b9953aece1757934fed9106d5107859ed294a",
+    ("kimi_linear", "decode", "tpu"):
+    "a5b9de5040eeea31e577285829267a52f8868b6daff6dce26af10d4ea2de433f",
+    ("kimi_linear", "prefill", "tpu"):
+    "b48fa8c835817af19e16bcd91fad3b19d0f107b28a82bd36bb121869c7c47d8b",
+    ("nemotron_h", "decode", "cpu"):
+    "e954a34f5de8264c8de598b224bfd1b284e0bb4ada309121f41ce6c01131968f",
+    ("nemotron_h", "prefill", "cpu"):
+    "aa7ba9a5e83f3f2aae4da32629208820255e12ad777d04a6ba0a8b189f05a6b7",
+    ("nemotron_h", "decode", "tpu"):
+    "e954a34f5de8264c8de598b224bfd1b284e0bb4ada309121f41ce6c01131968f",
+    ("nemotron_h", "prefill", "tpu"):
+    "aa7ba9a5e83f3f2aae4da32629208820255e12ad777d04a6ba0a8b189f05a6b7",
+    ("longcat_flash", "decode", "cpu"):
+    "4c2402899796976b61bf715023cf996b8d89a6775bc308e92daac39fe29a6022",
+    ("longcat_flash", "prefill", "cpu"):
+    "cb35140bac467f4a18d9395889f66a245264c55d138e5a959417bf2a7ed26665",
+    ("longcat_flash", "decode", "tpu"):
+    "e173e4a7527e90b5d993972b77c95c585425355fe6a3cd2d34e7f27eb67316f4",
+    ("longcat_flash", "prefill", "tpu"):
+    "da7f81847acc5368561d607bbaf98684f81928684f02a8574c07a9cad8b2dca9",
+}
+
+
+@pytest.mark.parametrize("family_program_backend", sorted(PARENT_47),
+                         ids=" ".join)
+def test_programs_without_a_window_layer_are_the_parents(
+        family_program_backend, monkeypatch):
+    fam, program, backend = family_program_backend
+    name, wide = WINDOWLESS[fam]
+    if backend == "tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _load(name, **(wide if backend == "tpu" else {}))
+    adapter = harness.load_module("models", fam + ".py")
+    model = adapter.build_model(cfg)
+    assert model.cache_window is None
+    text = _lowered(cfg, adapter, model, program)
+    kernels = text.count("custom_call @tpu_custom_call")
+    assert kernels == (program == "decode" and backend == "tpu"
+                       and bool(wide))
+    if program == "decode":
+        took = {"kimi_linear": "mla", "longcat_flash": "mla",
+                "nemotron_h": "gqa"}[fam]
+        assert sorted(model.attention_backend) == [took]
+        assert model.attention_backend[took][0] == (
+            "paged-latent" if kernels else "gather")
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                  'backend_config = ""', text)
+    assert _sha(text.encode()) == PARENT_47[family_program_backend]

@@ -389,21 +389,37 @@ def _gap(runs, params, cfg):
     return worst
 
 
-def test_prefill_then_decode_through_the_cache_is_the_full_forward(family):
+@pytest.mark.parametrize("rings", ["xla", "paged-grouped"])
+def test_prefill_then_decode_through_the_cache_is_the_full_forward(
+        family, rings, monkeypatch):
     """Every step's LOGITS of every occupied slot against the reference's
     full forward over prompt + served tokens (``PLAN``): contexts several
     windows deep, page boundaries, a bucket's padding, joins and leaves,
     and slots of different ages in one step. And what the cache holds: ONE
-    paged layer, whatever the layer count says."""
+    paged layer, whatever the layer count says. In both spellings of the
+    window layers' attend: the two einsums (what the CPU gets) and the page
+    walk over a slot's ring (interpret mode here; the kernel reads a pair of
+    heads in whole tiles of 128 lanes, so the same twelve layers with two
+    heads of 64, the published size, on a stream of 128: logits of order 6,
+    where both spellings read 5e-5)."""
     cfg, model, params = family
+    if rings == "paged-grouped":
+        cfg = _cfg(hidden_size=128, mamba_d_inner=256, num_attention_heads=2)
+        model, params = LM.build_model(cfg), LM.make_weights(cfg, 3)
+        monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
     runs, state = _serve(_engine(model, params, cfg), cfg, PLAN, 40)
     assert _gap(runs, params, cfg) < TOL
+    assert model.attention_backend["swa"][0] == rings
+    assert {model.attention_backend[k][0] for k in ("gqa", "xattn")} == {
+        "gather"}
+    d = cfg["hidden_size"]
+    row = 2 * 2 * d // cfg["num_attention_heads"]   # [k | v] on 2 heads
     assert sorted(state.arrays) == ["kv", "m1_conv", "m1_s", "swa_kv"]
-    assert [a.shape for a in state.arrays["kv"]] == [(4 * 16 + 1, 8, 64)]
-    assert [a.shape for a in state.arrays["swa_kv"]] == [(4, 8, 64)] * 3
+    assert [a.shape for a in state.arrays["kv"]] == [(4 * 16 + 1, 8, row)]
+    assert [a.shape for a in state.arrays["swa_kv"]] == [(4, 8, row)] * 3
     assert [(a.shape, a.dtype) for a in state.arrays["m1_s"]] == [
-        ((4, 4, 128), jnp.float32)] * 4
-    assert [a.shape for a in state.arrays["m1_conv"]] == [(4, 3, 128)] * 4
+        ((4, 4, 2 * d), jnp.float32)] * 4
+    assert [a.shape for a in state.arrays["m1_conv"]] == [(4, 3, 2 * d)] * 4
 
 
 def test_the_query_only_layers_read_the_shared_layers_pool(family):
@@ -541,14 +557,15 @@ def test_the_page_walk_takes_the_padded_pairs_of_both_kinds(positions,
     clean = pool.at[trash].set(0)
     want, pool0 = model._gqa_decode(p_full, h, clean, tables, pos, 8)
     want_x = model._gqa_decode(p_cross, h, pool0, tables, pos, 8, "xattn")
-    assert model.attention_backend == ("gather", "on cpu")
+    assert model.attention_backend == {
+        kind: ("gather", "on cpu") for kind in ("gqa", "xattn")}
     assert want_x[1] is pool0       # handed back as it came: nothing written
     monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
     monkeypatch.setattr(hybrid, "GATHER_VIEW_BYTES", 0)
     monkeypatch.setattr(pla, "GROUPED_VISIT_BYTES", 2 * 8 * 256 * 4)
     got, pool1 = model._gqa_decode(p_full, h, pool.at[trash].set(jnp.nan),
                                    tables, pos, 8)
-    assert model.attention_backend[0] == "paged-grouped"
+    assert model.attention_backend["gqa"][0] == "paged-grouped"
     got_x, _ = model._gqa_decode(p_cross, h, pool1, tables, pos, 8, "xattn")
     for a, b in ((got, want), (got_x, want_x[0])):
         assert not bool(jnp.isnan(a).any())
@@ -558,6 +575,54 @@ def test_the_page_walk_takes_the_padded_pairs_of_both_kinds(positions,
     # the chooser sees 4 query rows on 1 head of 128, a row of 256
     assert hybrid.grouped_attention_backend(4, 4, 1, 128, 8, 12, 4)[0] \
         == "paged-grouped"
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 2e-2)])
+def test_the_page_walk_takes_a_rings_padded_pairs(dtype, tol, monkeypatch):
+    """The window kind in the differential form, 4 heads on 2 key/value
+    heads of 64 (ONE pair of 128 with four query rows) over a ring of 24
+    rows in pages of 8: through the kernel and ``_attn_diff`` it is the two
+    einsums and ``_attn_diff``, to summation noise in float32 and within
+    one rounding in bfloat16; slots younger than the window (whose dead
+    pages hold NaN in float32), as old, one row past it and several turns
+    past it in one batch."""
+    L = LayerSpec
+    cfg = HybridConfig(
+        vocab_size=64, d_model=64, max_len=256, dense_ff=64,
+        layers=(L("swa", "dense"),), gqa_heads=4, swa_heads=4,
+        gqa_kv_heads=2, gqa_head_dim=64, swa_window=24, differential=True,
+        attn_bias=True, dtype=dtype, param_dtype=dtype)
+    model = HybridLM(cfg)
+    assert cfg.attention_shape("swa") == (1, 4, 128)
+    p = jax.tree.map(
+        lambda a: (10 * a).astype(a.dtype) if a.ndim == 2 else a,
+        model.init_params(jax.random.key(6))["blocks"][0]["mixer"])
+    ages = (0, 7, 8, 23, 24, 100)
+    ks = jax.random.split(jax.random.key(0), 2)
+    ring = jax.random.normal(ks[0], (len(ages), 24, 256)).astype(dtype)
+    h = jax.random.normal(ks[1], (len(ages), 64)).astype(dtype)
+    pos = jnp.asarray(ages, jnp.int32)
+    want, ring0 = model._swa_decode(p, h, ring, pos)
+    assert model.attention_backend == {"swa": ("xla", "on cpu")}
+    monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
+    monkeypatch.setattr(hybrid, "RING_PAGE_ROWS", 8)
+    if dtype == jnp.float32:
+        beyond = np.arange(24)[None, :] // 8 > np.asarray(ages)[:, None] // 8
+        ring = jnp.where(beyond[:, :, None], jnp.nan, ring)
+    got, ring1 = model._swa_decode(p, h, ring, pos)
+    assert model.attention_backend["swa"] == (
+        "paged-grouped",
+        "live pages of 8 rows of 256 of a ring of 24 read where they lie")
+    assert not bool(jnp.isnan(got).any())
+    want, got = want.astype(jnp.float32), got.astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(want))) > 0.05
+    assert float(jnp.max(jnp.abs(got - want))) < tol * max(
+        1.0, float(jnp.max(jnp.abs(want))))
+    at = np.asarray(ages) % 24
+    np.testing.assert_array_equal(
+        np.asarray(ring1, np.float32)[np.arange(len(ages)), at],
+        np.asarray(ring0, np.float32)[np.arange(len(ages)), at])
 
 
 # ------------------------------------------------------------ the bytes

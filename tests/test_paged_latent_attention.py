@@ -104,7 +104,7 @@ def _both(model, p, h, pool, tables, pos, monkeypatch, visit=None):
                             * pool.dtype.itemsize)
     got = model._mla_decode(p, h, pool.at[TRASH].set(jnp.nan), tables, pos,
                             P)
-    assert model.attention_backend[0] == "paged-latent"
+    assert model.attention_backend["mla"][0] == "paged-latent"
     return want, got
 
 
@@ -207,7 +207,7 @@ def test_greedy_tokens_through_decode_paged_are_the_fallbacks(family,
     monkeypatch.setattr(hybrid, "_on_tpu", lambda: True)
     eng = _engine(family)
     got = eng.generate(prompts, 24)
-    assert eng.model.attention_backend[0] == "paged-latent"
+    assert eng.model.attention_backend["mla"][0] == "paged-latent"
     assert got.shape == (3, 24) and np.array_equal(got, want)
     assert len({tuple(r) for r in got.tolist()}) == 3   # three streams
 
@@ -298,8 +298,8 @@ def test_the_kernel_is_in_the_decode_program_alone(monkeypatch, caplog):
             "/attn_core/mla_attend/jit(_paged_latent_attention)"), path
     said = [r.getMessage() for r in caplog.records
             if r.getMessage().startswith("attention backend")]
-    assert said == ["attention backend: paged-latent: live pages of 8 rows "
-                    f"of {ROW} read where they lie"]
+    assert said == ["attention backend: mla: paged-latent: live pages of 8 "
+                    f"rows of {ROW} read where they lie"]
 
 
 # -------------------------------------------- what a step's span counts
@@ -309,7 +309,7 @@ def test_attn_pages_on_the_step_span_and_the_choice_in_the_snapshot():
     step it fetched was dispatched for, as the loop held them at the
     dispatch; ``snapshot()`` names the attention's choice and its reason."""
     eng = _engine("kimi_linear")
-    assert eng.model.attention_backend is None      # no trace yet
+    assert eng.model.attention_backend == {}        # no trace yet
     jobs = [{"prompt": _prompt(n, 70 + i, 512), "max_new_tokens": m}
             for i, (n, m) in enumerate([(5, 14), (20, 9), (11, 12)])]
     with GenerationPipeline(eng, slots=2) as gp:    # compiles
@@ -327,7 +327,7 @@ def test_attn_pages_on_the_step_span_and_the_choice_in_the_snapshot():
         recs = _serve(gp, jobs)
         snap = gp.snapshot()
     assert all(r["error"] is None for r in recs)
-    assert snap["attention_backend"] == "gather: on cpu"
+    assert snap["attention_backend"] == "mla: gather: on cpu"
     steps = [s for s in sink.spans()
              if s.name == "decode_step" and "attn_pages" in (s.attrs or {})]
     assert len(steps) >= 14

@@ -568,6 +568,51 @@ def test_hybrid_decode_step_for_the_tpu_has_the_kernel_under_moe_experts(
         assert _inner.inner_of(op_name) == "moe_experts"
 
 
+def test_laguna_decode_step_for_the_tpu_has_the_walk_under_swa_attend(
+        monkeypatch):
+    """The sixth family's decode step lowered for the TPU with heads of 128
+    (what the kernel reads in whole tiles) and the process's backend
+    patched: each of the three window layers calls the ONE jitted
+    ``_paged_grouped_attention``, which holds the one Pallas custom call, on
+    a path ``.../attn_core/swa_attend/jit(_paged_grouped_attention)`` - the
+    compiled call's ``op_name`` is that path + ``/pallas_call`` (compiled
+    for a described v5e in tests/test_grouped_ffn.py), so
+    ``_laguna.names_of`` books the kernel's seconds to kind ``swa`` under
+    ``swa_attend`` and ``attn_core`` with no reading by name. The two full
+    layers keep the gathered window at this size."""
+    from perfbench import harness
+    from perfbench.layer_metrics import _laguna
+    mod = harness.load_module("models", "laguna.py")
+    cfg = harness.load_json("configs", "laguna-xs2-33b-a3b-stage5.json")
+    cfg.update(cfg["rehearsal"])
+    cfg.update(head_dim=128)
+    model = mod.build_model(cfg)
+    shapes = mod.weight_shapes(cfg)
+    eng = DecodeEngine(model, shapes, max_len=cfg["n_positions"],
+                       prefill_buckets=[16], page_tokens=8)
+    cache = jax.eval_shape(lambda: model.new_paged_cache(4, 9, 8))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)     # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = eng._decode_paged_jit.trace(
+        shapes, cache, i32(4, 16), i32(4), i32(4), i32()).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert model.attention_backend["swa"][0] == "paged-grouped"
+    assert model.attention_backend["gqa"][0] == "gather"
+    body = text[text.index("func.func private @_paged_grouped_attention("):]
+    assert "custom_call @tpu_custom_call" in body[:body.index("\n  }")]
+    calls = [line for line in text.splitlines()
+             if "call @_paged_grouped_attention(" in line]
+    assert len(calls) == 3
+    for call in calls:
+        loc = re.search(r"loc\((#loc\d+)\)\s*$", call).group(1)
+        path = re.search(rf'^{loc} = loc\("([^"]*)"', text, re.M).group(1)
+        assert path.endswith(
+            "/attn_core/swa_attend/jit(_paged_grouped_attention)"), path
+        op_name = path + "/pallas_call"
+        assert _named.scope_of(op_name) == "attn_core"
+        assert _laguna.names_of(op_name) == ("swa", "swa_attend")
+
+
 # ------------------------------------------------------ (d) the readers
 @pytest.mark.parametrize("op_name, scope", [
     ("jit(step)/jvp(ln)/mul", "ln"),
