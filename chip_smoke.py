@@ -381,8 +381,8 @@ def quant_gate_section(model, params):
           and engine.kv_quant,
           f"the numerics gate turned int8 KV storage off: "
           f"{engine.quant_gate}")
-    check(state.arrays["k"].dtype == jnp.int8,
-          f"page pool is {state.arrays['k'].dtype}, not int8")
+    check(state.arrays["k"][0].dtype == jnp.int8,
+          f"page pool is {state.arrays['k'][0].dtype}, not int8")
 
 
 # ------------------------------------------------------------------ flash

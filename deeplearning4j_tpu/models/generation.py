@@ -69,7 +69,6 @@ from jax import lax
 from deeplearning4j_tpu.observability import compile_watch as _cw
 from deeplearning4j_tpu.observability import cost_model as _cost
 from deeplearning4j_tpu.observability import span as _span
-from deeplearning4j_tpu.models.transformer import pack_kv_pages  # noqa: F401
 from deeplearning4j_tpu.resilience.policy import CachePagesExhausted
 
 _log = logging.getLogger(__name__)
@@ -627,7 +626,6 @@ class DecodeEngine:
         the smallest bucket, teacher-force a few greedy steps through
         BOTH paths, and compare per-step logits. Divergence beyond
         ``quant_tol`` flips the engine back to f32 storage."""
-        from deeplearning4j_tpu.models import transformer as _tr
         model, params = self.model, self.params
         bucket = self.prefill_buckets[0]
         if self.max_len - bucket < 1:
@@ -651,15 +649,10 @@ class DecodeEngine:
                                       quant=True)
         tables = np.full((1, self.pages_per_slot), n_pages, np.int32)
         tables[0, :n_pages] = np.arange(n_pages)
-        k8, ks = _tr.quantize_kv_rows(pack_kv_pages(kv["k"],
-                                                    self.page_tokens))
-        v8, vs = _tr.quantize_kv_rows(pack_kv_pages(kv["v"],
-                                                    self.page_tokens))
-        ids = np.arange(self.pages_for(bucket))
-        pool = {"k": pool["k"].at[:, ids].set(k8),
-                "v": pool["v"].at[:, ids].set(v8),
-                "k_scale": pool["k_scale"].at[:, ids].set(ks),
-                "v_scale": pool["v_scale"].at[:, ids].set(vs)}
+        # the insert production traces, run eagerly: ONE packing
+        pool = model.insert_paged(
+            pool, kv, jnp.arange(self.pages_for(bucket), dtype=jnp.int32),
+            0, self.page_tokens)
         tok = jnp.argmax(logits_p[:, bucket - 1], axis=-1).astype(jnp.int32)
         pos = jnp.full((1,), bucket, jnp.int32)
         max_diff = 0.0

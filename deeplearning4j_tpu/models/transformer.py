@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import math
 import os
 from typing import Any, Dict, Optional, Tuple
 
@@ -108,18 +109,20 @@ def quantize_kv_rows(rows):
 
 
 def pack_kv_pages(arr, page_tokens: int):
-    """(L, 1, Tb, H, hd) prefill k/v → (L, npb, P, H, hd) page rows,
-    zero-padded up to whole pages (pad rows sit past the prompt's
-    positions — masked until the slot's own decode writes overwrite
-    them). ONE spelling shared by the traced paged insert and the
-    eager numerics-gate probe: the gate must compare exactly the
-    packing production inserts use, or a layout change could slip past
-    it."""
-    L, _b, tb, h, hd = arr.shape
+    """(L, 1, Tb, H, hd) prefill k/v → (L, npb, P, H·hd) page rows of
+    whole lanes (a per-row scale (L, 1, Tb) → (L, npb, P)), zero-padded
+    up to whole pages (pad rows sit past the prompt's positions —
+    masked until the slot's own decode writes overwrite them). ONE
+    spelling: the traced paged insert uses it and the eager
+    numerics-gate probe goes through that insert, so the gate compares
+    exactly the packing production uses and a layout change cannot
+    slip past it."""
+    L, _b, tb = arr.shape[:3]
     npb = -(-tb // page_tokens)
     pad = npb * page_tokens - tb
-    a = jnp.pad(arr[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
-    return a.reshape(L, npb, page_tokens, h, hd)
+    a = jnp.pad(arr[:, 0], ((0, 0), (0, pad)) + ((0, 0),) * (arr.ndim - 3))
+    lanes = (math.prod(arr.shape[3:]),) if arr.ndim > 3 else ()
+    return a.reshape(L, npb, page_tokens, *lanes)
 
 
 @dataclasses.dataclass
@@ -847,18 +850,19 @@ class TransformerLM:
 
     def insert_paged(self, pool, kv, page_ids, slot, page_tokens: int):
         """(L, 1, Tb, H, hd) prefill k/v -> whole-page rows
-        (:func:`pack_kv_pages`) scattered into the slot's physical pages."""
-        kr = pack_kv_pages(kv["k"], page_tokens)
-        vr = pack_kv_pages(kv["v"], page_tokens)
+        (:func:`pack_kv_pages`) written into the slot's physical pages of
+        each layer's own donated array, in place: the program moves the
+        joiner's pages and no other."""
+        rows = {"k": kv["k"], "v": kv["v"]}
         if "k_scale" in pool:
-            k8, ks = quantize_kv_rows(kr)
-            v8, vs = quantize_kv_rows(vr)
-            return {"k": pool["k"].at[:, page_ids].set(k8),
-                    "v": pool["v"].at[:, page_ids].set(v8),
-                    "k_scale": pool["k_scale"].at[:, page_ids].set(ks),
-                    "v_scale": pool["v_scale"].at[:, page_ids].set(vs)}
-        return {"k": pool["k"].at[:, page_ids].set(kr),
-                "v": pool["v"].at[:, page_ids].set(vr)}
+            rows["k"], rows["k_scale"] = quantize_kv_rows(kv["k"])
+            rows["v"], rows["v_scale"] = quantize_kv_rows(kv["v"])
+        out = {}
+        for leaf, arr in rows.items():
+            packed = pack_kv_pages(arr, page_tokens)
+            out[leaf] = [held.at[page_ids].set(packed[li])
+                         for li, held in enumerate(pool[leaf])]
+        return out
 
     def decode_paged(self, params, pool, tables, tokens, positions,
                      page_tokens: int):
@@ -980,10 +984,10 @@ class TransformerLM:
 
     # ------------------------------------------ paged / windowed decode
     # The paged twin of the dense cache above: k/v live in a POOL of
-    # fixed-size pages (L, n_pages, page_tokens, H, hd) shared by every
-    # slot, and a per-slot PAGE TABLE (B, pages_per_slot) int32 maps
-    # logical page j of slot b to a physical pool page. Decode writes
-    # scatter through the table, attention gathers through it — the
+    # fixed-size pages, one (n_pages, page_tokens, H·hd) array a layer,
+    # shared by every slot, and a per-slot PAGE TABLE (B, pages_per_slot)
+    # int32 maps logical page j of slot b to a physical pool page. Decode
+    # writes scatter through the table, attention gathers through it — the
     # executable depends only on the (static) pool/table shapes, never
     # on which pages are allocated, so steady-state decode stays
     # zero-retrace exactly like the dense path. ``decode_step_math`` is
@@ -999,21 +1003,27 @@ class TransformerLM:
     def init_paged_cache(self, n_pages: int, page_tokens: int,
                          quant: bool = False,
                          dtype: Optional[Any] = None) -> Dict:
-        """Page pool: ``{"k","v"}`` of (L, n_pages, P, H, hd) — int8
-        plus per-row f32 scales ``{"k_scale","v_scale"}`` (L, n_pages,
-        P) under ``quant`` (one scale per cached token row, stored
-        page-wise: quantizing a row at write time needs no re-scan of
-        the page it lands in)."""
+        """Page pool: ``{"k","v"}``, each a list of one (n_pages, P,
+        H·hd) array a layer — a row is the token's heads side by side,
+        whole 128-lane tiles at the served widths, and a layer's array is
+        written in place by the programs it is donated to. Under
+        ``quant`` the rows are int8 and ``{"k_scale","v_scale"}`` hold a
+        layer's (n_pages, P) float32 scales (one scale per cached token
+        row, stored page-wise: quantizing a row at write time needs no
+        re-scan of the page it lands in). The LAST physical page is the
+        trash page."""
         c = self.config
-        h, hd = c.n_heads, c.d_model // c.n_heads
-        shape = (c.n_layers, n_pages, page_tokens, h, hd)
+        shape = (n_pages, page_tokens, c.d_model)
+        dt = jnp.int8 if quant else (dtype if dtype is not None else c.dtype)
+
+        def layers(shape, dt):
+            return [jnp.zeros(shape, dt) for _ in range(c.n_layers)]
+
+        pool = {"k": layers(shape, dt), "v": layers(shape, dt)}
         if quant:
-            return {"k": jnp.zeros(shape, jnp.int8),
-                    "v": jnp.zeros(shape, jnp.int8),
-                    "k_scale": jnp.zeros(shape[:3], jnp.float32),
-                    "v_scale": jnp.zeros(shape[:3], jnp.float32)}
-        dt = dtype if dtype is not None else c.dtype
-        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+            pool["k_scale"] = layers(shape[:2], jnp.float32)
+            pool["v_scale"] = layers(shape[:2], jnp.float32)
+        return pool
 
     def _window_embed(self, params, tokens, positions):
         """(B, W) tokens at (B, W) positions → (B, W, C) activations +
@@ -1080,9 +1090,11 @@ class TransformerLM:
 
     def decode_window_paged(self, params, pool, tables, tokens, positions,
                             page_tokens: int):
-        """Paged W-window decode/verify: scatter the window's k/v rows
-        into the pool through the per-slot page table, gather each
-        slot's logical pages back, and attend under the same causal
+        """Paged W-window decode/verify: write the window's k/v rows
+        into each layer's own (n_pages, P, H·hd) array through the
+        per-slot page table — in place, the pool is donated and nothing
+        is sliced out of it or stacked back — gather each slot's logical
+        pages as a (B, S, H, hd) view, and attend under the same causal
         mask. ``tables`` (B, pages_per_slot) int32; quantized pools
         (``k_scale`` present) dequantize ON THE FLY inside the
         attention — int8 rows never round-trip through a dense f32
@@ -1092,8 +1104,9 @@ class TransformerLM:
         B, W = tokens.shape
         P = int(page_tokens)
         S = tables.shape[1] * P
-        hd = c.d_model // c.n_heads
+        h, hd = c.n_heads, c.d_model // c.n_heads
         quant = "k_scale" in pool
+        pool = {name: list(held) for name, held in pool.items()}
         pos_w = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
         x = self._window_embed(params, tokens, pos_w)
         mask = jnp.arange(S)[None, None, :] <= pos_w[:, :, None]  # (B,W,S)
@@ -1104,7 +1117,14 @@ class TransformerLM:
         # physical page, owned by no table row — instead of letting the
         # gather clamp corrupt a page the slot legitimately owns.
         bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
-        trash = pool["k"].shape[1] - 1
+        trash = 0
+        if pool["k"]:           # a zero-layer trunk holds no page
+            n_pages, _p, lanes = pool["k"][0].shape
+            trash = n_pages - 1
+            self._say_once(
+                "paged cache",
+                f"{len(pool['k'])} arrays of ({n_pages}, {P}, {lanes}) "
+                "lanes", "rows and joiners written in place")
         in_range = pos_w < S
         with jax.named_scope("kv_write"):
             phys = jnp.where(
@@ -1114,35 +1134,28 @@ class TransformerLM:
             off = pos_w % P                                      # (B, W)
 
         def store(name, li, rows):
-            """Scatter W rows per slot into layer ``li`` of pool
-            ``name`` (+ scale grid under quant), then gather every
+            """Write W rows per slot into layer ``li``'s array of pool
+            ``name`` (+ its scale grid under quant), then gather every
             slot's pages back as a dequantized (B, S, H, hd) view."""
             with jax.named_scope("kv_write"):
-                pool_l, scale_l = pool[name][li], None
                 if quant:
-                    q8, sc = quantize_kv_rows(rows)
-                    pool_l = pool_l.at[phys, off].set(q8)
-                    scale_l = pool[name + "_scale"][li].at[phys, off].set(sc)
-                else:
-                    pool_l = pool_l.at[phys, off].set(rows)
+                    rows, sc = quantize_kv_rows(rows)
+                    scale = name + "_scale"
+                    pool[scale][li] = pool[scale][li].at[phys, off].set(sc)
+                pool[name][li] = pool[name][li].at[phys, off].set(
+                    rows.reshape(B, W, c.d_model))
             with jax.named_scope("kv_gather"):
-                view = pool_l[tables].reshape(B, S, *pool_l.shape[-2:])
+                view = pool[name][li][tables].reshape(B, S, h, hd)
                 if quant:
-                    gsc = scale_l[tables].reshape(B, S)
+                    gsc = pool[scale][li][tables].reshape(B, S)
                     view = (view.astype(jnp.float32)
                             * gsc[:, :, None, None]).astype(c.dtype)
-            return pool_l, scale_l, view
+            return view
 
-        nk, nv, nks, nvs = [], [], [], []
         for li, blk in enumerate(self._decode_blocks(params)):
             q, k, v = self._qkv(blk["attn"], self._ln(blk["ln1"], x))
-            pk, sk, ck = store("k", li, k)
-            pv, sv, cv = store("v", li, v)
-            nk.append(pk)
-            nv.append(pv)
-            if quant:
-                nks.append(sk)
-                nvs.append(sv)
+            ck = store("k", li, k)
+            cv = store("v", li, v)
             o = self._window_attend(q, ck, cv, mask, hd)
             with jax.named_scope("attn_out"):
                 x = x + (o.reshape(B, W, c.d_model) @ blk["attn"]["wo"])
@@ -1151,14 +1164,7 @@ class TransformerLM:
         with jax.named_scope("head"):
             logits = jnp.matmul(x, params["tok_emb"].T,
                                 preferred_element_type=jnp.float32)
-        if not nk:              # zero-layer trunk: pool untouched
-            return logits, pool
-        with jax.named_scope("kv_write"):
-            out = {"k": jnp.stack(nk), "v": jnp.stack(nv)}
-            if quant:
-                out["k_scale"] = jnp.stack(nks)
-                out["v_scale"] = jnp.stack(nvs)
-        return logits, out
+        return logits, pool
 
 
 def make_sharded_lm(config: TransformerConfig, mesh: Mesh, optimizer=None,

@@ -444,3 +444,72 @@ def test_the_ring_walk_compiles_for_the_v5e_at_published_widths(
     assert mem.alias_size_in_bytes == ring
     assert mem.temp_size_in_bytes < ring // 8
 
+
+def _pool_shaped(text, shapes):
+    """(opcode, line) of every instruction of ``text`` whose result has one
+    of ``shapes``, parameters and the pieces of a tuple left out."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])\S* "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(1) in shapes and m.group(2) not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            found.append((m.group(2), line))
+    return found
+
+
+def test_the_gpt2_pool_is_written_where_it_lies_on_the_v5e(one_chip):
+    """``gpt2l-batch-gen``'s decode and insert programs (36 layers, 20
+    slots, 255 pages + the trash page of 64 rows of 1,280 lanes in bfloat16,
+    the 256 bucket), compiled for a described v5e: every one of the 72 pool
+    arrays is aliased input to output; what has a pool array's shape is the
+    in-place scatter under ``kv_write`` and nothing else (no ``copy``, no
+    slice of a stacked pool, no stack: the parent's program held 5.4 GB of
+    such temporaries and its insert a whole second pool), but for the
+    compiler's own prefetch of the FIRST array into VMEM and back, one
+    asynchronous copy; the temporaries are the gathered views and the
+    weights' bfloat16 copy a layer at a time."""
+    from deeplearning4j_tpu.models.generation import DecodeEngine
+    adapter = harness.load_module("models", "gpt2.py")
+    cfg = harness.load_json("configs", "gpt2-large.json")
+    model = adapter.build_model(cfg)
+    L, slots, n_pages, P, d, bucket = 36, 20, 256, 64, 1280, 256
+    assert (model.config.n_layers, model.config.d_model) == (L, d)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    params = on_chip(adapter.weight_shapes(cfg))
+    eng = DecodeEngine(model, params, max_len=1024, page_tokens=P,
+                       prefill_buckets=[bucket])
+    pool = on_chip(jax.eval_shape(
+        lambda: model.new_paged_cache(slots, n_pages, P)))
+    assert [a.shape for a in pool["k"]] == [(n_pages, P, d)] * L
+    pool_bytes = 2 * L * n_pages * P * d * 2
+    kv = on_chip(jax.eval_shape(lambda p, t: model.prefill(p, t)[1],
+                                params, i32(1, bucket)))
+    decode = eng._decode_paged_jit.lower(
+        params, pool, i32(slots, 1024 // P), i32(slots), i32(slots),
+        i32()).compile()
+    insert = eng._insert_paged_jit.lower(pool, kv, i32(bucket // P),
+                                         i32()).compile()
+    shapes = {f"bf16[{n_pages},{P},{d}]", f"bf16[{L},{n_pages},{P},{d}]",
+              f"bf16[1,{n_pages},{P},{d}]"}
+    for program, compiled, temp in (("_decode_paged", decode, 2 << 30),
+                                    ("_insert_paged", insert, 200e6)):
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == pool_bytes, program
+        assert mem.temp_size_in_bytes < temp, (program,
+                                               mem.temp_size_in_bytes)
+        found = _pool_shaped(compiled.as_text(), shapes)
+        writes = [line for op, line in found if op in ("fusion", "scatter")]
+        assert len(writes) >= 2 * L, program
+        assert all(f'op_name="jit({program})/kv_write/scatter"' in line
+                   for line in writes), program
+        # the prefetch: four slices joined in VMEM, one copy back
+        others = sorted(op for op, line in found
+                        if op not in ("fusion", "scatter"))
+        assert others in ([], ["copy-done", "custom-call"]), (program, others)

@@ -372,11 +372,14 @@ def test_gpt2_through_the_protocol_is_the_parents_program():
     assert np.array_equal(np.asarray(logits), np.asarray(want_logits))
     pool = model.init_paged_cache(11, 8)
     pool = jax.jit(lambda pool, kv, ids: {
-        n: pool[n].at[:, ids].set(pack_kv_pages(kv[n], 8))
+        n: [held.at[ids].set(pack_kv_pages(kv[n], 8)[li])
+            for li, held in enumerate(pool[n])]
         for n in ("k", "v")})(pool, want_kv, pages)
     for name in ("k", "v"):
-        assert np.array_equal(np.asarray(state.arrays[name]),
-                              np.asarray(pool[name]))
+        assert len(state.arrays[name]) == tc.n_layers
+        for got, want in zip(state.arrays[name], pool[name]):
+            assert got.shape == (11, 8, tc.d_model)     # rows of whole lanes
+            assert np.array_equal(np.asarray(got), np.asarray(want))
     want_step, _ = jax.jit(
         lambda p, pool, tab, tok, pos: model.decode_window_paged(
             p, pool, tab, tok[:, None], pos, 8))(

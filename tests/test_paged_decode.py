@@ -11,6 +11,7 @@ reclamation on exhaustion, admission resumes after reclaim), the
 speculative accept/resample loop (greedy byte-exactness, seeded
 resample distribution == the target's), and the paged+spec chaos drill
 (every request resolves exactly once, pages all reclaimed)."""
+import dataclasses
 import threading
 import time
 
@@ -18,6 +19,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from deeplearning4j_tpu.models import transformer as _tr
 from deeplearning4j_tpu.models.generation import (DecodeEngine,
@@ -251,8 +253,8 @@ def test_quant_gate_passes_and_stores_int8():
     gate = eng.quant_gate
     assert gate["checked"] and gate["passed"]
     assert gate["max_abs_logit_diff"] <= gate["tol"]
-    assert eng.kv_quant and st.arrays["k"].dtype == np.int8
-    assert "k_scale" in st.arrays
+    assert eng.kv_quant and "k_scale" in st.arrays
+    assert all(a.dtype == np.int8 for n in ("k", "v") for a in st.arrays[n])
     # int8 pages cost a fraction of f32 pages (the admission win)
     assert eng.page_bytes() < _engine("paged").page_bytes() / 3
     # quantized decode stays argmax-faithful on a real continuation
@@ -280,10 +282,202 @@ def test_quant_gate_trips_on_bad_scale_and_falls_back(monkeypatch):
     assert gate["checked"] and not gate["passed"]
     assert gate["max_abs_logit_diff"] > gate["tol"]
     assert not eng.kv_quant                # fell back
-    assert st.arrays["k"].dtype != np.int8 and "k_scale" not in st.arrays
+    assert "k_scale" not in st.arrays
+    assert all(a.dtype != np.int8 for n in ("k", "v") for a in st.arrays[n])
     out = eng.generate(_prompt(9)[None], 10)
     assert np.array_equal(out, _engine("paged").generate(
         _prompt(9)[None], 10))
+
+
+# ------------------------------------- the layout and the in-place writes
+# One (n_pages, P, H·hd) array a layer, written where it lies (PR 48). The
+# limits come from the dtypes, against logits of order 0.5, with the first
+# readings beside them: float32 pages hold the forward's own rows, so a
+# window's logits differ from ``apply()`` by summation order alone (ten
+# steps of 2**-23; read 6e-8 - 7.5e-8); bfloat16 rounds every product to
+# 2**-8 (read 1.2e-3 - 1.4e-3); an int8 row is 1/127 of its largest entry a
+# step, averaged over 32 lanes and 48 rows (read 1.6e-4 - 2.2e-4), far
+# inside the deploy-time gate's ``quant_tol`` of 0.05.
+_KINDS = ("float32", "bfloat16", "int8")
+_LIMIT = {"float32": 1e-6, "bfloat16": 5e-3, "int8": 2e-3}
+_KIND_MODELS = {}
+
+
+def _kind_model(kind):
+    """(model, params, quant): the module's float32 weights, computed in
+    ``kind``; int8 pages under the float32 model."""
+    if kind not in _KIND_MODELS:
+        m, p = _mp()
+        if kind == "bfloat16":
+            m = TransformerLM(dataclasses.replace(m.config,
+                                                  dtype=jnp.bfloat16))
+        _KIND_MODELS[kind] = (m, p, kind == "int8")
+    return _KIND_MODELS[kind]
+
+
+def _noise_pool(model, n_pages, quant, seed=3):
+    """A pool whose every row holds something: a write that strays shows."""
+    rng = np.random.default_rng(seed)
+    pool = model.init_paged_cache(n_pages, PAGE, quant=quant)
+    return {name: [jnp.asarray(
+        rng.integers(-100, 100, a.shape).astype(np.int8) if a.dtype == np.int8
+        else rng.uniform(0.5, 1.5, a.shape), a.dtype) for a in held]
+            for name, held in pool.items()}
+
+
+def _same_int8_rows(got, want, got_scale, want_scale):
+    """The same quantised rows from two programs (one eager, one fused): a
+    scale may differ in its last bit, and a value that sat on a rounding
+    boundary by one step."""
+    np.testing.assert_allclose(got_scale, want_scale, rtol=1e-6)
+    assert np.abs(got.astype(np.int32) - want.astype(np.int32)).max() <= 1
+    assert (got != want).mean() < 1e-2
+
+
+def _host(pool):
+    return {name: [np.array(a) for a in held] for name, held in pool.items()}
+
+
+@pytest.mark.parametrize("kind", ["bfloat16", "int8"])
+def test_insert_writes_the_joiners_pages_and_no_other(kind):
+    """After ``insert_paged`` (the engine's donated program) every page NOT
+    among the joiner's, the trash page with them, is bit for bit what it
+    was, and the joiner's pages hold ``pack_kv_pages``' rows of whole
+    lanes, layer by layer."""
+    m, p, quant = _kind_model(kind)
+    eng = DecodeEngine(m, p, max_len=MAXLEN, page_tokens=PAGE)
+    _first, _l, kv, _t = eng.prefill(_prompt(20)[None])      # bucket 32
+    n_pages, ids = 8, np.asarray([5, 2], np.int32)
+    pool = _noise_pool(m, n_pages, quant)
+    before = _host(pool)
+    leaves = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
+    assert set(pool) == set(leaves)
+    for name in leaves:
+        assert len(pool[name]) == m.config.n_layers
+        assert all(a.shape == (n_pages, PAGE, m.config.d_model)[:a.ndim]
+                   for a in pool[name])
+    after = _host(eng._insert_paged_jit(pool, kv, ids, 0))
+    others = np.setdiff1d(np.arange(n_pages), ids)
+    assert n_pages - 1 in others                       # the trash page
+    for name in leaves:
+        for li in range(m.config.n_layers):
+            assert np.array_equal(after[name][li][others],
+                                  before[name][li][others]), (name, li)
+    for name in ("k", "v"):
+        rows = kv[name]
+        if quant:
+            rows, scale = _tr.quantize_kv_rows(rows)
+            want_scale = np.asarray(_tr.pack_kv_pages(scale, PAGE))
+        want = np.asarray(_tr.pack_kv_pages(rows, PAGE))
+        assert want.shape == (m.config.n_layers, 2, PAGE, m.config.d_model)
+        for li in range(m.config.n_layers):
+            if quant:
+                _same_int8_rows(after[name][li][ids], want[li],
+                                after[name + "_scale"][li][ids],
+                                want_scale[li])
+            else:
+                assert np.array_equal(after[name][li][ids], want[li])
+
+
+#: (window, tokens already cached): a first row on a page the prefill did
+#: not touch, a window across a page boundary, a window whose last row lies
+#: past the slot's last logical page, and a step wholly past it
+_WINDOWS = [(1, 16), (3, 14), (3, 46), (1, 48)]
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+@pytest.mark.parametrize("window,cached", _WINDOWS,
+                         ids=[f"W{w}at{n}" for w, n in _WINDOWS])
+def test_window_through_the_pages_gives_the_forwards_logits(kind, window,
+                                                            cached):
+    """``decode_window_paged`` over a slot whose pages lie out of order in
+    the pool, beside a free slot: each window row's logits are ``apply()``'s
+    at that position; the rows land at (page of the table, position % P) of
+    every layer's array and NOWHERE else, a row past the last logical page
+    on the trash page."""
+    m, p, quant = _kind_model(kind)
+    S = MAXLEN
+    tokens = _prompt(cached + window, seed=cached)
+    n_pages, trash, own = 8, 7, [4, 1, 6]
+    pool = _noise_pool(m, n_pages, quant, seed=cached)
+    _logits, kv = m.prefill(p, jnp.asarray(tokens[None, :cached]))
+    pool = m.insert_paged(pool, kv, jnp.asarray(own, jnp.int32), 1, PAGE)
+    before = _host(pool)
+    tables = np.full((2, S // PAGE), trash, np.int32)
+    tables[1] = own
+    win = np.stack([np.zeros(window, np.int32), tokens[cached:]])
+    step = jax.jit(m.decode_window_paged, static_argnums=(5,),
+                   donate_argnums=(1,))
+    logits, pool = step(p, pool, jnp.asarray(tables), jnp.asarray(win),
+                        jnp.asarray([0, cached], jnp.int32), PAGE)
+    after = _host(pool)
+    assert logits.shape == (2, window, VOCAB)
+    inside = [j for j in range(window) if cached + j < S]
+    if inside:
+        upto = cached + len(inside)
+        want = np.asarray(m.apply(p, jnp.asarray(tokens[None, :upto])),
+                          np.float32)[0, cached:upto]
+        got = np.asarray(logits, np.float32)[1, :len(inside)]
+        assert np.abs(got - want).max() <= _LIMIT[kind], \
+            np.abs(got - want).max()
+    # where the rows went: the free slot's (table row on the trash page) at
+    # positions 0.., the served slot's through its table or past it
+    written = {(trash, j) for j in range(window)}
+    for j in range(window):
+        pos = cached + j
+        written.add((own[pos // PAGE], pos % PAGE) if pos < S
+                    else (trash, pos % PAGE))
+    keep = np.ones((n_pages, PAGE), bool)
+    for page, off in written:
+        keep[page, off] = False
+    for name, held in after.items():
+        assert len(held) == m.config.n_layers
+        for li, arr in enumerate(held):
+            assert np.array_equal(arr[keep], before[name][li][keep]), \
+                (name, li)
+            if not name.endswith("_scale"):
+                assert all((arr[pg, off] != before[name][li][pg, off]).any()
+                           for pg, off in written), (name, li)
+
+
+def test_the_gate_probes_through_the_insert_production_traces(monkeypatch):
+    """``pack_kv_pages`` is the ONE packing: the int8 gate's eager probe
+    fills its pool through ``insert_paged``, the function the engine's
+    donated program traces, and both give the same rows for the same
+    prefill."""
+    m, p = _mp()
+    seen = []
+    real = TransformerLM.insert_paged
+
+    def spy(self, pool, kv, page_ids, slot, page_tokens):
+        out = real(self, pool, kv, page_ids, slot, page_tokens)
+        seen.append((kv, page_ids, out))
+        return out
+
+    packed = []
+    real_pack = _tr.pack_kv_pages
+    monkeypatch.setattr(TransformerLM, "insert_paged", spy)
+    monkeypatch.setattr(_tr, "pack_kv_pages", lambda arr, page_tokens: (
+        packed.append(arr.shape), real_pack(arr, page_tokens))[1])
+    eng = DecodeEngine(m, p, max_len=MAXLEN, page_tokens=PAGE, kv_quant=True)
+    state = eng.new_state(1)               # the gate runs here, eagerly
+    assert eng.quant_gate["passed"] and len(seen) == 1
+    kv, ids, probe = seen[0]
+    assert len(packed) == 4                # k, k_scale, v, v_scale: once each
+    assert not isinstance(probe["k"][0], jax.core.Tracer)
+    n = len(np.asarray(ids))
+    state.slot_pages[0] = state.alloc.alloc(n)
+    traced = eng._insert_paged_jit(
+        state.arrays, kv, np.asarray(state.slot_pages[0], np.int32), 0)
+    assert len(seen) == 2 and len(packed) == 8      # the same two functions
+    theirs, mine = np.asarray(state.slot_pages[0]), np.asarray(ids)
+    for name in ("k", "v"):
+        for li in range(m.config.n_layers):
+            _same_int8_rows(
+                np.asarray(traced[name][li])[theirs],
+                np.asarray(probe[name][li])[mine],
+                np.asarray(traced[name + "_scale"][li])[theirs],
+                np.asarray(probe[name + "_scale"][li])[mine])
 
 
 # --------------------------------------------------- pipeline admission
